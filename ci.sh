@@ -12,16 +12,10 @@ echo "== native build =="
 make -C native "PYTHON=$(command -v python3)"
 
 echo "== native artifacts must load (no silent pure-Python fallback) =="
-python3 - <<'EOF'
+python3 -c "
 from parsec_tpu import native
-assert native.available(), "libptcore.so built but failed to load"
-assert native.load_ptdtd() is not None, "_ptdtd built but failed to load"
-assert native.load_ptexec() is not None, "_ptexec built but failed to load"
-assert native.load_ptcomm() is not None, "_ptcomm built but failed to load"
-assert native.load_ptsched() is not None, "_ptsched built but failed to load"
-assert native.load_ptdev() is not None, "_ptdev built but failed to load"
-print("native artifacts OK (ptcore, ptdtd, ptexec, ptcomm, ptsched, ptdev)")
-EOF
+native.require_all()
+print('native artifacts OK (ptcore, ptdtd, ptexec, ptcomm, ptsched, ptdev)')"
 
 echo "== no compiled artifacts tracked/staged =="
 # .gitignore already covers __pycache__/*.pyc; this guards the regression
@@ -237,14 +231,14 @@ EOF
 
 echo "== byte-compile lint (syntax over the whole tree) =="
 python3 -m compileall -q parsec_tpu tests examples benchmarks bench.py \
-    __graft_entry__.py setup.py
+    chip_smoke.py __graft_entry__.py setup.py
 
 echo "== CLI smoke =="
 python3 -m parsec_tpu --version
 python3 -m parsec_tpu --help-mca > /dev/null
 
 echo "== example smoke (CPU) =="
-EXAMPLES_CPU=1 timeout 180 python3 examples/ex04_chain_data.py
+JAX_PLATFORMS=cpu timeout 180 python3 examples/ex04_chain_data.py
 
 if [ "${1:-}" = "quick" ]; then
     echo "== quick suite =="
@@ -255,8 +249,17 @@ else
     timeout 1800 python3 -m pytest tests/ -q -x
 fi
 
-echo "== driver entry compile-check (8 virtual devices) =="
-XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    timeout 600 python3 __graft_entry__.py 8 > /dev/null
+echo "== chip smoke: rehearsal passes, and no chip means failure =="
+# the chip itself is reached only through the chip tool (python3
+# chip_smoke.py); here the script is rehearsed on the CPU backend, and it
+# must refuse to report success without an accelerator
+timeout 600 python3 chip_smoke.py --rehearsal > /dev/null
+if JAX_PLATFORMS=cpu timeout 300 python3 chip_smoke.py > /dev/null 2>&1; then
+    echo "ERROR: chip_smoke.py exited 0 on the CPU backend" >&2
+    exit 1
+fi
+
+echo "== driver entry dry run (8 virtual CPU devices) =="
+JAX_PLATFORMS=cpu timeout 600 python3 __graft_entry__.py 8 > /dev/null
 
 echo "CI OK"

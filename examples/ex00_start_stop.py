@@ -2,10 +2,10 @@
 
 (Reference analogue: examples/Ex00_StartStop.c)
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 def main():
-    maybe_force_cpu()
+    setup()
     import parsec_tpu as pt
     ctx = pt.init(nb_cores=1)
     ctx.start()

@@ -2,10 +2,10 @@
 
 (Reference analogue: examples/Ex01_HelloWorld.c)
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.dsl.dtd import DTDTaskpool, RW

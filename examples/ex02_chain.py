@@ -2,7 +2,7 @@
 
 (Reference analogue: examples/Ex02_Chain.c + chain.jdf)
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 SRC = """
 %global NT
@@ -19,7 +19,7 @@ END
 """
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.data.matrix import TiledMatrix

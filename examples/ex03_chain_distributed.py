@@ -3,10 +3,10 @@
 (Reference analogue: examples/Ex03_ChainMPI.c; ranks here are in-process,
 the same CE vtable backs a multi-host transport on a pod.)
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     from parsec_tpu.comm.remote_dep import RemoteDepEngine
     from parsec_tpu.comm.threads import ThreadsCE, run_distributed

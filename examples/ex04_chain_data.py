@@ -2,7 +2,7 @@
 (BASELINE.json config 1, reference analogue examples/Ex04_ChainData.jdf).
 Each T(k) reads its own tile A(k) and accumulates into the flowing X.
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 SRC = """
 %global NT
@@ -21,7 +21,7 @@ END
 """
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.data.matrix import TiledMatrix

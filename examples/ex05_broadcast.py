@@ -4,7 +4,7 @@
 workers; the reference rides its chain/binomial trees for the distributed
 version, remote_dep.c:322-360.)
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 SRC = """
 %global W
@@ -41,7 +41,7 @@ END
 """
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.data.matrix import TiledMatrix

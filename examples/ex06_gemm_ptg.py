@@ -1,5 +1,5 @@
 """Ex06: tiled GEMM as a PTG with a TPU body (BASELINE config 2)."""
-from _common import maybe_force_cpu
+from _common import setup
 
 SRC = """
 %global MT
@@ -20,12 +20,13 @@ GEMM(m, n, k)
   RW   C <- (k == 0) ? descC(m, n) : C GEMM(m, n, k-1)
        -> (k < KT-1) ? C GEMM(m, n, k+1) : descC(m, n)
 BODY [type=TPU]
-  C = C + jnp.dot(A, B, preferred_element_type=jnp.float32)
+  C = C + jnp.dot(A, B, precision=lax.Precision.HIGHEST,
+                  preferred_element_type=jnp.float32)
 END
 """
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.data.matrix import TiledMatrix

@@ -1,8 +1,8 @@
 """Ex07: tiled Cholesky through the dynamic interface (BASELINE config 3)."""
-from _common import maybe_force_cpu
+from _common import setup
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import parsec_tpu as pt
     from parsec_tpu.data.matrix import TiledMatrix

@@ -3,7 +3,7 @@ READ tasks, cross-rank dataflow over multicast trees, fourcounter
 termination. The DPLASMA idiom on in-process ranks (the same program runs
 unchanged over a multi-host transport on a pod).
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 SRC = """
 %global MT
@@ -49,7 +49,7 @@ END
 """
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     from parsec_tpu.comm.remote_dep import RemoteDepEngine
     from parsec_tpu.comm.threads import ThreadsCE, run_distributed

@@ -5,14 +5,14 @@ ep (routed MoE), and sp (ring attention) against its single-device
 reference — the scaling-book recipe end to end: pick a mesh, annotate
 shardings, let XLA insert the collectives.
 
-    EXAMPLES_CPU=1 XLA_FLAGS=--xla_force_host_platform_device_count=8 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8 \
         python examples/ex10_parallelism_modes.py
 """
-from _common import maybe_force_cpu
+from _common import setup
 
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
 
     from parsec_tpu.parallel.moe import (dense_reference, init_moe_params,

@@ -14,11 +14,11 @@ import sys
 import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from _common import maybe_force_cpu  # noqa: E402
+from _common import setup  # noqa: E402
 
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
 
     import parsec_tpu as pt

@@ -22,11 +22,11 @@ import tempfile
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from _common import maybe_force_cpu  # noqa: E402
+from _common import setup  # noqa: E402
 
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
     import optax
 

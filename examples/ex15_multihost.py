@@ -20,11 +20,11 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from _common import maybe_force_cpu  # noqa: E402
+from _common import setup  # noqa: E402
 
 
 def controller():
-    maybe_force_cpu()
+    setup()
     import jax
     from parsec_tpu.parallel.multihost import (fetch_replicated,
                                                global_mesh, init_multihost)

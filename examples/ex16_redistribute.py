@@ -11,9 +11,9 @@ Run: python examples/ex16_redistribute.py
 
 import numpy as np
 
-from _common import maybe_force_cpu
+from _common import setup
 
-maybe_force_cpu()
+setup()
 
 import parsec_tpu as pt                                   # noqa: E402
 from parsec_tpu.data.matrix import TiledMatrix            # noqa: E402

@@ -22,11 +22,11 @@ import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from _common import maybe_force_cpu  # noqa: E402
+from _common import setup  # noqa: E402
 
 
 def main():
-    maybe_force_cpu()
+    setup()
     import numpy as np
 
     from parsec_tpu.comm.tcp import run_distributed_procs

@@ -84,7 +84,9 @@ constexpr int H_READY = 1;    // batch-lane ready-push -> drain-pop wait
 constexpr int N_HISTS = 2;
 const char *const HIST_NAMES[N_HISTS] = {"exec_ns", "ready_wait_ns"};
 
-constexpr Py_ssize_t PT_FLOWS_MAX = 64;
+// sizes two stack arrays per insert. 256 admits the fused k-chain GEMM task
+// (1 + 2*kt flows) up to kt = 127; 64 refused the 32x32-tile harness shape
+constexpr Py_ssize_t PT_FLOWS_MAX = 256;
 
 struct TaskRec {
     int32_t deps_remaining = 1;   // the insertion-in-progress guard
@@ -445,7 +447,8 @@ PyObject *engine_insert(PyObject *obj, PyObject *args) {
     // failure after linking flow 0 would leave successor edges (and
     // possibly tile.last_writer) pointing at a popped — soon reused — id
     if (nflows > PT_FLOWS_MAX) {
-        PyErr_SetString(PyExc_ValueError, "too many flows (max 64)");
+        PyErr_Format(PyExc_ValueError, "too many flows (max %zd)",
+                     PT_FLOWS_MAX);
         return nullptr;
     }
     int64_t tixs[PT_FLOWS_MAX];
@@ -590,7 +593,8 @@ PyObject *engine_register_class(PyObject *obj, PyObject *args) {
     n = PySequence_Fast_GET_SIZE(fast);
     if (n > PT_FLOWS_MAX) {
         Py_DECREF(fast);
-        PyErr_SetString(PyExc_ValueError, "too many flows (max 64)");
+        PyErr_Format(PyExc_ValueError, "too many flows (max %zd)",
+                     PT_FLOWS_MAX);
         return nullptr;
     }
     for (Py_ssize_t i = 0; i < n; i++) {

@@ -12,7 +12,6 @@ construction, so aggregate throughput measures pure runtime machinery
 scale-out, not communication.
 """
 
-import os
 import sys
 import time
 
@@ -20,9 +19,6 @@ EP_SOURCE = "%global NT\nEP(i)\n  i = 0 .. NT-1\nBODY\n  pass\nEND\n"
 
 
 def main() -> None:
-    if os.environ.get("PARSEC_TPU_FORCE_CPU") == "1":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     ntasks = int(sys.argv[1]) if len(sys.argv) > 1 else 20000
 
     from parsec_tpu.comm.remote_dep import RemoteDepEngine

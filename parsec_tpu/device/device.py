@@ -27,7 +27,11 @@ from ..utils import mca, output
 
 mca.register("device_load_balance_skew", 20,
              "Percent skew tolerated before moving work off the affine device", type=int)
-mca.register("device_load_balance_allow_cpu", True,
+# off by default: the CPU device never accumulates load (its chores run
+# inline), so against a deep accelerator queue it always looks idle — a
+# wide tile DAG (~1k queued tiles) would tip every further
+# accelerator-capable task onto the host
+mca.register("device_load_balance_allow_cpu", False,
              "Allow spilling accelerator-capable tasks to the CPU device", type=bool)
 mca.register("device_tpu_enabled", True, "Enable the TPU device module", type=bool)
 mca.register("device_recursive_enabled", True,
@@ -115,12 +119,9 @@ class DeviceRegistry:
             from .recursive import RecursiveDevice
             self.add(RecursiveDevice())  # device 1, like the reference
         if mca.get("device_tpu_enabled", True):
-            try:
-                from .tpu import discover_tpu_devices
-                for dev in discover_tpu_devices():
-                    self.add(dev)
-            except Exception as e:  # pragma: no cover - jax should be present
-                output.warning(f"TPU device discovery failed: {e}")
+            from .tpu import discover_tpu_devices
+            for dev in discover_tpu_devices():
+                self.add(dev)
 
     def add(self, dev: DeviceModule) -> DeviceModule:
         dev.device_index = len(self.devices)
@@ -200,7 +201,7 @@ class DeviceRegistry:
                         return d
         # min estimated time of availability
         skew = 1.0 + mca.get("device_load_balance_skew", 20) / 100.0
-        allow_cpu = mca.get("device_load_balance_allow_cpu", True)
+        allow_cpu = mca.get("device_load_balance_allow_cpu", False)
         best, best_eta = None, float("inf")
         for d in candidates:
             eta = d.device_load + d.time_estimate(task)

@@ -51,8 +51,10 @@ def main(argv=None) -> int:
                   + (f"  [{flows}]" if flows else "  [flowless]"))
 
     if opts.globals:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
+        # counting a task space needs no device: skip accelerator
+        # discovery so the checker never claims a chip
+        from ...utils import mca
+        mca.set("device_tpu_enabled", False)
         from ...core.context import Context
         from .compiler import PTGProgram
         g = {}

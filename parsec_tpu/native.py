@@ -1,8 +1,11 @@
 """ctypes bindings for the native C++ core (native/src/ptcore.cpp).
 
-The library is built on demand with the in-tree Makefile; every binding has
-a pure-Python fallback so the framework works without a toolchain. Wired-in
-fast paths:
+The library is built on demand with the in-tree Makefile. Inside the
+library a missing artifact degrades to the interpreted engine with a
+WARNING (docs/native_exec.md: ~100x slower); the entry points that claim to
+exercise the lanes — ``chip_smoke.py``, ``parsec_tpu.launch`` — call
+:func:`require_all`, which rebuilds incrementally and raises instead.
+Wired-in fast paths:
 
 * :class:`NativeDepTable` — the dependency-update engine
   (parsec_update_deps_with_mask role) behind ``Taskpool.update_deps`` for
@@ -51,19 +54,41 @@ _lib_lock = threading.Lock()
 _KEY_MAX = 16
 
 
+def build() -> None:
+    """Run the in-tree (incremental) native build for the RUNNING
+    interpreter; raises with the compiler's output on failure."""
+    import sys
+    r = subprocess.run(["make", "-C", _NATIVE_DIR, f"-j{os.cpu_count() or 1}",
+                        f"PYTHON={sys.executable}"],
+                       capture_output=True, text=True, timeout=600)
+    if r.returncode != 0:
+        raise RuntimeError(f"native build failed (make -C {_NATIVE_DIR}):\n"
+                           f"{r.stderr[-2000:]}")
+
+
 def _build() -> bool:
     try:
-        import sys
-        r = subprocess.run(["make", "-C", _NATIVE_DIR,
-                            f"PYTHON={sys.executable}"],
-                           capture_output=True, text=True, timeout=120)
-        if r.returncode != 0:
-            output.debug_verbose(1, "native", f"build failed: {r.stderr[-500:]}")
-            return False
-        return os.path.exists(_SO)
-    except Exception as e:  # noqa: BLE001
-        output.debug_verbose(1, "native", f"build error: {e}")
+        build()
+        return True
+    except (OSError, RuntimeError, subprocess.TimeoutExpired) as e:
+        output.warning(f"native build unavailable, interpreted engine in "
+                       f"use: {e}")
         return False
+
+
+def require_all() -> None:
+    """The smoke/launcher rule: what runs is what the source tree holds.
+    Always run ``make`` (a no-op when up to date — an existing ``.so``
+    older than ``native/src`` is rebuilt, never trusted), then load all six
+    artifacts; any build or load failure raises."""
+    if os.path.exists(os.path.join(_NATIVE_DIR, "Makefile")):
+        build()
+    missing = [name for name, loader in (
+        ("ptcore", load), ("ptdtd", load_ptdtd), ("ptexec", load_ptexec),
+        ("ptcomm", load_ptcomm), ("ptsched", load_ptsched),
+        ("ptdev", load_ptdev)) if loader() is None]
+    if missing:
+        raise RuntimeError(f"native artifacts failed to load: {missing}")
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -87,7 +112,7 @@ def load() -> Optional[ctypes.CDLL]:
         try:
             lib = ctypes.CDLL(so)
         except OSError as e:
-            output.debug_verbose(1, "native", f"dlopen failed: {e}")
+            output.warning(f"native core dlopen failed: {e}")
             return None
         # signatures
         lib.pt_dep_table_create.restype = ctypes.c_void_p
@@ -167,8 +192,8 @@ def _load_pyext(stem: str, cache):
                 output.debug_verbose(1, "native",
                                      f"{stem} loaded from {so}")
             except Exception as e:  # noqa: BLE001
-                output.debug_verbose(1, "native",
-                                     f"{stem} load failed: {e}")
+                output.warning(f"native extension {stem} failed to "
+                               f"load, interpreted engine in use: {e}")
             return cache[0]
         finally:
             cache[1] = True

@@ -38,7 +38,7 @@ def tile_gemm_chain(c, a_stack, b_stack):
     device_gpu.c:2229): a whole k-chain of compatible GEMM tasks collapses
     into one device call. Backed by the Pallas kernel
     (:func:`parsec_tpu.ops.pallas_kernels.gemm_chain`) which keeps C in
-    VMEM across all k steps; falls back to a lax.scan inside that module.
+    VMEM across all k steps.
     """
     from .pallas_kernels import gemm_chain
     return gemm_chain(c, a_stack, b_stack)
@@ -53,8 +53,7 @@ def insert_gemm_tasks(tp: DTDTaskpool, A: TiledMatrix, B: TiledMatrix,
     fused scan body — fewer, bigger device dispatches (the TPU-first answer
     to per-tile task overhead). ``batch`` additionally marks the tasks
     batchable so the device module may collapse up to device_tpu_batch_max
-    compatible ready tasks into one vmapped dispatch (essential when
-    per-dispatch latency is high, e.g. a remote chip).
+    compatible ready tasks into one vmapped dispatch.
     Returns the number of inserted tasks.
     """
     mt, nt, kt = C.mt, C.nt, A.nt

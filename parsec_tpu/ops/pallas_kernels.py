@@ -19,9 +19,9 @@ chore path:
   resident per head. Positional offsets make it usable on rotated ring
   blocks (`parallel/ring_attention.py`) and sequence-sharded shards.
 
-Every entry point degrades gracefully: on non-TPU backends the kernels run
-in interpreter mode (tests), and any Pallas failure falls back to the XLA
-expression of the same math.
+On an accelerator backend every kernel compiles through Mosaic or raises —
+there is no quiet XLA route. Interpreter mode is used only when the backend
+is ``cpu`` (tests, rehearsal). :func:`verify_lowering` is the compile gate.
 """
 
 from __future__ import annotations
@@ -32,10 +32,6 @@ from typing import Optional, Tuple
 import numpy as np
 
 from ..utils import mca
-
-mca.register("pallas_strict", False,
-             "Fail loudly instead of falling back to XLA when a Pallas "
-             "kernel cannot lower/run (the CI compile gate)", type=bool)
 
 mca.register("tile_dot_precision", "highest",
              "MXU pass count for float32 tile dots: 'default' (fast bf16 "
@@ -55,97 +51,64 @@ def dot_precision():
                 name, jax.lax.Precision.HIGHEST)
 
 
-def _backend() -> str:
-    import jax
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
-
-
 def _interpret() -> bool:
-    return _backend() not in ("tpu",)
-
-
-_warned_fallbacks: set = set()
-
-
-def _fallback(kernel_name: str, err, reason: str = None) -> None:
-    """A Pallas failure must never be invisible: strict mode re-raises
-    (the CI compile gate), default mode warns ONCE per kernel before the
-    XLA fallback runs. ``err=None`` with a ``reason`` marks a deliberate
-    shape-based routing decision (not a failure) — never a strict-mode
-    error, but still warned once so the path is visible."""
-    from ..utils import mca, output
-    if err is None:
-        key = f"{kernel_name}:routed"
-        if key not in _warned_fallbacks:
-            _warned_fallbacks.add(key)
-            output.warning(f"pallas kernel {kernel_name!r} routed to XLA: "
-                           f"{reason}")
-        return
-    if mca.get("pallas_strict", False):
-        raise RuntimeError(
-            f"pallas kernel {kernel_name!r} failed to lower/run "
-            f"(pallas_strict=1): {err}") from err
-    if kernel_name not in _warned_fallbacks:
-        _warned_fallbacks.add(kernel_name)
-        output.warning(f"pallas kernel {kernel_name!r} fell back to XLA: "
-                       f"{type(err).__name__}: {err}")
-
-
-def verify_lowering(shapes=((256, 256, 256), ), kt: int = 4) -> dict:
-    """Compile-only gate: lower every kernel for the CURRENT backend (real
-    Mosaic lowering on TPU, interpreter elsewhere) and FAIL LOUDLY on any
-    error instead of silently falling back. Returns {kernel: 'ok'|error}.
-
-    Run under pallas_strict in CI / at bench startup so a Mosaic breakage
-    on real hardware is a red build, not a quiet perf regression."""
+    """Interpreter mode only on the CPU backend; anywhere else a kernel is
+    a real Mosaic compile."""
     import jax
-    import numpy as np
+    return jax.default_backend() == "cpu"
+
+
+def _mxu_precision(dtype):
+    """``dot_precision()`` for f32 operands; None for narrower dtypes, which
+    the MXU multiplies natively in one pass — Mosaic refuses an fp32
+    contract precision on bf16 operands ("Bad lhs type")."""
+    import jax.numpy as jnp
+    return dot_precision() if jnp.dtype(dtype) == jnp.float32 else None
+
+
+def verify_lowering(shapes=((256, 256, 256), ), kt: int = 4,
+                    dtypes=("float32",)) -> dict:
+    """Compile-only gate: lower and compile every kernel for the CURRENT
+    backend (real Mosaic on an accelerator, the interpreter on cpu) at each
+    (m, k, n) of ``shapes`` and each dtype, without executing. Raises with
+    the compiler's own messages if any kernel fails; returns
+    {kernel: 'ok'} otherwise."""
+    import jax
     results = {}
     interp = _interpret()
-    errors = []
-    f32 = np.float32
-    for m, k, n in shapes:
-        checks = {
-            f"gemm_chain[{m}x{k}x{n}]": (
-                lambda m=m, k=k, n=n: _gemm_chain_call(
-                    kt, m, k, n, "float32", interp),
-                (jax.ShapeDtypeStruct((m, n), f32),
-                 jax.ShapeDtypeStruct((kt, m, k), f32),
-                 jax.ShapeDtypeStruct((kt, k, n), f32))),
-            f"matmul[{m}x{k}x{n}]": (
-                lambda m=m, k=k, n=n: _matmul_call(
-                    m, n, k, min(m, 256), min(n, 256), min(k, 256),
-                    "float32", interp),
-                (jax.ShapeDtypeStruct((m, k), f32),
-                 jax.ShapeDtypeStruct((k, n), f32))),
-            f"stencil1d[{n}]": (
-                lambda n=n: _stencil_call(
-                    8, n, (0.25, 0.5, 0.25), "float32", interp),
-                (jax.ShapeDtypeStruct((8, n), f32),
-                 jax.ShapeDtypeStruct((8, n), f32),
-                 jax.ShapeDtypeStruct((8, n), f32))),
-            "flash_attention[2x256x128]": (
-                lambda: _flash_attn_call(
-                    2, 256, 256, 128, 128, 128, True, 0.088388,
-                    0, 0, "float32", interp, None),
-                (jax.ShapeDtypeStruct((2, 256, 128), f32),
-                 jax.ShapeDtypeStruct((2, 256, 128), f32),
-                 jax.ShapeDtypeStruct((2, 256, 128), f32))),
-        }
-        for name, (build, args) in checks.items():
-            try:
-                # lower+compile without executing (the compile-only part)
-                jax.jit(build()).lower(*args).compile()
-                results[name] = "ok"
-            except Exception as e:  # noqa: BLE001 - collected and re-raised
-                results[name] = f"{type(e).__name__}: {e}"
-                errors.append(name)
+    for dtype in dtypes:
+        prec = _mxu_precision(dtype)
+
+        def sds(*shape):
+            return jax.ShapeDtypeStruct(shape, dtype)
+
+        for m, k, n in shapes:
+            checks = {
+                f"gemm_chain[{kt}x{m}x{k}x{n},{dtype}]": (
+                    _gemm_chain_call(kt, m, k, n, dtype, interp, prec),
+                    (sds(m, n), sds(kt, m, k), sds(kt, k, n))),
+                f"matmul[{m}x{k}x{n},{dtype}]": (
+                    _matmul_call(m, n, k, min(m, 256), min(n, 256),
+                                 min(k, 256), dtype, interp, prec),
+                    (sds(m, k), sds(k, n))),
+                f"stencil1d[8x{n},{dtype}]": (
+                    _stencil_call(8, n, (0.25, 0.5, 0.25), dtype, interp),
+                    (sds(8, n), sds(8, n), sds(8, n))),
+                f"flash_attention[2x{m}x128,{dtype}]": (
+                    _flash_attn_call(2, m, m, 128, min(m, 128), min(m, 128),
+                                     True, 0.088388, 0, 0, dtype, interp,
+                                     None),
+                    (sds(2, m, 128), sds(2, m, 128), sds(2, m, 128))),
+            }
+            for name, (call, args) in checks.items():
+                try:
+                    call.lower(*args).compile()
+                    results[name] = "ok"
+                except Exception as e:  # noqa: BLE001 - collected, re-raised
+                    results[name] = f"{type(e).__name__}: {e}"
+    errors = {k: v for k, v in results.items() if v != "ok"}
     if errors:
-        raise RuntimeError(f"pallas lowering FAILED for {errors}: "
-                           f"{ {k: results[k] for k in errors} }")
+        raise RuntimeError(f"pallas lowering FAILED: {errors}")
     return results
 
 
@@ -159,7 +122,6 @@ def _gemm_chain_call(kt: int, ts_m: int, ts_k: int, ts_n: int, dtype: str,
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     def kernel(c_ref, a_ref, b_ref, out_ref):
         k = pl.program_id(0)
@@ -189,26 +151,11 @@ def _gemm_chain_call(kt: int, ts_m: int, ts_k: int, ts_n: int, dtype: str,
 
 def gemm_chain(c, a_stack, b_stack):
     """C += sum_k A[k] @ B[k]; one kernel, C resident in VMEM throughout."""
-    import jax.numpy as jnp
     kt, ts_m, ts_k = a_stack.shape
     ts_n = b_stack.shape[2]
-    try:
-        call = _gemm_chain_call(kt, ts_m, ts_k, ts_n, str(c.dtype),
-                                _interpret(), dot_precision())
-        return call(c, a_stack, b_stack)
-    except Exception as e:  # noqa: BLE001
-        _fallback("gemm_chain", e)
-        # XLA fallback: scan keeps the accumulator in registers too
-        import jax
-
-        def step(acc, ab):
-            a, b = ab
-            return acc + jnp.dot(a, b, precision=dot_precision(),
-                                 preferred_element_type=jnp.float32
-                                 ).astype(acc.dtype), None
-
-        out, _ = jax.lax.scan(step, c, (a_stack, b_stack))
-        return out
+    dtype = str(c.dtype)
+    return _gemm_chain_call(kt, ts_m, ts_k, ts_n, dtype, _interpret(),
+                            _mxu_precision(dtype))(c, a_stack, b_stack)
 
 
 # ---------------------------------------------------------------------------
@@ -248,7 +195,8 @@ def _matmul_call(m: int, n: int, k: int, bm: int, bn: int, bk: int,
 
 
 def matmul(a, b, block: Tuple[int, int, int] = (256, 256, 256)):
-    """Blocked A @ B; falls back to jnp.dot on shape mismatch or error."""
+    """Blocked A @ B. Shapes the blocks do not divide are the caller's
+    property, not a kernel failure: they take the plain ``jnp.dot``."""
     import jax.numpy as jnp
     m, k = a.shape
     n = b.shape[1]
@@ -256,13 +204,9 @@ def matmul(a, b, block: Tuple[int, int, int] = (256, 256, 256)):
     if m % bm or n % bn or k % bk:
         return jnp.dot(a, b, precision=dot_precision(),
                        preferred_element_type=jnp.float32).astype(a.dtype)
-    try:
-        return _matmul_call(m, n, k, bm, bn, bk, str(a.dtype),
-                            _interpret(), dot_precision())(a, b)
-    except Exception as e:  # noqa: BLE001
-        _fallback("matmul", e)
-        return jnp.dot(a, b, precision=dot_precision(),
-                       preferred_element_type=jnp.float32).astype(a.dtype)
+    dtype = str(a.dtype)
+    return _matmul_call(m, n, k, bm, bn, bk, dtype, _interpret(),
+                        _mxu_precision(dtype))(a, b)
 
 
 # ---------------------------------------------------------------------------
@@ -295,30 +239,8 @@ def _stencil_call(rows: int, cols: int, w: Tuple[float, float, float],
 def stencil1d(x, left, right, weights=(0.25, 0.5, 0.25)):
     """Fused 3-point stencil; ``left``/``right`` are the neighbor tiles
     (pass zero tiles at the domain boundary)."""
-    try:
-        call = _stencil_call(x.shape[0], x.shape[1], tuple(weights),
-                             str(x.dtype), _interpret())
-        return call(x, left, right)
-    except Exception as e:  # noqa: BLE001
-        _fallback("stencil1d", e)
-        import jax.numpy as jnp
-        w0, w1, w2 = weights
-        xm = jnp.concatenate([left[:, -1:], x[:, :-1]], axis=1)
-        xp = jnp.concatenate([x[:, 1:], right[:, :1]], axis=1)
-        return (w0 * xm + w1 * x + w2 * xp).astype(x.dtype)
-
-
-def _sds(jax, shape, dtype, vma=None):
-    """``ShapeDtypeStruct`` with a version-tolerant ``vma``: newer jax
-    types shard_map-varying outputs through the kwarg; older jax has no
-    VMA checker at all, so dropping it there is the correct degrade
-    (passing even ``vma=None`` raises TypeError on those versions)."""
-    if vma:
-        try:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=set(vma))
-        except TypeError:
-            pass
-    return jax.ShapeDtypeStruct(shape, dtype)
+    return _stencil_call(x.shape[0], x.shape[1], tuple(weights),
+                         str(x.dtype), _interpret())(x, left, right)
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +328,8 @@ def _flash_attn_call(bh: int, sq: int, sk: int, d: int, bq: int, bk: int,
             pl.BlockSpec((1, bk, d), lambda b, iq, kk: (b, kk, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda b, iq, kk: (b, iq, 0)),
-        out_shape=_sds(jax, (bh, sq, d), dtype, vma),
+        out_shape=(jax.ShapeDtypeStruct((bh, sq, d), dtype, vma=set(vma))
+                   if vma else jax.ShapeDtypeStruct((bh, sq, d), dtype)),
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),     # acc
             pltpu.VMEM((bq, 128), jnp.float32),   # running max (lanes equal)
@@ -429,10 +352,8 @@ def flash_attention(q, k, v, causal: bool = False, scale: float = None,
     ``shard_map``, pass ``vma=(axis, ...)`` so the output is typed as
     device-varying. Sequence lengths not divisible by the block sizes
     shrink the blocks to the largest divisor (a caller-shape property,
-    handled here — never a silent fallback). The XLA fallback is reserved
-    for Pallas LOWERING/runtime failures raised at trace/call time — a
-    Mosaic error surfacing later, at an OUTER jit's compile, is out of
-    reach by design; :func:`verify_lowering` is the gate for that class."""
+    handled here); only a degenerate divisor takes the dense XLA
+    expression, chosen from the shapes. A Pallas failure raises."""
     import jax.numpy as jnp
     q4 = q.reshape((-1,) + q.shape[-2:])
     k4 = k.reshape((-1,) + k.shape[-2:])
@@ -475,20 +396,12 @@ def flash_attention(q, k, v, causal: bool = False, scale: float = None,
     # A prime/odd sequence length degrades the largest divisor toward 1,
     # which is below TPU tile granularity — a severe Pallas perf cliff or a
     # Mosaic trace failure. Below _MIN_BLOCK (unless the block IS the whole
-    # sequence), the dense XLA path is the better program: take it
-    # deliberately, not via the exception fallback.
+    # sequence), the dense XLA path is the better program.
     _MIN_BLOCK = 8
     if (bq < _MIN_BLOCK < sq) or (bk < _MIN_BLOCK < sk):
-        _fallback("flash_attention", None,
-                  reason=f"block degenerated (bq={bq}, bk={bk}) for seq "
-                         f"lens ({sq}, {sk}); dense XLA path is faster")
         return _dense(q4, k4, v4).reshape(q.shape)
-    try:
-        out = _flash_attn_call(bhn, sq, sk, d, bq, bk, bool(causal),
-                               float(scale), int(q_offset), int(k_offset),
-                               str(q.dtype), _interpret(),
-                               tuple(vma) if vma else None)(q4, k4, v4)
-    except Exception as e:  # noqa: BLE001
-        _fallback("flash_attention", e)
-        out = _dense(q4, k4, v4)
+    out = _flash_attn_call(bhn, sq, sk, d, bq, bk, bool(causal),
+                           float(scale), int(q_offset), int(k_offset),
+                           str(q.dtype), _interpret(),
+                           tuple(vma) if vma else None)(q4, k4, v4)
     return out.reshape(q.shape)

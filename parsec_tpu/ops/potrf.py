@@ -104,6 +104,25 @@ def make_spd(n: int, seed: int = 0, dtype=np.float32) -> np.ndarray:
     return spd.astype(dtype)
 
 
+def spd_tile(n: int, ts: int, m: int, k: int, seed: int = 0,
+             dtype=np.float32) -> np.ndarray:
+    """Tile (m, k) of a seeded, well-conditioned n x n SPD matrix, made in
+    O(ts^2) by whoever needs it: S + 3I, S a symmetric Gaussian (Wigner)
+    matrix with off-diagonal variance 1/n, so the spectrum lies in about
+    [1, 5]. :func:`make_spd` costs an n^3 float64 host matmul and cannot
+    reach sizes that fill a chip; here every rank builds exactly its own
+    tiles, and a reference builds the same matrix from the same seed."""
+    lo, hi = max(m, k), min(m, k)
+    g = np.random.default_rng((seed, lo, hi)).standard_normal(
+        (ts, ts), dtype=np.float32) / np.float32(np.sqrt(n))
+    if m == k:
+        g = (g + g.T) / np.float32(np.sqrt(2.0)) \
+            + 3.0 * np.eye(ts, dtype=np.float32)
+    elif m < k:
+        g = g.T
+    return g.astype(dtype, copy=False)
+
+
 # --------------------------------------------------------------- SPD solve
 
 def tile_trsv_l(lkk, bk):

@@ -86,7 +86,7 @@ def dense_reference(params, x, k: int = 1):
 def _moe_call(mesh, capacity: int, experts_per_dev: int, k: int):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]

@@ -96,7 +96,7 @@ def init_multihost(coordinator: Optional[str] = None,
                      else os.environ.get(ENV_PROC, "0"))
     if num_processes > 1:
         plats = str(getattr(jax.config, "jax_platforms", "") or "")
-        if plats.startswith("cpu") or os.environ.get("PARSEC_TPU_FORCE_CPU"):
+        if plats.startswith("cpu"):
             _enable_cpu_collectives()
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
@@ -166,11 +166,10 @@ def run_multicontroller(nprocs: int, script: str,
     procs = []
     for pid in range(nprocs):
         env = dict(os.environ)
-        env.pop("JAX_PLATFORMS", None)
+        env["JAX_PLATFORMS"] = "cpu"
         env[ENV_COORD] = coord
         env[ENV_PROC] = str(pid)
         env[ENV_NPROC] = str(nprocs)
-        env["PARSEC_TPU_FORCE_CPU"] = "1"
         # replace (not append after) any inherited device-count flag: the
         # caller may itself run under a virtual-device env, and relying on
         # last-flag-wins is fragile

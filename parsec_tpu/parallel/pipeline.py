@@ -65,7 +65,7 @@ def _pipe_stages_call(mesh, n_micro: int, stage_fn: Callable,
     per stage-pytree structure (jax's own trace cache handles shapes)."""
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]

@@ -63,7 +63,7 @@ def _ring_call(mesh, causal: bool, block: int, scale: float):
     hashable; jit's own cache handles the remaining shape signature)."""
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]
@@ -132,7 +132,7 @@ def ring_attention(q, k, v, mesh=None, causal: bool = False,
 def _ulysses_call(mesh, causal: bool, scale: float):
     import jax
     import jax.numpy as jnp
-    from .compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axis = mesh.axis_names[0]

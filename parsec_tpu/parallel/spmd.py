@@ -82,7 +82,7 @@ def distributed_gemm(A, B, mesh=None, dtype=None):
     jax = _jax()
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
+    from jax import shard_map
 
     if mesh is None:
         mesh = make_mesh()
@@ -111,10 +111,8 @@ def distributed_gemm(A, B, mesh=None, dtype=None):
             return (a, b, acc), None
 
         acc = jnp.zeros((a.shape[0], b.shape[1]), jnp.float32)
-        if hasattr(jax.lax, "pcast"):
-            # newer jax: type the replicated zeros as device-varying for
-            # the VMA checker; old jax has no VMA system (nothing to cast)
-            acc = jax.lax.pcast(acc, ("p", "q"), to="varying")
+        # type the replicated zeros as device-varying for the VMA checker
+        acc = jax.lax.pcast(acc, ("p", "q"), to="varying")
         (_, _, acc), _ = jax.lax.scan(step, (a, b, acc), None, length=T)
         return acc.astype(a_blk.dtype if dtype is None else dtype)
 
@@ -133,7 +131,7 @@ def distributed_gemm_allgather(A, B, mesh=None, dtype=None):
     jax = _jax()
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from .compat import shard_map
+    from jax import shard_map
 
     if mesh is None:
         mesh = make_mesh()
@@ -167,7 +165,7 @@ def distributed_potrf(A, mesh=None, block: Optional[int] = None):
     jax = _jax()
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from .compat import shard_map
+    from jax import shard_map
 
     if mesh is None:
         mesh = make_mesh()

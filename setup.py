@@ -1,20 +1,17 @@
 """Build hooks for the native pieces (metadata lives in pyproject.toml).
 
-Two native artifacts ship inside the wheel:
+Six native artifacts ship inside the wheel: the C-ABI core
+``parsec_tpu._ptcore`` (dep table / zone allocator; native/src/ptcore.cpp,
+loaded via ctypes — built as an Extension for a portable compile+install
+path) and the five CPython-extension lanes ``_ptdtd``, ``_ptexec``,
+``_ptcomm``, ``_ptsched``, ``_ptdev``. parsec_tpu/native.py searches the
+package directory first, then the in-tree native/build/.
 
-* ``parsec_tpu._ptdtd`` — the CPython-extension DTD dependency engine
-  (native/src/ptdtd.cpp), a standard Extension.
-* ``parsec_tpu._ptcore`` — the C-ABI core (dep table / zone allocator /
-  deque; native/src/ptcore.cpp), loaded via ctypes. Building it as an
-  Extension is deliberate: it needs no Python symbols, but the Extension
-  machinery gives a portable compile+install path and ctypes can dlopen an
-  ABI-suffixed .so just fine (parsec_tpu/native.py searches the package
-  directory first, then the in-tree native/build/).
-
-Both are OPTIONAL: the runtime falls back to pure Python when they are
-missing, so a toolchain-less install still works (``--no-build-isolation``
-environments, exotic platforms). The reference's analogue is the CMake
-feature probe tree (CMakeLists.txt:1): features degrade, builds don't fail.
+A missing toolchain does not fail the install: the library then warns and
+runs its interpreted engines (~100x slower, docs/native_exec.md). That
+degrade is for the library only — ``chip_smoke.py`` and
+``python -m parsec_tpu.launch`` call ``native.require_all()`` and refuse to
+run without all six.
 """
 
 from setuptools import Extension, setup
@@ -29,7 +26,7 @@ class optional_build_ext(build_ext):
             super().run()
         except Exception as e:  # noqa: BLE001
             print(f"WARNING: native extensions skipped ({e}); "
-                  f"parsec_tpu will use its pure-Python fallbacks")
+                  f"parsec_tpu will warn and run its interpreted engines")
 
     def build_extension(self, ext):
         try:

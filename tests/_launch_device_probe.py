@@ -11,9 +11,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 
 def main():
-    if os.environ.get("PARSEC_TPU_FORCE_CPU") == "1":
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import numpy as np
 
     from parsec_tpu.comm.remote_dep import RemoteDepEngine

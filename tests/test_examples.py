@@ -31,7 +31,7 @@ def test_example_runs(ex):
     if ex == "ex15" and not cpu_collectives_available():
         pytest.skip("multiprocess CPU collectives unavailable in this jax")
     fname = [f for f in os.listdir(EX_DIR) if f.startswith(ex)][0]
-    env = dict(os.environ, EXAMPLES_CPU="1", JAX_PLATFORMS="cpu")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run([sys.executable, fname], cwd=EX_DIR, env=env,
                          capture_output=True, text=True, timeout=110)
     assert out.returncode == 0, out.stderr[-2000:]
@@ -40,7 +40,7 @@ def test_example_runs(ex):
 def test_example_tcp_launch():
     """Ex09 goes through the real multi-process launcher CLI."""
     fname = "ex09_tcp_launch.py"
-    env = dict(os.environ, EXAMPLES_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "parsec_tpu.launch", "-n", "2", "--cpu",
          os.path.join("examples", fname)],
@@ -53,7 +53,7 @@ def test_example_tcp_launch():
 def test_example_device_mem_comms():
     """Ex14: device-native cross-rank payloads via the launcher's --mca."""
     fname = "ex14_device_mem_comms.py"
-    env = dict(os.environ, EXAMPLES_CPU="1")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     out = subprocess.run(
         [sys.executable, "-m", "parsec_tpu.launch", "-n", "2", "--cpu",
          "--mca", "comm_device_mem", "1", os.path.join("examples", fname)],
