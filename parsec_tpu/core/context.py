@@ -159,6 +159,9 @@ class Context:
         #: scheduler policy has no native flavor (counted fallback)
         from .sched_plane import SchedPlane
         self.sched_plane = SchedPlane.maybe_create(self)
+        #: the per-task device path's spans (utils/xla_trace.py Spans);
+        #: None when off, armed below once the histograms' verdict is in
+        self._spans = None
         # device registry (lazy import to avoid cycles)
         from ..device.device import DeviceRegistry
         self.devices = DeviceRegistry(self)
@@ -254,6 +257,13 @@ class Context:
         #: percentiles); off = one null branch per lane event site
         self._hist_on = bool(mca.get("hist_enabled", False)) or \
             self.metrics is not None
+        if self._hist_on or mca.get("profile_xla_dir", ""):
+            from ..utils.xla_trace import Spans
+            self._spans = Spans()
+            for kind, obj in self._spans.hists:
+                self._hist_attach(kind, obj)
+            for dev in self.devices.devices:
+                dev.attach(self)    # the TPU modules pick the spans up
         #: lane stall watchdog (core/watchdog.py): armed by --mca
         #: watchdog_stall_ms; reads existing counters only (the PR 13
         #: no-new-hot-path contract), degrades /health on a latched
@@ -522,6 +532,11 @@ class Context:
         if self.sched_plane is not None:
             # same lifecycle for the plane's queue-wait histogram
             self._hist_detach(self.sched_plane.plane)
+        if self._spans is not None:
+            # and for the span histograms: folded, so /metrics and the
+            # benchmark's readers still see them after fini
+            for _kind, obj in self._spans.hists:
+                self._hist_detach(obj)
         # persist the online cost model (ISSUE 18) alongside the warm-
         # executable cache's lifecycle: a restarted serving process loads
         # it back at its first placement decision and starts warm
@@ -571,6 +586,9 @@ class Context:
                 if t.priority:
                     self._prio_seen = True
                     break
+        sp = self._spans
+        if sp is not None:
+            sp.stamp(tasks)     # ready-wait starts here
         stream = stream or self._current_stream()
         if self.pins.enabled:
             self.pins.fire(pins_mod.SCHEDULE_BEGIN, stream, tasks)

@@ -39,6 +39,7 @@ from ..core.task import (DEV_TPU, FLOW_ACCESS_CTL, FLOW_ACCESS_WRITE,
                          HOOK_ASYNC, HOOK_DONE, Task)
 from ..data.data import COHERENCY_INVALID, COHERENCY_OWNED, COHERENCY_SHARED, Data, DataCopy
 from ..utils import mca, output
+from ..utils.xla_trace import DEV_POLL, DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT
 from .device import DeviceModule
 
 mca.register("device_tpu_max_bytes", 0,
@@ -106,6 +107,10 @@ class TPUDevice(DeviceModule):
         self.batched_dispatches = 0
         self._prof_stream = None
         self._prof_keys = None
+        #: the context's span object (utils/xla_trace.py Spans): None when
+        #: off, so every site is one attribute load and one branch
+        self._spans = None
+        self._retired_ns = 0        # dev.retire total, for dev.poll to subtract
         self._lru: "collections.OrderedDict[Any, DataCopy]" = collections.OrderedDict()
         self._lru_sizes: Dict[Any, int] = {}   # accounted bytes per key
         self._lru_segs: Dict[Any, Any] = {}    # key -> pt_zone segment
@@ -139,6 +144,10 @@ class TPUDevice(DeviceModule):
         # mutates it from lane stage-ins — compound updates like the
         # resident-bytes delta are not GIL-atomic across both
         self._heap_lock = threading.RLock()
+
+    def attach(self, context) -> None:
+        super().attach(context)
+        self._spans = context._spans
 
     # ------------------------------------------------- native coherency map
     @staticmethod
@@ -300,6 +309,12 @@ class TPUDevice(DeviceModule):
             # head-of-line block completed peers behind it (ref: per-stream
             # event polls, device_gpu.c:2593,2944,3179)
             still: Deque[TPUTask] = collections.deque()
+            sp = self._spans if self._inflight else None
+            if sp is not None:
+                # one record per pass that polls, the epilogs' own spans
+                # (nested inside on the timeline) subtracted
+                tok = sp.begin(DEV_POLL)
+                retired0 = self._retired_ns
             while self._inflight:
                 gt = self._inflight.popleft()
                 if gt.out_arrays and not all(a.is_ready() for a in gt.out_arrays):
@@ -308,6 +323,8 @@ class TPUDevice(DeviceModule):
                 self._epilog(stream, gt)
                 completed += 1
             self._inflight = still
+            if sp is not None:
+                sp.end(tok, sp.poll, less=self._retired_ns - retired0)
             return completed
         finally:
             self._manager_lock.release()
@@ -357,24 +374,31 @@ class TPUDevice(DeviceModule):
         src = newest
         if src is None:
             raise RuntimeError(f"no valid copy to stage in for {data!r}")
-        arr = self._jax.device_put(src.payload, self.jax_device)  # async H2D/D2D
-        nbytes = _nbytes(arr)
-        if self._ncoh is None:
-            self._reserve(nbytes)   # native mode: stage_in reserved above
-        if copy is None:
-            copy = data.create_copy(dev_idx, arr, COHERENCY_SHARED)
-        else:
-            copy.payload = arr
-            copy.coherency_state = COHERENCY_SHARED
-        copy.version = src.version
-        self.transfer_in_bytes += nbytes
-        self._lru_touch(self.res_key(data), copy)
-        if pin:
-            if self._ncoh is not None:
-                with self._heap_lock:
-                    copy.readers += 1     # table half pinned in stage_in
+        sp = self._spans
+        if sp is not None:
+            tok = sp.begin(DEV_STAGE_IN)    # a miss: the host cost of one H2D
+        try:
+            arr = self._jax.device_put(src.payload, self.jax_device)  # async H2D/D2D
+            nbytes = _nbytes(arr)
+            if self._ncoh is None:
+                self._reserve(nbytes)   # native mode: stage_in reserved above
+            if copy is None:
+                copy = data.create_copy(dev_idx, arr, COHERENCY_SHARED)
             else:
-                self.pin_copy(copy)
+                copy.payload = arr
+                copy.coherency_state = COHERENCY_SHARED
+            copy.version = src.version
+            self.transfer_in_bytes += nbytes
+            self._lru_touch(self.res_key(data), copy)
+            if pin:
+                if self._ncoh is not None:
+                    with self._heap_lock:
+                        copy.readers += 1     # table half pinned in stage_in
+                else:
+                    self.pin_copy(copy)
+        finally:
+            if sp is not None:
+                sp.end(tok, sp.stage_in)
         return copy
 
     def lane_stage_in(self, data: Data, pin: bool = False) -> DataCopy:
@@ -422,8 +446,17 @@ class TPUDevice(DeviceModule):
             from ..utils.trace import EVENT_FLAG_START
             ps.trace(self._prof_keys[0], hash(task.key) & 0x7FFFFFFF,
                      task.taskpool.taskpool_id, EVENT_FLAG_START)
-        inputs = self._gather_inputs(gt)
-        outs = gt.submit(self, task, inputs)
+        sp = self._spans
+        if sp is not None:
+            tok = sp.begin(DEV_SUBMIT)
+        try:
+            inputs = self._gather_inputs(gt)
+            outs = gt.submit(self, task, inputs)
+        finally:
+            if sp is not None:
+                sp.end(tok, sp.submit)      # a failed attempt's cost too
+        if sp is not None:
+            sp.ready_wait(task)     # issued: ready-wait ends, once per task
         if outs is None:
             outs = ()
         elif not isinstance(outs, (tuple, list)):
@@ -506,11 +539,16 @@ class TPUDevice(DeviceModule):
         """One dispatch for a batch of compatible independent tasks; ragged
         batches (e.g. boundary tiles of a different shape) fall back to
         per-task submission. Returns the tasks actually dispatched."""
+        sp = self._spans
+        if sp is not None:
+            tok = sp.begin(DEV_SUBMIT)
         try:
             inputs_list = [self._gather_inputs(g) for g in group]
             outs_list = group[0].batch_submit(self, [g.task for g in group],
                                               inputs_list)
         except Exception as e:  # noqa: BLE001 - ragged shapes, stage-in OOM
+            if sp is not None:
+                sp.end(tok, None)   # the per-task retries record their own
             output.debug_verbose(2, "device",
                                  f"batch of {len(group)} fell back: {e}")
             # unpin EVERY member (a stage-in failure mid-gather leaves
@@ -518,6 +556,11 @@ class TPUDevice(DeviceModule):
             for g in group:
                 self._unpin(g)
             return [g for g in group if self._submit_one_retry(g)]
+        if sp is not None:
+            # one dispatch, recorded once per member at its share
+            sp.end(tok, sp.submit, n=len(group))
+            for g in group:
+                sp.ready_wait(g.task)
         for g, outs in zip(group, outs_list):
             if outs is None:
                 outs = ()
@@ -531,6 +574,9 @@ class TPUDevice(DeviceModule):
         bump versions, OWNED->SHARED transitions, then complete the task."""
         task = gt.task
         tc = task.task_class
+        sp = self._spans
+        if sp is not None:
+            tok = sp.begin(DEV_RETIRE)
         outs = list(gt.out_arrays or ())
         oi = 0
         for flow in tc.flows:
@@ -568,6 +614,8 @@ class TPUDevice(DeviceModule):
         if gt.complete_cb is not None:
             gt.complete_cb(gt)
         self.context and self.context.complete_task_execution(stream, task)
+        if sp is not None:
+            self._retired_ns += sp.end(tok, sp.retire)
 
     def _stage_out(self, data: Data, copy: DataCopy) -> None:
         """D2H write-back (ref: stage_out device_gpu.c:1674 + w2r task)."""

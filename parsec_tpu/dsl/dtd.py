@@ -48,6 +48,7 @@ from ..data.collection import DataCollection
 from ..data.data import COHERENCY_OWNED, Data, data_from_array
 from ..device.tpu import TPUDevice, make_tpu_hook
 from ..utils import mca, output
+from ..utils.xla_trace import DTD_LINK, DTD_STALL
 
 # access flags for insert_task args (ref: PARSEC_INPUT/OUTPUT/INOUT | AFFINITY)
 READ = FLOW_ACCESS_READ
@@ -331,6 +332,10 @@ class DTDTaskpool(Taskpool):
         #: the master stream's drain loop
         self._insert_lock = threading.RLock()
         self._stall_lock = threading.Lock()
+        #: the context's span object (utils/xla_trace.py Spans), None when
+        #: off: dtd.link around the locked insert, dtd.stall around the
+        #: window stall
+        self._spans = context._spans
         self.inserted = 0
         self.local_inserted = 0   # tasks this rank actually executes
         self.window_stalls = 0    # inserter blocked on the task window
@@ -992,22 +997,31 @@ class DTDTaskpool(Taskpool):
             return
         if self.ctx.in_progress_loop():
             return              # mid-body insert: never block flow control
-        self._flush_ready()
-        self.window_stalls += 1
-        self.ctx.start()
-        while self.local_inserted - self.executed > self.window_size:
-            if self.ctx._error is not None:
-                return
-            if self._stall_lock.acquire(blocking=False):
-                try:
-                    target = self.local_inserted - self.threshold_size
-                    self.ctx._progress_loop(
-                        self.ctx.streams[0],
-                        until=lambda: self.executed >= target)
-                finally:
-                    self._stall_lock.release()
-                return
-            time.sleep(50e-6)   # another user thread is draining
+        sp = self._spans
+        if sp is not None:
+            # dtd.stall: the inserter draining tasks or sleeping; every
+            # device span the drain runs nests inside
+            tok = sp.begin(DTD_STALL)
+        try:
+            self._flush_ready()
+            self.window_stalls += 1
+            self.ctx.start()
+            while self.local_inserted - self.executed > self.window_size:
+                if self.ctx._error is not None:
+                    return
+                if self._stall_lock.acquire(blocking=False):
+                    try:
+                        target = self.local_inserted - self.threshold_size
+                        self.ctx._progress_loop(
+                            self.ctx.streams[0],
+                            until=lambda: self.executed >= target)
+                    finally:
+                        self._stall_lock.release()
+                    return
+                time.sleep(50e-6)   # another user thread is draining
+        finally:
+            if sp is not None:
+                sp.end(tok, sp.stall)
 
     def _admission_stall(self) -> None:
         """Admission backpressure (ISSUE 9): the scheduler plane reported
@@ -1103,8 +1117,19 @@ class DTDTaskpool(Taskpool):
                         self._admission_stall()
                 return None
         with self._insert_lock:
-            task = self._insert_task_locked(fn, args, priority, where, name,
-                                            jit, batch)
+            sp = self._spans
+            if sp is None:
+                task = self._insert_task_locked(fn, args, priority, where,
+                                                name, jit, batch)
+            else:
+                # dtd.link: the whole locked insert (class lookup, tile
+                # chains, engine link, ready buffering), no window stall
+                tok = sp.begin(DTD_LINK)
+                try:
+                    task = self._insert_task_locked(fn, args, priority,
+                                                    where, name, jit, batch)
+                finally:
+                    sp.end(tok, sp.link)
         self._window_stall()
         if not nowait:
             self._admission_stall()

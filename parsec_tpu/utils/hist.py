@@ -8,7 +8,9 @@ module mirrors the bucket scheme, sums snapshots across every live lane
 object (plus lanes that already finished — their buckets are accumulated
 at detach, like the trace bridge's drop accounting), and summarizes
 p50/p99/p999 for the counter registry, ``live_view``, and the
-``/metrics`` endpoint (tools/metrics_server.py).
+``/metrics`` endpoint (tools/metrics_server.py). :class:`PyHistograms`
+is the same thing recorded from Python, for paths no lane covers: the
+per-task device path's spans (``utils/xla_trace.py``).
 
 Bucket scheme (must mirror pthist.h): values < 8 ns map exactly to
 buckets 0..7; above that the index is ``(exponent, top-3-mantissa-bits)``
@@ -41,6 +43,8 @@ SUB_BITS = 3
 SUBS = 1 << SUB_BITS
 NBUCKETS = (64 - SUB_BITS + 1) * SUBS          # 496, mirrors pthist.h
 _BUCKET_FMT = f"<{NBUCKETS}Q"
+#: a HistCell buckets its raw records this often (bounds what it holds)
+_FOLD_AT = 1024
 
 #: the histogram names each lane kind exports (hist_snapshot() keys)
 HIST_NAMES: Dict[str, Tuple[str, ...]] = {
@@ -48,6 +52,10 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     "ptdtd": ("exec_ns", "ready_wait_ns"),
     "ptcomm": ("rdv_rtt_ns", "act_queue_ns"),
     "sched": ("queue_ns",),     # plane push->pop wait (ISSUE 9)
+    # the per-task device path's spans (utils/xla_trace.py Spans), recorded
+    # from Python into PyHistograms below
+    "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns"),
+    "dtd": ("link_ns", "stall_ns"),
 }
 
 
@@ -123,6 +131,76 @@ def _max_bucket(buckets: List[int]) -> float:
         if buckets[i]:
             return bucket_mid(i)
     return 0.0
+
+
+class HistCell:
+    """One histogram of a :class:`PyHistograms` set: count, sum and the
+    496 log2 buckets of ``bucket_index``. :meth:`record` only appends the
+    raw value — a list append is atomic under the GIL, so sites on
+    different threads (two devices' managers, two inserting user threads)
+    share a cell without a lock on the hot path — and the bucket math
+    waits for :meth:`fold` (every ``_FOLD_AT`` records and at each
+    snapshot)."""
+
+    __slots__ = ("_mu", "_raw", "count", "sum_ns", "buckets")
+
+    def __init__(self, mu: threading.Lock) -> None:
+        self._mu = mu
+        self._raw: List[int] = []
+        self.count = 0
+        self.sum_ns = 0
+        self.buckets = [0] * NBUCKETS
+
+    def record(self, ns: int, n: int = 1) -> None:
+        """``n`` observations of ``ns`` nanoseconds."""
+        raw = self._raw
+        if n == 1:
+            raw.append(ns)
+        else:
+            raw.extend([ns] * n)
+        if len(raw) >= _FOLD_AT:
+            with self._mu:
+                self.fold()
+
+    def fold(self) -> None:
+        """Bucket what was recorded since the last fold (the set's lock
+        held). The copy and the prefix delete are each atomic, and a
+        concurrent append lands behind the prefix, so none is lost."""
+        raw = self._raw
+        taken = raw[:]
+        del raw[:len(taken)]
+        buckets = self.buckets
+        for ns in taken:
+            buckets[bucket_index(ns)] += 1
+        self.count += len(taken)
+        self.sum_ns += sum(taken)
+
+
+class PyHistograms:
+    """A set of named histograms recorded from Python, in pthist.h's
+    bucket scheme and behind the registry's protocol (``hist_enable`` /
+    ``hist_snapshot``), so :meth:`NativeHistograms.attach`, ``detach``,
+    ``snapshot`` and ``summaries`` treat it like a native lane object.
+    It exists only where recording is on, so arming is a no-op."""
+
+    def __init__(self, names: Tuple[str, ...]) -> None:
+        self._mu = threading.Lock()
+        self._cells = {name: HistCell(self._mu) for name in names}
+
+    def cell(self, name: str) -> HistCell:
+        return self._cells[name]
+
+    def hist_enable(self) -> None:
+        pass
+
+    def hist_snapshot(self) -> Dict[str, Tuple[int, int, bytes]]:
+        out = {}
+        with self._mu:
+            for name, c in self._cells.items():
+                c.fold()
+                out[name] = (c.count, c.sum_ns,
+                             struct.pack(_BUCKET_FMT, *c.buckets))
+        return out
 
 
 class NativeHistograms:

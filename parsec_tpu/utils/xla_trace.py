@@ -12,15 +12,24 @@ Usage::
         ... run taskpools ...
 
 or annotate spans manually through :class:`TaskAnnotator` (a PINS module).
+
+:class:`Spans` is the runtime's own instrumentation of the per-task device
+path: six named spans in ``device/tpu.py`` and ``dsl/dtd.py``, each a
+``TraceAnnotation`` on the profiler's host plane and a duration in a
+``utils/hist.py`` histogram, plus the ready-wait interval. One object per
+``Context``, ``None`` when off, so a site is ``sp = self._spans`` /
+``if sp is not None:``.
 """
 
 from __future__ import annotations
 
 import contextlib
-from typing import Iterator, Optional
+from time import perf_counter_ns
+from typing import Iterator, List, Optional, Tuple
 
 from ..core import pins as P
 from . import mca, output
+from .hist import HIST_NAMES, HistCell, PyHistograms
 
 mca.register("profile_xla_dir", "", "Capture a jax.profiler trace into this dir")
 
@@ -41,10 +50,88 @@ def xla_trace(logdir: Optional[str] = None) -> Iterator[None]:
         output.inform(f"XLA trace captured to {logdir}")
 
 
+#: span names as they stand on the profiler's host plane
+DTD_LINK, DTD_STALL = "dtd.link", "dtd.stall"
+DEV_SUBMIT, DEV_STAGE_IN = "dev.submit", "dev.stage_in"
+DEV_POLL, DEV_RETIRE = "dev.poll", "dev.retire"
+
+
+class Spans:
+    """The spans of one context. ``tok = sp.begin(NAME)`` ...
+    ``sp.end(tok, sp.<cell>)``, entered and left on one thread in strict
+    nesting (legal TraceMe nesting). Outside a profiler session no
+    annotation is made and only the histogram records."""
+
+    def __init__(self) -> None:
+        from jax.profiler import TraceAnnotation
+
+        from ..dsl.dtd import DTDTask
+        self._annotation = TraceAnnotation
+        self._tracing = TraceAnnotation.is_enabled
+        self._dtd_task = DTDTask
+        tpudev = PyHistograms(HIST_NAMES["tpudev"])
+        dtd = PyHistograms(HIST_NAMES["dtd"])
+        # DTD pools' ready -> issued wait joins the native engine's
+        # histogram of the same name under the registry key
+        # ``ptdtd.ready_wait_ns`` (the engine arms its own on the batched
+        # lane only, which refuses contexts with an accelerator)
+        ready = PyHistograms(("ready_wait_ns",))
+        self.submit = tpudev.cell("submit_ns")
+        self.stage_in = tpudev.cell("stage_in_ns")
+        self.poll = tpudev.cell("poll_ns")
+        self.retire = tpudev.cell("retire_ns")
+        self.link = dtd.cell("link_ns")
+        self.stall = dtd.cell("stall_ns")
+        self._ready = ready.cell("ready_wait_ns")
+        #: (registry kind, object) for Context._hist_attach/_hist_detach
+        self.hists: List[Tuple[str, PyHistograms]] = [
+            ("tpudev", tpudev), ("dtd", dtd), ("ptdtd", ready)]
+
+    def begin(self, name: str):
+        ann = None
+        if self._tracing():
+            ann = self._annotation(name)
+            ann.__enter__()
+        return ann, perf_counter_ns()
+
+    def end(self, tok, cell: Optional[HistCell], less: int = 0,
+            n: int = 1) -> int:
+        """Close the span and record its nanoseconds, ``less`` what nested
+        spans already recorded, as ``n`` equal observations (a batched
+        dispatch counts once per member; ``cell=None`` records nothing).
+        Returns the whole duration."""
+        dt = perf_counter_ns() - tok[1]
+        if tok[0] is not None:
+            tok[0].__exit__(None, None, None)
+        if cell is not None:
+            cell.record((dt - less) // n, n)
+        return dt
+
+    def stamp(self, tasks) -> None:
+        """``Context.schedule``: the moment these tasks became ready. A
+        task scheduled again (an OOM bounce) keeps its first stamp."""
+        now = perf_counter_ns()
+        for t in tasks:
+            if t.prof_info is None:
+                t.prof_info = now
+
+    def ready_wait(self, task) -> None:
+        """The task's first successful submit: ready -> issued, once per
+        executed task, for DTD pools."""
+        t0 = task.prof_info
+        if t0 is not None and isinstance(task, self._dtd_task):
+            task.prof_info = None
+            self._ready.record(perf_counter_ns() - t0)
+
+
 class TaskAnnotator:
     """PINS module: wrap task execution in jax.profiler.TraceAnnotation so
     device kernels group under their task names in the timeline (the NVTX
-    range push/pop role)."""
+    range push/pop role). The interval is ``EXEC_BEGIN..EXEC_END``, fired
+    once per task on every lane that has Python task objects (the native
+    engine's per-task lane included). For a device task that is the
+    *enqueue* (the hook returns ``HOOK_ASYNC``), not the submit:
+    :data:`DEV_SUBMIT` marks the dispatch."""
 
     name = "xla_annotator"
 

@@ -30,3 +30,105 @@ def test_xla_trace_capture(tmp_path):
 def test_xla_trace_noop_without_dir():
     with xla_trace(None):
         pass  # must be a clean no-op
+
+
+def test_task_annotator_fires_once_per_task_on_the_native_per_task_lane():
+    """A DTD pool on a context with an accelerator device takes the native
+    engine's per-task lane (the batched lane refuses it); EXEC_BEGIN/END —
+    the enqueue, for a device task — fire once per task there."""
+    from parsec_tpu.utils import mca
+
+    class Counting(TaskAnnotator):
+        begun = ended = 0
+
+        def _begin(self, stream, task, extra):
+            Counting.begun += 1
+            super()._begin(stream, task, extra)
+
+        def _end(self, stream, task, extra):
+            Counting.ended += 1
+            super()._end(stream, task, extra)
+
+    mca.set("device_tpu_over_cpu", True)
+    try:
+        ctx = Context(nb_cores=1)
+        ann = Counting()
+        ann.enable(ctx)
+        tp = DTDTaskpool(ctx, "xt-native")
+        t = tp.tile_new((8, 8), np.float32)
+
+        def body(x):
+            return x * 1.5
+
+        for _ in range(12):
+            tp.insert_task(body, (t, RW))
+        tp.wait(); tp.close(); ctx.wait()
+        native, batched = tp._neng is not None, tp._batch_on
+        ann.disable(ctx)
+        ctx.fini()
+    finally:
+        mca.params.unset("device_tpu_over_cpu")
+    if not native:
+        import pytest
+        pytest.skip("native _ptdtd unavailable")
+    assert not batched
+    assert Counting.begun == Counting.ended == 12 and not ann._open
+
+
+def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path):
+    """A jax.profiler trace of a small DTD pool with the spans on: the
+    span names are TraceMe events of a host plane, and on each thread any
+    two of them are disjoint or one inside the other."""
+    from jax.profiler import ProfileData
+
+    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.utils import mca
+    from parsec_tpu.utils import xla_trace as X
+
+    params = {"device_tpu_over_cpu": True, "hist_enabled": True,
+              "dtd_window_size": 8, "dtd_threshold_size": 4}
+    for k, v in params.items():
+        mca.set(k, v)
+    try:
+        ctx = Context(nb_cores=1)
+        A = TiledMatrix("XS", 64, 16, 16, 16)
+        A.fill(lambda m, n: np.ones((16, 16), np.float32))
+        with xla_trace(str(tmp_path)):
+            tp = DTDTaskpool(ctx, "xt-spans")
+
+            def body(x):
+                return x + 1.0
+
+            for i in range(32):
+                tp.insert_task(body, (tp.tile_of(A, i % 4, 0), RW))
+            tp.wait(); tp.close(); ctx.wait()
+        ctx.fini()
+    finally:
+        for k in params:
+            mca.params.unset(k)
+    names = {X.DTD_LINK, X.DTD_STALL, X.DEV_SUBMIT, X.DEV_STAGE_IN,
+             X.DEV_POLL, X.DEV_RETIRE}
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    seen, parents = {}, {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = sorted(((ev.start_ns, ev.start_ns + ev.duration_ns, ev.name)
+                          for ev in line.events if ev.name in names),
+                         key=lambda e: (e[0], -e[1]))
+            stack = []
+            for s, e, name in evs:
+                assert plane.name.startswith("/host:"), plane.name
+                seen[name] = seen.get(name, 0) + 1
+                while stack and stack[-1][1] <= s:
+                    stack.pop()
+                if stack:
+                    assert e <= stack[-1][1], (name, stack[-1][2])
+                    parents.setdefault(name, set()).add(stack[-1][2])
+                stack.append((s, e, name))
+    assert set(seen) == names
+    assert seen[X.DTD_LINK] == seen[X.DEV_SUBMIT] == seen[X.DEV_RETIRE] == 32
+    assert seen[X.DEV_STAGE_IN] == 4
+    assert parents[X.DEV_STAGE_IN] == {X.DEV_SUBMIT}
+    assert parents[X.DEV_RETIRE] == {X.DEV_POLL}
+    assert X.DTD_STALL in parents[X.DEV_SUBMIT]
