@@ -54,6 +54,9 @@ class DeviceModule:
         self.executed_tasks = 0
         self.transfer_in_bytes = 0
         self.transfer_out_bytes = 0
+        # programs that carried several tasks, and the tasks in them
+        self.batched_dispatches = 0
+        self.batched_tasks = 0
         self._lock = threading.Lock()
 
     # -- lifecycle ------------------------------------------------------------
@@ -220,6 +223,8 @@ class DeviceRegistry:
                 "executed_tasks": d.executed_tasks,
                 "transfer_in_bytes": d.transfer_in_bytes,
                 "transfer_out_bytes": d.transfer_out_bytes,
+                "batched_dispatches": d.batched_dispatches,
+                "batched_tasks": d.batched_tasks,
                 "load": d.device_load,
             }
             for d in self.devices

@@ -22,8 +22,10 @@ XLA/PJRT execution model:
   tracked in an LRU; exceeding the byte budget evicts clean (non-owned) copies
   first, then writes back owned ones (the w2r task role, transfer_gpu.c).
 * Task batching (parsec_gpu_task_collect_batch, device_gpu.c:2229,
-  docs/doxygen/task-batching.md): compatible queued tasks are handed to a
-  batch hook in one dispatch when the task class opts in.
+  docs/doxygen/task-batching.md): pending tasks of one class are handed to
+  the class's group hook as ONE program when the class opted in
+  (``batchable``) or when the manager observes that the host, not the chip,
+  paces the class (:meth:`TPUDevice._observe`).
 """
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ from __future__ import annotations
 import collections
 import os
 import threading
+import weakref
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -42,13 +45,24 @@ from ..utils import mca, output
 from ..utils.xla_trace import DEV_POLL, DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT
 from .device import DeviceModule
 
+#: the sizes a multi-task program comes in (capped by
+#: ``device_tpu_batch_max``): a pending run of 7 goes as 4 + 2 + 1, so a
+#: class compiles at most these programs whatever the timing of a run
+GROUP_LADDER = (16, 8, 4, 2)
+#: programs of a class found complete one host cycle after their submit, in
+#: a row, before the class is taken as paced by the host. On the v5e a
+#: program shows complete ~0.4 ms after its call returns however short it
+#: is, about the host's cycle per task, so two in three singles pass and a
+#: longer streak would rarely form; a program behind a backlog never passes
+PACED_STREAK = 4
+
 mca.register("device_tpu_max_bytes", 0,
              "HBM tile-heap budget in bytes (0 = 75% of the device's "
              "reported bytes_limit)", type=int)
 mca.register("device_tpu_max_inflight", 64,
              "Max concurrently dispatched device tasks", type=int)
 mca.register("device_tpu_batch_max", 16,
-             "Max compatible tasks collapsed into one batched dispatch", type=int)
+             "Max tasks of one class issued as one program", type=int)
 mca.register("device_tpu_over_cpu", False,
              "TEST MODE: register the device module over a host jax device",
              type=bool)
@@ -62,7 +76,7 @@ class TPUTask:
 
     __slots__ = ("task", "submit", "stage_in", "stage_out", "pushout",
                  "batchable", "batch_submit", "load", "out_arrays",
-                 "complete_cb", "oom_retries", "pinned")
+                 "complete_cb", "oom_retries", "pinned", "issued")
 
     def __init__(self, task: Task, submit: Callable, stage_in=None,
                  stage_out=None, pushout: int = 0, batchable: bool = False,
@@ -72,11 +86,15 @@ class TPUTask:
         self.stage_in = stage_in      # optional override (ref: custom stage, stage_custom.jdf)
         self.stage_out = stage_out
         self.pushout = pushout        # bitmask of flows to push back to host now
+        #: the class opted in: grouped whatever the manager observes
         self.batchable = batchable
-        #: batch_submit(device, tasks, inputs_list) -> list of output tuples;
-        #: compatible queued tasks collapse into one dispatch
+        #: batch_submit(device, tasks, inputs_list) -> list of output tuples,
+        #: ONE program for the whole group; None = never grouped
         #: (ref: parsec_gpu_task_collect_batch, device_gpu.c:2229)
         self.batch_submit = batch_submit
+        #: the manager pass that issued the program this task leads,
+        #: until the program is judged (:meth:`TPUDevice._observe`)
+        self.issued = 0
         self.load = 0.0
         self.out_arrays: Optional[Sequence[Any]] = None
         self.complete_cb: Optional[Callable] = None
@@ -100,11 +118,17 @@ class TPUDevice(DeviceModule):
         # task-class time_estimate properties
         self.gflops = 100_000.0
         self._pending: Deque[TPUTask] = collections.deque()
-        self._inflight: Deque[TPUTask] = collections.deque()
+        #: issued programs, oldest first: the tasks each one carries
+        self._inflight: Deque[List[TPUTask]] = collections.deque()
+        self._inflight_tasks = 0
+        self._pass = 0              # manager passes, for _observe
         self._manager_lock = threading.Lock()  # the CAS mutex (device_gpu.c:3408)
         self._fifo_lock = threading.Lock()
+        #: task class -> its programs judged complete in a row
+        #: (:meth:`_observe`); weak, so a closed pool's classes leave
+        self._paced: "weakref.WeakKeyDictionary[Any, int]" = \
+            weakref.WeakKeyDictionary()
         # LRU tile heap bookkeeping (ref: gpu_mem_lru / gpu_mem_owned_lru)
-        self.batched_dispatches = 0
         self._prof_stream = None
         self._prof_keys = None
         #: the context's span object (utils/xla_trace.py Spans): None when
@@ -250,9 +274,62 @@ class TPUDevice(DeviceModule):
         self.load_add(tpu_task.load)
         with self._fifo_lock:
             self._pending.append(tpu_task)
-        # opportunistically become the manager right away
-        self.progress(stream)
+        # a task that waits for companions stays in _pending: the progress
+        # loop this thread runs polls the device after its burst of hooks
+        # and issues what the burst enqueued, grouped. Anyone else
+        # opportunistically becomes the manager right away
+        if not (self._groups(tpu_task) and self.context.in_progress_loop()):
+            self.progress(stream)
         return HOOK_ASYNC
+
+    # ------------------------------------------------------------- batching
+    def _groups(self, gt: TPUTask) -> bool:
+        """Is ``gt`` issued with companions of its class? The one batching
+        policy: the class opted in, or the host paces it."""
+        return gt.batch_submit is not None and (
+            gt.batchable or
+            self._paced.get(gt.task.task_class, 0) >= PACED_STREAK)
+
+    def _observe(self, gt: TPUTask, complete: bool) -> None:
+        """Judge a program once, at the poll of the first later pass that
+        issued something: the host has come round with the next program.
+        Complete by then: the program was shorter than the host's cycle,
+        the chip sat idle through the call just made, and a call saved is
+        time saved; a streak of those and the class is issued in groups.
+        Not complete: the chip has work queued, waiting for companions
+        would only delay it, and the class goes back to a program a task."""
+        gt.issued = 0
+        if gt.batch_submit is None:
+            return
+        tc = gt.task.task_class
+        if complete:
+            self._paced[tc] = self._paced.get(tc, 0) + 1
+        elif tc in self._paced:
+            del self._paced[tc]
+
+    def group_sizes(self) -> List[int]:
+        """The sizes a multi-task program may come in here, largest first."""
+        cap = mca.get("device_tpu_batch_max", 16)
+        return [k for k in GROUP_LADDER if k <= cap]
+
+    def _collect(self, gt: TPUTask) -> List[TPUTask]:
+        """``gt`` and the pending tasks of its class, in arrival order, up to
+        the largest ladder size they fill (fifo lock held). Pending tasks
+        are mutually independent (dependencies release at epilog), so
+        taking a class across the pending window reorders nothing that
+        matters (ref: parsec_gpu_task_collect_batch)."""
+        tc, hook = gt.task.task_class, gt.batch_submit
+        mates = [p for p in self._pending
+                 if p.task.task_class is tc and p.batch_submit == hook]
+        size = next((k for k in self.group_sizes() if k <= 1 + len(mates)), 1)
+        if size == 1:
+            return [gt]
+        del mates[size - 1:]
+        taken = set(map(id, mates))
+        rest = [p for p in self._pending if id(p) not in taken]
+        self._pending.clear()
+        self._pending.extend(rest)
+        return [gt] + mates
 
     # ------------------------------------------------------------- progress
     def progress(self, stream) -> int:
@@ -270,45 +347,34 @@ class TPUDevice(DeviceModule):
         if not self._manager_lock.acquire(blocking=False):
             return 0
         try:
-            completed = 0
-            max_inflight = mca.get("device_tpu_max_inflight", 64)
             # kernel_push + kernel_exec phases (device_gpu.c:2746,2874)
-            batch_max = mca.get("device_tpu_batch_max", 16)
-            while len(self._inflight) < max_inflight:
+            self._pass += 1
+            issued = False
+            max_inflight = mca.get("device_tpu_max_inflight", 64)
+            while self._inflight_tasks < max_inflight:
                 with self._fifo_lock:
                     if not self._pending:
                         break
-                    head = self._pending[0]
-                    # batchable head while the device is busy: let the batch
-                    # accumulate — deferral is free, the chip has work
-                    # (the collect discipline of parsec_gpu_task_collect_batch)
-                    if (head.batchable and head.batch_submit is not None and
-                            self._inflight and
-                            len(self._pending) < batch_max):
-                        break
                     gt = self._pending.popleft()
-                    group = [gt]
-                    # collect compatible pending tasks into one dispatch
-                    # (ref: parsec_gpu_task_collect_batch)
-                    if gt.batchable and gt.batch_submit is not None:
-                        while (self._pending and len(group) < batch_max and
-                               self._pending[0].batchable and
-                               self._pending[0].batch_submit == gt.batch_submit and
-                               self._pending[0].task.task_class is gt.task.task_class):
-                            group.append(self._pending.popleft())
+                    group = self._collect(gt) if self._groups(gt) else [gt]
                 if len(group) > 1:
-                    submitted = self._submit_group(group)
-                    if len(submitted) == len(group):
-                        self.batched_dispatches += 1
+                    programs = self._submit_group(group)
                 else:
-                    submitted = group if self._submit_one_retry(gt) else []
-                self._inflight.extend(submitted)
-            # event polling + kernel_pop/epilog: poll each task's events
-            # independently — inflight tasks are mutually independent (their
-            # deps only release at epilog), so one slow kernel must not
-            # head-of-line block completed peers behind it (ref: per-stream
-            # event polls, device_gpu.c:2593,2944,3179)
-            still: Deque[TPUTask] = collections.deque()
+                    programs = [group] if self._submit_one_retry(gt) else []
+                for program in programs:
+                    program[0].issued = self._pass
+                    self._inflight_tasks += len(program)
+                self._inflight.extend(programs)
+                issued |= bool(programs)
+            # event polling + kernel_pop/epilog: poll each program's events
+            # independently — inflight programs are mutually independent
+            # (their deps only release at epilog), so one slow kernel must
+            # not head-of-line block completed peers behind it (ref:
+            # per-stream event polls, device_gpu.c:2593,2944,3179). The
+            # outputs of one program complete together: its first task's
+            # decide for all it carries
+            completed = 0
+            still: Deque[List[TPUTask]] = collections.deque()
             sp = self._spans if self._inflight else None
             if sp is not None:
                 # one record per pass that polls, the epilogs' own spans
@@ -316,12 +382,20 @@ class TPUDevice(DeviceModule):
                 tok = sp.begin(DEV_POLL)
                 retired0 = self._retired_ns
             while self._inflight:
-                gt = self._inflight.popleft()
-                if gt.out_arrays and not all(a.is_ready() for a in gt.out_arrays):
-                    still.append(gt)
+                program = self._inflight.popleft()
+                head = program[0]
+                done = not head.out_arrays or \
+                    all(a.is_ready() for a in head.out_arrays)
+                if issued and head.issued and \
+                        (done or head.issued != self._pass):
+                    self._observe(head, done)
+                if not done:
+                    still.append(program)
                     continue
-                self._epilog(stream, gt)
-                completed += 1
+                for gt in program:
+                    self._epilog(stream, gt)
+                self._inflight_tasks -= len(program)
+                completed += len(program)
             self._inflight = still
             if sp is not None:
                 sp.end(tok, sp.poll, less=self._retired_ns - retired0)
@@ -535,10 +609,11 @@ class TPUDevice(DeviceModule):
                 self.context.schedule([gt.task])
                 return False
 
-    def _submit_group(self, group: List[TPUTask]) -> List[TPUTask]:
-        """One dispatch for a batch of compatible independent tasks; ragged
-        batches (e.g. boundary tiles of a different shape) fall back to
-        per-task submission. Returns the tasks actually dispatched."""
+    def _submit_group(self, group: List[TPUTask]) -> List[List[TPUTask]]:
+        """One program for a group of independent tasks of one class; a
+        ragged group (e.g. boundary tiles of a different shape) or an OOM
+        falls back to per-task submission. Returns the programs actually
+        dispatched, each as the tasks it carries."""
         sp = self._spans
         if sp is not None:
             tok = sp.begin(DEV_SUBMIT)
@@ -550,15 +625,18 @@ class TPUDevice(DeviceModule):
             if sp is not None:
                 sp.end(tok, None)   # the per-task retries record their own
             output.debug_verbose(2, "device",
-                                 f"batch of {len(group)} fell back: {e}")
+                                 f"group of {len(group)} fell back: {e}")
             # unpin EVERY member (a stage-in failure mid-gather leaves
             # earlier members pinned); per-task retries re-gather + re-pin
             for g in group:
                 self._unpin(g)
-            return [g for g in group if self._submit_one_retry(g)]
+            return [[g] for g in group if self._submit_one_retry(g)]
+        self.batched_dispatches += 1
+        self.batched_tasks += len(group)
         if sp is not None:
             # one dispatch, recorded once per member at its share
             sp.end(tok, sp.submit, n=len(group))
+            sp.group_tasks.record(len(group))
             for g in group:
                 sp.ready_wait(g.task)
         for g, outs in zip(group, outs_list):
@@ -567,7 +645,7 @@ class TPUDevice(DeviceModule):
             elif not isinstance(outs, (tuple, list)):
                 outs = (outs,)
             g.out_arrays = tuple(outs)
-        return group
+        return [group]
 
     def _epilog(self, stream, gt: TPUTask) -> None:
         """parsec_device_kernel_epilog (device_gpu.c:3179): attach outputs,

@@ -212,18 +212,34 @@ _jit_cache: Dict[Any, Any] = {}
 _jit_cache_lock = threading.Lock()
 
 
-def _vmapped(fn: Callable):
-    """jit(vmap(fn)) cached per body function (batched dispatch path)."""
-    key = ("__vmap__", fn)
+def _grouped(fn: Callable, k: int):
+    """One flat program for ``k`` independent tasks of the body ``fn``
+    (the device manager's group dispatch), cached per ``(fn, k)``: the
+    operands of the tasks side by side in, one output per task out, each
+    the same function of the same operands as the task's own program.
+    The wrapper carries the body's name, so the XLA module is named
+    ``jit_<body>`` whichever program runs the body."""
+    key = (fn, k)
     j = _jit_cache.get(key)
     if j is None:
         with _jit_cache_lock:
             j = _jit_cache.get(key)
             if j is None:
                 import jax
-                j = jax.jit(jax.vmap(fn))
+
+                def group(*flat):
+                    a = len(flat) // k
+                    return tuple(fn(*flat[i * a:(i + 1) * a])
+                                 for i in range(k))
+                group.__name__ = group.__qualname__ = \
+                    getattr(fn, "__name__", "dtd_task")
+                j = jax.jit(group)
                 _jit_cache[key] = j
     return j
+
+
+#: (body, operand signature) whose ladder of group programs is compiled
+_ladders_built: set = set()
 
 
 _host_dev_cache = [False, None]   # [resolved, device]
@@ -270,8 +286,9 @@ class DTDTaskClass(TaskClass):
         self.flow_accesses = flow_accesses
         #: False for side-effectful bodies (callbacks, host I/O): run eagerly
         self.jit_ok = jit_ok
-        #: True: compatible queued device tasks collapse into one vmapped
-        #: dispatch (ref: dtd GPU batching flag on task-class chores)
+        #: True: pending device tasks of the class are issued together as
+        #: one program whatever the device manager observes (ref: dtd GPU
+        #: batching flag on task-class chores)
         self.batchable = batchable
         for i, acc in enumerate(flow_accesses):
             self.add_flow(Flow(f"f{i}", acc))
@@ -1576,40 +1593,45 @@ class DTDTaskpool(Taskpool):
         return HOOK_DONE
 
     def _tpu_hook(self, stream, task: "DTDTask") -> int:
-        """TPU chore: enqueue on the selected device, with batching metadata
-        (plays the generated GPU hook role, jdf2c.c:6613)."""
+        """TPU chore: enqueue on the selected device, with the group hook of
+        every jittable task (plays the generated GPU hook role,
+        jdf2c.c:6613). Whether tasks are grouped is the manager's call."""
         from ..device.tpu import TPUTask, _run_inline
         dev = task.selected_device
         if dev is None or not isinstance(dev, TPUDevice):
             return _run_inline(stream, task, self._tpu_submit)
         tc: DTDTaskClass = task.task_class
-        batchable = tc.batchable and self._jittable(task)
-        gt = TPUTask(task, self._tpu_submit, batchable=batchable,
-                     batch_submit=self._tpu_batch_submit if batchable else None)
+        jittable = self._jittable(task)
+        gt = TPUTask(task, self._tpu_submit,
+                     batchable=tc.batchable and jittable,
+                     batch_submit=self._tpu_batch_submit if jittable else None)
         return dev.kernel_scheduler(stream, task, tpu_task=gt)
 
     def _tpu_batch_submit(self, device: TPUDevice, tasks: List["DTDTask"],
                           inputs_list: List[List[Any]]):
-        """One vmapped dispatch over a batch of compatible independent tasks
-        (they are mutually independent by construction: only dependency-free
-        tasks sit in the device queue)."""
-        import jax
-        import jax.numpy as jnp
-        tc: DTDTaskClass = tasks[0].task_class
-        vals_list = [self._gather_args(t, inp)
-                     for t, inp in zip(tasks, inputs_list)]
-        stacked = []
-        for i in range(len(vals_list[0])):
-            col = [np.asarray(v) if isinstance(v, (int, float)) else v
-                   for v in (vals[i] for vals in vals_list)]
-            stacked.append(jnp.stack(col))
-        vm = _vmapped(tc.fn)
-        outs = vm(*stacked)
-        if outs is None:
-            return [() for _ in tasks]
-        if not isinstance(outs, (tuple, list)):
-            outs = (outs,)
-        return [tuple(o[i] for o in outs) for i in range(len(tasks))]
+        """One flat program over a group of independent tasks of one class
+        (mutually independent by construction: only dependency-free tasks
+        sit in the device queue). A group whose members differ in operand
+        shapes raises, and the manager issues its tasks one by one."""
+        fn = tasks[0].task_class.fn
+        flat: List[Any] = []
+        for t, inp in zip(tasks, inputs_list):
+            flat += [np.asarray(v) if isinstance(v, (int, float)) else v
+                     for v in self._gather_args(t, inp)]
+        a = len(flat) // len(tasks)
+        sig = tuple((v.shape, v.dtype) for v in flat[:a])
+        for i in range(a, len(flat)):
+            if (flat[i].shape, flat[i].dtype) != sig[i % a]:
+                raise ValueError(f"ragged group of {tasks[0].task_class.name}")
+        if (fn, sig) not in _ladders_built:
+            # the class's first group: build every size a later group may
+            # come in, on this group's first operands, so that which
+            # programs exist never depends on the timing of a run
+            for k in device.group_sizes():
+                _grouped(fn, k)(*flat[:a] * k)
+            _ladders_built.add((fn, sig))
+        outs = _grouped(fn, len(tasks))(*flat)
+        return [tuple(self._apply_outputs(t, o)) for t, o in zip(tasks, outs)]
 
     def _tpu_submit(self, device: TPUDevice, task: DTDTask, inputs: List[Any]):
         """TPU chore body: call the jitted class function on device arrays.

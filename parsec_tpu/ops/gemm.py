@@ -51,9 +51,11 @@ def insert_gemm_tasks(tp: DTDTaskpool, A: TiledMatrix, B: TiledMatrix,
 
     With ``batch_k`` the whole k-chain per C tile becomes ONE task using the
     fused scan body — fewer, bigger device dispatches (the TPU-first answer
-    to per-tile task overhead). ``batch`` additionally marks the tasks
-    batchable so the device module may collapse up to device_tpu_batch_max
-    compatible ready tasks into one vmapped dispatch.
+    to per-tile task overhead). ``batch`` additionally marks the class as
+    grouped on the device whatever its manager observes: up to
+    device_tpu_batch_max pending tasks leave as one flat program (their
+    operands side by side in, one output per task out; nothing is stacked).
+    Without it the manager groups only a class it sees the host pace.
     Returns the number of inserted tasks.
     """
     mt, nt, kt = C.mt, C.nt, A.nt

@@ -54,7 +54,9 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     "sched": ("queue_ns",),     # plane push->pop wait (ISSUE 9)
     # the per-task device path's spans (utils/xla_trace.py Spans), recorded
     # from Python into PyHistograms below
-    "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns"),
+    # ``group_tasks`` is no time: one record per multi-task program, its size
+    "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns",
+               "group_tasks"),
     "dtd": ("link_ns", "stall_ns"),
 }
 

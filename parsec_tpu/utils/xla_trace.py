@@ -80,6 +80,7 @@ class Spans:
         self.stage_in = tpudev.cell("stage_in_ns")
         self.poll = tpudev.cell("poll_ns")
         self.retire = tpudev.cell("retire_ns")
+        self.group_tasks = tpudev.cell("group_tasks")
         self.link = dtd.cell("link_ns")
         self.stall = dtd.cell("stall_ns")
         self._ready = ready.cell("ready_wait_ns")

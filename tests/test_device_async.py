@@ -64,10 +64,12 @@ def test_device_chain_reuses_resident_tiles(dctx):
 
 
 def test_batched_dispatch(dctx):
-    """Independent same-class tasks collapse into vmapped dispatches
-    (ref: parsec_gpu_task_collect_batch). A host device completes work
-    instantly, so the batch window never fills on its own; holding the
-    manager lock during enqueue models a busy chip accumulating work."""
+    """Independent tasks of a ``batch=True`` class leave as flat multi-task
+    programs (ref: parsec_gpu_task_collect_batch): one XLA program takes the
+    operands of the whole group and gives one output per task. The enqueues
+    here happen under the held manager lock, so all eight are pending when
+    the manager next runs (tests/test_device_groups.py has the groups that
+    form by observation, without the flag and without the lock)."""
     dev = _tpu_dev(dctx)
     A = TiledMatrix("AB", 16 * 8, 16, 16, 16)
     A.fill(lambda m, n: np.full((16, 16), float(m), np.float32))

@@ -216,9 +216,9 @@ def test_span_histograms_count_exactly_and_survive_fini(spanned_pool, key):
 
 
 def test_batched_dispatch_counts_once_per_member(mca_params):
-    """One vmapped dispatch of a group records ``submit_ns`` and the
-    ready-wait once per member (the manager lock is held during enqueue so
-    the batch accumulates, as in test_device_async)."""
+    """One multi-task program records ``submit_ns`` and the ready-wait once
+    per member (the manager lock is held during enqueue so the eight are
+    pending together, as in test_device_async)."""
     mca_params("device_tpu_over_cpu", True)
     mca_params("hist_enabled", True)
     ctx = Context(nb_cores=1)
