@@ -281,8 +281,9 @@ def phase_ptg_gemm(args, jax, report):
     from parsec_tpu.data.matrix import TiledMatrix
     from parsec_tpu.device.native import PTDEV_STATS
     from parsec_tpu.dsl.ptg.compiler import PTEXEC_STATS, compile_ptg
-    from parsec_tpu.utils.counters import counters
+    from parsec_tpu.utils.counters import counters, install_native_counters
 
+    install_native_counters()       # ptdev.cb_errors reads the lane's count
     nt = args.n // args.ts
     a, b = _gemm_operands(args)
     ctx = pt.Context(nb_cores=1)
@@ -305,6 +306,11 @@ def phase_ptg_gemm(args, jax, report):
     require(dd["tasks_engaged"] == nt ** 3,
             f"ptdev carried {dd['tasks_engaged']} of {nt ** 3} tasks")
     require(cb_errors == 0, f"ptdev.cb_errors = {cb_errors}")
+    # one executable per SHAPE of fused region: the nt * nt k-chains are one
+    report["region_programs"] = dx["region_programs"]
+    require(dx["region_programs"] <= 8,
+            f"{dx['region_programs']} region programs for {nt * nt} "
+            f"structurally equal regions")
     _placement(ctx, nt ** 3, report)
 
     ref = jnp.dot(jnp.asarray(a), jnp.asarray(b),
@@ -367,12 +373,8 @@ def _size(args, phase):
     """(N, TS) of a phase: what the command line says, else the smoke's
     size — or, rehearsing, tiny tiles on the real 32x32 tile grid, so that
     what depends on the grid is rehearsed too (the fused k-chain task's
-    1 + 2*32 flows overran a limit no smaller grid could show). ptg-gemm
-    compiles one program per C tile, which the CPU does slowly: 8x8."""
-    if args.rehearsal:
-        default = (64, 8) if phase == "ptg-gemm" else (256, 8)
-    else:
-        default = (16384, 512)
+    1 + 2*32 flows overran a limit no smaller grid could show)."""
+    default = (256, 8) if args.rehearsal else (16384, 512)
     return args.n or default[0], args.ts or default[1]
 
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import threading
 import time
+import weakref
 import zlib
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -238,8 +239,11 @@ def _grouped(fn: Callable, k: int):
     return j
 
 
-#: (body, operand signature) whose ladder of group programs is compiled
-_ladders_built: set = set()
+#: (body, operand signature) -> True once its ladder of group programs is
+#: compiled; until then a weak reference to the pool that first ran it on the
+#: device, the only pool that may still compile it: a later pool builds no
+#: program (``DTDTaskpool._may_group``)
+_ladders: Dict[Tuple, Any] = {}
 
 
 _host_dev_cache = [False, None]   # [resolved, device]
@@ -290,6 +294,9 @@ class DTDTaskClass(TaskClass):
         #: one program whatever the device manager observes (ref: dtd GPU
         #: batching flag on task-class chores)
         self.batchable = batchable
+        #: may the device manager issue the class in groups? Unknown until
+        #: its first program in this pool (``DTDTaskpool._may_group``)
+        self.groups: Optional[bool] = None
         for i, acc in enumerate(flow_accesses):
             self.add_flow(Flow(f"f{i}", acc))
 
@@ -1602,10 +1609,22 @@ class DTDTaskpool(Taskpool):
             return _run_inline(stream, task, self._tpu_submit)
         tc: DTDTaskClass = task.task_class
         jittable = self._jittable(task)
+        groups = jittable and tc.groups is not False
         gt = TPUTask(task, self._tpu_submit,
-                     batchable=tc.batchable and jittable,
-                     batch_submit=self._tpu_batch_submit if jittable else None)
+                     batchable=tc.batchable and groups,
+                     batch_submit=self._tpu_batch_submit if groups else None)
         return dev.kernel_scheduler(stream, task, tpu_task=gt)
+
+    def _may_group(self, key: Tuple) -> bool:
+        """May this pool issue the (body, operand signature) ``key`` in
+        groups? The ladder of group programs is compiled in the first pool
+        that runs the key on the device, at its first group, or not at all
+        in this process: a pool that comes later finds the programs there
+        or issues the class a program a task, so nothing is first built
+        inside a later pool (a later solve of a benchmark, a later request
+        of a server) whatever the timing of the first."""
+        state = _ladders.setdefault(key, weakref.ref(self))
+        return state is True or state() is self
 
     def _tpu_batch_submit(self, device: TPUDevice, tasks: List["DTDTask"],
                           inputs_list: List[List[Any]]):
@@ -1623,13 +1642,18 @@ class DTDTaskpool(Taskpool):
         for i in range(a, len(flat)):
             if (flat[i].shape, flat[i].dtype) != sig[i % a]:
                 raise ValueError(f"ragged group of {tasks[0].task_class.name}")
-        if (fn, sig) not in _ladders_built:
+        if _ladders.get((fn, sig)) is not True:
+            if not self._may_group((fn, sig)):
+                tasks[0].task_class.groups = False
+                raise ValueError(f"no group programs of "
+                                 f"{tasks[0].task_class.name}: the first "
+                                 f"pool that ran it built none")
             # the class's first group: build every size a later group may
             # come in, on this group's first operands, so that which
             # programs exist never depends on the timing of a run
             for k in device.group_sizes():
                 _grouped(fn, k)(*flat[:a] * k)
-            _ladders_built.add((fn, sig))
+            _ladders[(fn, sig)] = True
         outs = _grouped(fn, len(tasks))(*flat)
         return [tuple(self._apply_outputs(t, o)) for t, o in zip(tasks, outs)]
 
@@ -1646,6 +1670,9 @@ class DTDTaskpool(Taskpool):
         if jittable:
             vals = [np.asarray(v) if isinstance(v, (int, float)) else v
                     for v in vals]
+            if tc.groups is None:   # the class's first program in this pool
+                tc.groups = self._may_group(
+                    (tc.fn, tuple((v.shape, v.dtype) for v in vals)))
         outs = self._apply_outputs(task, fn(*vals))
         # order outputs by WRITE flows (contract shared with device epilog)
         return tuple(outs)

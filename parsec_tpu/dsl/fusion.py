@@ -106,6 +106,8 @@ class ExecCache:
                  stats: Optional[Dict[str, int]] = None) -> None:
         self.cap = cap
         self.stats = CAPTURE_CACHE_STATS if stats is None else stats
+        #: this cache's own counts, beside the shared ``stats``
+        self.hits = self.misses = self.evictions = 0
         self._d: "collections.OrderedDict[Hashable, Any]" = \
             collections.OrderedDict()
         self._mu = threading.Lock()
@@ -117,18 +119,22 @@ class ExecCache:
         instantiation paid a trace."""
         if key is None:
             self.stats["cache_misses"] += 1
+            self.misses += 1
             return builder(), False
         with self._mu:
             v = self._d.get(key)
             if v is not None:
                 self._d.move_to_end(key)
                 self.stats["cache_hits"] += 1
+                self.hits += 1
                 return v, True
             self.stats["cache_misses"] += 1
+            self.misses += 1
             v = self._d[key] = builder()
             while len(self._d) > self.cap:
                 self._d.popitem(last=False)
                 self.stats["cache_evictions"] += 1
+                self.evictions += 1
             return v, False
 
     def __len__(self) -> int:

@@ -24,6 +24,7 @@ against task locals + user globals.
 
 from __future__ import annotations
 
+import ast
 import textwrap
 import threading
 import time
@@ -42,6 +43,9 @@ from ...data.data import COHERENCY_OWNED, DataCopy
 from ...data.reshape import NamedDatatype, default_datatype
 from ...device.tpu import make_tpu_hook
 from ...utils import mca, output
+from ...utils.xla_trace import (
+    PTDEV_DISPATCH, PTDEV_POLL, PTDEV_RETIRE, PTG_LOWER,
+)
 from . import parser as P
 
 mca.register("ptg_agglomerate", True,
@@ -80,7 +84,11 @@ PTEXEC_STATS = _LaneStats(pools_engaged=0, tasks_engaged=0,
                           # region fusion (ISSUE 12): original tasks
                           # collapsed into fused super-tasks vs tasks the
                           # scheduler still handles per-task (the seams)
-                          fused_regions=0, fused_tasks=0, seam_tasks=0)
+                          fused_regions=0, fused_tasks=0, seam_tasks=0,
+                          # region executables BUILT (ISSUE 29): one per
+                          # distinct shape of region a program's pools
+                          # have shown, not one per region
+                          region_programs=0)
 
 _ACCESS_MAP = {
     P.FLOW_READ: FLOW_ACCESS_READ,
@@ -176,7 +184,11 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class):
     index) through a trace-time mem env, matching the per-task path's
     release-edge ordering. Returns (externally-consumed slot values,
     member write-back values in emission order). Pure w.r.t. its inputs
-    — safe to jit once and reuse across pool instantiations."""
+    — safe to jit once and reuse across pool instantiations, and across
+    every region of one shape: ``rp`` is then the shape's canonical plan
+    (:func:`_region_shape`), whose slot and memory ids are the region's
+    own numbering. ``rp["name"]`` names the program, hence its XLA
+    module (``jit_ptg_region_<classes>``)."""
     steps, out_slots = rp["steps"], rp["out_slots"]
 
     def region_program(ext_vals):
@@ -205,7 +217,39 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class):
                 menv[mk] = vals[dj]
                 wb_vals.append(vals[dj])
         return (tuple(env[s] for s in out_slots), tuple(wb_vals))
+    if rp.get("name"):
+        region_program.__name__ = region_program.__qualname__ = rp["name"]
     return region_program
+
+
+def _region_shape(kind: str, steps, out_slots, reads, class_names):
+    """The canonical plan of a region (ISSUE 29): its steps with slot
+    and memory ids renumbered by first appearance, each member's parameter
+    tuple cut to the positions its body names (``reads[ci]``; the others
+    read 0, which no body sees). Two regions replay as ONE program exactly
+    when their canonical plans are equal, so the plan, a nest of tuples,
+    is its own signature and the executable cache's key; external
+    operands, externally-consumed outputs and write-backs keep their
+    positions, so a region hands the shared program its own lists."""
+    slot_of: Dict[int, int] = {}
+    mem_of: Dict[Tuple, int] = {}
+    canon: List[Tuple] = []
+    for ci, key, srcs, base, nd, wbs in steps:
+        csrcs = tuple(
+            (kk, slot_of[v]) if kk == "int" else
+            (kk, mem_of[v]) if kk == "intm" else (kk, v)
+            for kk, v in srcs)
+        cbase = len(slot_of)
+        for dj in range(nd):
+            slot_of[base + dj] = cbase + dj
+        cwbs = tuple((dj, mem_of.setdefault(mk, len(mem_of)))
+                     for dj, mk in wbs)
+        ckey = tuple(v if i in reads[ci] else 0 for i, v in enumerate(key))
+        canon.append((ci, ckey, csrcs, cbase, nd, cwbs))
+    steps, outs = tuple(canon), tuple(slot_of[s] for s in out_slots)
+    names = dict.fromkeys(class_names[step[0]] for step in steps)
+    return {"sig": (kind, steps, outs), "steps": steps, "out_slots": outs,
+            "name": "ptg_region_" + "_".join(names)}
 
 
 class PTGTaskpool(Taskpool):
@@ -267,6 +311,8 @@ class PTGTaskpool(Taskpool):
         #: the decline reason ("ineligible" | "fallback" | None = engaged)
         self._ptexec_state: Optional[Dict[str, Any]] = None
         self._ptexec_refusal: Optional[str] = None
+        #: what ``PTGProgram.instantiate`` took, for ``ptg.lower`` to add
+        self._lower_ns = 0
         self._build()
         if ctx.comm is not None and ctx.nb_ranks > 1:
             # distributed PTG: global termination + name-keyed routing
@@ -1765,6 +1811,20 @@ class PTGTaskpool(Taskpool):
             wb_by_task.setdefault(tid, []).append((dj, dcn, idx))
         bases = flat["bases"]
         params_by_class = flat["params"]
+        # the shapes of region this plan holds (ISSUE 29): regions whose
+        # canonical plans are equal replay as one program, so the
+        # executable cache is keyed by shape, and a region only records
+        # which shape it is. A member's parameters enter its shape where
+        # its body names them (PTGProgram.body_names)
+        class_names = [tc._ptg_spec.name for tc in classes]
+        reads = []
+        for tc in classes:
+            named = self.program.body_names[tc._ptg_spec.name]
+            reads.append(frozenset(
+                i for i, p in enumerate(tc._ptg_spec.params)
+                if named is None or p in named))
+        shapes: List[Dict[str, Any]] = []
+        shape_ix: Dict[Tuple, int] = {}
         rplans: List[Dict[str, Any]] = []
         for ri, members in enumerate(regions):
             ext: List[Tuple] = []
@@ -1810,11 +1870,17 @@ class PTGTaskpool(Taskpool):
             outs = [slot_base[m] + dj for m in members
                     for dj in range(ndflows[cls_of[m]])
                     if slot_uses2[slot_base[m] + dj] > 0]
+            shape = _region_shape(kind[members[0]], steps, outs, reads,
+                                  class_names)
+            si = shape_ix.get(shape["sig"])
+            if si is None:
+                si = shape_ix[shape["sig"]] = len(shapes)
+                shapes.append(shape)
             rplans.append({"members": list(members),
                            "kind": kind[members[0]],
                            "ext": ext,
                            "ext_mems": [v for k2, v in ext if k2 == "mem"],
-                           "steps": steps, "wb_keys": wb_keys,
+                           "shape": si, "wb_keys": wb_keys,
                            "out_slots": outs})
         dev_mask2 = None
         ndev_tasks = 0
@@ -1842,7 +1908,7 @@ class PTGTaskpool(Taskpool):
                             for nd in node],
                 "orig_of": [nd[1] if nd[0] == "t" else rep_of[nd[1]]
                             for nd in node],
-                "rcid": rcid, "regions": rplans,
+                "rcid": rcid, "regions": rplans, "shapes": shapes,
                 "writebacks": [w for w in data["writebacks"]
                                if reg_of[w[0]] < 0],
                 "dev_mask": dev_mask2, "ndev_tasks": ndev_tasks,
@@ -1915,10 +1981,11 @@ class PTGTaskpool(Taskpool):
                            bucket: int) -> Dict[str, Any]:
         """Build the native-lane state for a pool with a fusion plan:
         the compact graph (regions + seams) with original-task weights,
-        per-region jitted programs out of the PERSISTENT executable
-        cache (program-scoped, keyed by the placement-aware flatten key
-        + region index — a second instantiation of the same DAG shape
-        reuses the compiled program with zero re-tracing), and the
+        one jitted program per SHAPE of region out of the PERSISTENT
+        executable cache (program-scoped, keyed by the class names, the
+        placement, the globals a body names and the shape's canonical
+        plan — regions of one shape share one program, and a second
+        instantiation builds, traces and loads nothing), and the
         region-aware dispatch callbacks."""
         import jax
         data = flat["data"]
@@ -1942,22 +2009,35 @@ class PTGTaskpool(Taskpool):
                              f"collection {dc_name!r}")
             writebacks.setdefault(tid, []).append((dj, dc.data_of(*idx)))
         fns, written_by_class = self._ptexec_class_fns(classes, data)
-        cache = self.program.__dict__.setdefault(
-            "_region_prog_cache", ExecCache(128))
-        runners: Dict[int, Any] = {}
-        dev_regions: Dict[int, Dict[str, Any]] = {}
-        cold_regions: set = set()
-        for ri, rp in enumerate(plan["regions"]):
+        cache = self.program.region_programs
+        # the flatten key names every primitive global; a region program
+        # depends on those a body names, so pools that differ in the
+        # others (a larger grid of the same tiles) share executables
+        named = self.program.globals_named
+        rkey = None if ckey is None else (
+            ckey[1], ckey[2], tuple(g for g in ckey[0]
+                                    if named is None or g[0] in named))
+        programs = []
+        for shape in plan["shapes"]:
             # the cached object is the TIMED wrapper: its first call (the
             # jit trace+compile) feeds the __region_trace__ pseudo-class
             # fusion sizing reads back; a cache HIT reuses the wrapper
             # with the first call already burned, so warm replays never
             # observe a phantom trace
             jitted, hit = cache.get_or_build(
-                None if ckey is None else (ckey, ri),
-                lambda rp=rp: _timed_region_program(
-                    jax.jit(_mk_region_program(rp, fns, written_by_class)),
-                    len(rp["members"])))
+                None if rkey is None else (rkey, shape["sig"]),
+                lambda shape=shape: _timed_region_program(
+                    jax.jit(_mk_region_program(shape, fns,
+                                               written_by_class)),
+                    len(shape["steps"])))
+            if not hit:
+                PTEXEC_STATS["region_programs"] += 1
+            programs.append((jitted, hit))
+        runners: Dict[int, Any] = {}
+        dev_regions: Dict[int, Dict[str, Any]] = {}
+        cold_regions: set = set()
+        for ri, rp in enumerate(plan["regions"]):
+            jitted, hit = programs[rp["shape"]]
             if not hit:
                 cold_regions.add(ri)
             wb_datas = []
@@ -2165,6 +2245,17 @@ class PTGTaskpool(Taskpool):
 
             def _stage(mi):
                 return dev.lane_stage_in(mem_datas[mi], pin=True)
+        sp = self.ctx._spans
+        if sp is not None:
+            # ptdev.stage_in: the push phase's misses only (a hit moves
+            # no bytes); on the timeline a miss is the dev.stage_in
+            # annotation of TPUDevice._stage_in_copy, inside ptdev.dispatch
+            def _stage(mi, _inner=_stage):
+                moved, t0 = dev.transfer_in_bytes, _pc()
+                copy = _inner(mi)
+                if dev.transfer_in_bytes != moved:
+                    sp.pt_stage_in.record(_pc() - t0)
+                return copy
         if fusion is not None:
             # fused pool (ISSUE 12): a device REGION dispatches as one
             # region-sized async program; its inflight/retire id is the
@@ -2289,6 +2380,8 @@ class PTGTaskpool(Taskpool):
                 if events and not all(a.is_ready() for a in events):
                     inflight.append(ent)
                     continue
+                if sp is not None:
+                    tok = sp.begin(PTDEV_RETIRE)
                 if wbs:
                     for dj, dref in wbs:
                         v = vals[dj]
@@ -2303,6 +2396,8 @@ class PTGTaskpool(Taskpool):
                 dev.executed_tasks += w
                 retired.append((ckey2, w))
                 done.append(i)
+                if sp is not None:
+                    retired_ns[0] += sp.end(tok, sp.pt_retire)
             if retired and _obs is not None:
                 # batch amortization, the SAME semantics as the C lane's
                 # exec bump: the wall window since the last retire sweep
@@ -2324,7 +2419,27 @@ class PTGTaskpool(Taskpool):
                 dev_clock[0] = now
             return done
 
-        return dispatch, poll
+        if sp is None:
+            return dispatch, poll
+        retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
+
+        def traced_dispatch(ids):
+            # one span a callback, recorded once per device program
+            tok = sp.begin(PTDEV_DISPATCH)
+            try:
+                return dispatch(ids)
+            finally:
+                sp.end(tok, sp.pt_dispatch, n=len(ids))
+
+        def traced_poll():
+            # one record a pass, the retirements' own spans subtracted
+            tok, before = sp.begin(PTDEV_POLL), retired_ns[0]
+            try:
+                return poll()
+            finally:
+                sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
+
+        return traced_dispatch, traced_poll
 
     def _ptexec_owners(self, classes: List[TaskClass],
                        flat) -> Optional[List[int]]:
@@ -2646,6 +2761,19 @@ class PTGTaskpool(Taskpool):
 
     # ------------------------------------------------------------------ startup
     def _startup(self, stream, tp) -> List[Task]:
+        """The startup hook, under the second half of the ``ptg.lower``
+        span: one record an instantiation, from ``instantiate`` to the
+        lanes bound (or the first ready tasks made)."""
+        sp = self.ctx._spans
+        if sp is None:
+            return self._lower(stream)
+        tok = sp.begin(PTG_LOWER)
+        try:
+            return self._lower(stream)
+        finally:
+            sp.lower.record(self._lower_ns + sp.end(tok, None))
+
+    def _lower(self, stream) -> List[Task]:
         total = 0
         ready: List[Task] = []
         my_rank = self.ctx.my_rank
@@ -2719,20 +2847,58 @@ class PTGTaskpool(Taskpool):
         return ready
 
 
+#: names through which a body can read a variable without naming it
+_DYNAMIC_LOOKUPS = frozenset(("locals", "vars", "globals", "eval", "exec"))
+
+
+def _names_in(source: str) -> Optional[frozenset]:
+    """Every identifier a BODY's text names, or None when the text does
+    not parse alone or looks names up at run time (everything is then
+    taken as named)."""
+    try:
+        tree = ast.parse(textwrap.dedent(source))
+    except SyntaxError:
+        return None
+    names = frozenset(n.id for n in ast.walk(tree) if isinstance(n, ast.Name))
+    return None if names & _DYNAMIC_LOOKUPS else names
+
+
 class PTGProgram:
     """A compiled PTG program; instantiate per (globals, collections) run."""
 
     def __init__(self, spec: P.ProgramSpec) -> None:
         self.spec = spec
+        #: class name -> the identifiers its bodies name. What a body does
+        #: not name cannot change the program it traces to, so a task
+        #: parameter or a global enters a region executable's key only
+        #: where a body names it (ISSUE 29)
+        self.body_names: Dict[str, Optional[frozenset]] = {}
+        for tcs in spec.task_classes:
+            named = [_names_in(b.source) for b in tcs.bodies]
+            self.body_names[tcs.name] = None if None in named \
+                else frozenset().union(*named)
+        every = list(self.body_names.values())
+        self.globals_named: Optional[frozenset] = None if None in every \
+            else frozenset().union(*every)
+        #: the region executables of this program's pools, one per shape
+        #: of region (``_ptexec_lane_fused``); its hit/miss/evict counts
+        #: are the cache's own attributes
+        self.region_programs = ExecCache(128)
 
     def instantiate(self, ctx: Context, globals: Optional[Dict[str, Any]] = None,
                     collections: Optional[Dict[str, Any]] = None,
                     name: Optional[str] = None,
                     datatypes: Optional[Dict[str, NamedDatatype]] = None
                     ) -> PTGTaskpool:
-        return PTGTaskpool(self, ctx, dict(globals or {}),
-                           dict(collections or {}), name,
-                           datatypes=datatypes)
+        sp = ctx._spans
+        if sp is not None:
+            tok = sp.begin(PTG_LOWER)
+        tp = PTGTaskpool(self, ctx, dict(globals or {}),
+                         dict(collections or {}), name, datatypes=datatypes)
+        if sp is not None:
+            # recorded when the startup hook has bound the lanes
+            tp._lower_ns = sp.end(tok, None)
+        return tp
 
 
 def compile_ptg(source: str, name: str = "ptg") -> PTGProgram:

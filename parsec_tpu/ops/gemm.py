@@ -108,3 +108,15 @@ def _gemm_chain_body(kt: int):
 def gemm_flops(M: int, N: int, K: int) -> float:
     """2·M·N·K (ref: dtd_test_simple_gemm.c gflops computation)."""
     return 2.0 * M * N * K
+
+
+def gemm_reference(a, b, c0, solves: int = 1):
+    """The plain reference of the tiled GEMM configurations (DTD and PTG):
+    ``c0 + solves * a @ b`` in float32 ``jax.numpy`` at the highest matmul
+    precision; no tiles, no runtime. ``solves`` is how often the graph ran
+    over an accumulating C."""
+    import jax
+    import jax.numpy as jnp
+    with jax.default_matmul_precision("highest"):
+        prod = jnp.dot(jnp.asarray(a, jnp.float32), jnp.asarray(b, jnp.float32))
+    return jnp.asarray(c0, jnp.float32) + jnp.float32(solves) * prod

@@ -58,6 +58,10 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns",
                "group_tasks"),
     "dtd": ("link_ns", "stall_ns"),
+    # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
+    # lanes, and the ptdev manager's dispatch / stage-in / poll / retire
+    "ptg": ("lower_ns",),
+    "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns"),
 }
 
 

@@ -13,12 +13,16 @@ Usage::
 
 or annotate spans manually through :class:`TaskAnnotator` (a PINS module).
 
-:class:`Spans` is the runtime's own instrumentation of the per-task device
-path: six named spans in ``device/tpu.py`` and ``dsl/dtd.py``, each a
-``TraceAnnotation`` on the profiler's host plane and a duration in a
-``utils/hist.py`` histogram, plus the ready-wait interval. One object per
-``Context``, ``None`` when off, so a site is ``sp = self._spans`` /
-``if sp is not None:``.
+:class:`Spans` is the runtime's own instrumentation of the device paths:
+six named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``)
+and four on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
+instantiation, and the ``ptdev`` manager's dispatch, poll and retire),
+each a ``TraceAnnotation`` on the profiler's host plane and a duration in
+a ``utils/hist.py`` histogram, plus two intervals that are histograms
+alone: the ready-wait, and ``ptdev.stage_in_ns`` (a miss of the lane's
+push phase, whose annotation is the ``dev.stage_in`` it nests).
+One object per ``Context``, ``None`` when off, so a site is
+``sp = self._spans`` / ``if sp is not None:``.
 """
 
 from __future__ import annotations
@@ -54,6 +58,9 @@ def xla_trace(logdir: Optional[str] = None) -> Iterator[None]:
 DTD_LINK, DTD_STALL = "dtd.link", "dtd.stall"
 DEV_SUBMIT, DEV_STAGE_IN = "dev.submit", "dev.stage_in"
 DEV_POLL, DEV_RETIRE = "dev.poll", "dev.retire"
+PTG_LOWER = "ptg.lower"
+PTDEV_DISPATCH = "ptdev.dispatch"
+PTDEV_POLL, PTDEV_RETIRE = "ptdev.poll", "ptdev.retire"
 
 
 class Spans:
@@ -84,9 +91,17 @@ class Spans:
         self.link = dtd.cell("link_ns")
         self.stall = dtd.cell("stall_ns")
         self._ready = ready.cell("ready_wait_ns")
+        ptg = PyHistograms(HIST_NAMES["ptg"])
+        ptdev = PyHistograms(HIST_NAMES["ptdev"])
+        self.lower = ptg.cell("lower_ns")
+        self.pt_dispatch = ptdev.cell("dispatch_ns")
+        self.pt_stage_in = ptdev.cell("stage_in_ns")
+        self.pt_poll = ptdev.cell("poll_ns")
+        self.pt_retire = ptdev.cell("retire_ns")
         #: (registry kind, object) for Context._hist_attach/_hist_detach
         self.hists: List[Tuple[str, PyHistograms]] = [
-            ("tpudev", tpudev), ("dtd", dtd), ("ptdtd", ready)]
+            ("tpudev", tpudev), ("dtd", dtd), ("ptdtd", ready),
+            ("ptg", ptg), ("ptdev", ptdev)]
 
     def begin(self, name: str):
         ann = None

@@ -26,6 +26,7 @@ TS = 16
 @pytest.fixture()
 def dctx():
     mca.set("device_tpu_over_cpu", True)
+    dtd_mod._ladders.clear()    # every test's pools are the first of a body
     c = Context(nb_cores=1)
     yield c
     c.fini()
@@ -396,14 +397,7 @@ def test_a_second_solve_of_the_same_dag_compiles_nothing(dctx):
     """A class's whole ladder is built at its first group, so which
     programs a later solve can need is decided by the first solve of the
     DAG and never by how the groups happened to fall."""
-    import jax
-    compiles, on = [], [True]
-
-    def listener(event, secs, **_kw):
-        if on[0] and event == "/jax/core/compile/backend_compile_duration":
-            compiles.append(secs)
-
-    jax.monitoring.register_event_duration_secs_listener(listener)
+    compiles, on = _compile_counter()
     try:
         dev = _dev(dctx)
         _potrf(dctx, _spd(1), "C1")
@@ -419,6 +413,85 @@ def test_a_second_solve_of_the_same_dag_compiles_nothing(dctx):
         assert sizes == set(tpu_mod.GROUP_LADDER)
     finally:
         on[0] = False
+
+
+def thrice(x):
+    return x * 3.0
+
+
+def _compile_counter():
+    import jax
+    compiles, on = [], [True]
+
+    def listener(event, secs, **_kw):
+        if on[0] and event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    return compiles, on
+
+
+def test_no_program_is_first_built_inside_a_later_pool(dctx, monkeypatch):
+    """The first pool that runs a body on the device forms no group (every
+    program late: a backlog behind a cold compile); the second is paced by
+    the host and would group. It issues a program a task and compiles
+    nothing: the ladder is built in a class's first pool or not at all."""
+    dev = _dev(dctx)
+    issued = _record_programs(dev, monkeypatch)
+    after = [3]
+    _make_late(dev, monkeypatch, after)
+    compiles, on = _compile_counter()
+    try:
+        A = _column("NB", 48 + 64)
+        tp = DTDTaskpool(dctx, "first")
+        for m in range(48):
+            tp.insert_task(thrice, (tp.tile_of(A, m, 0), RW))
+        tp.wait(); tp.close(); dctx.wait()
+        assert [len(ids) for _n, ids in issued] == [1] * 48
+        built = len(compiles)
+        assert built >= 1           # the single program, in the first pool
+        after[0] = 0                # the host paces the class from here on
+        del issued[:]
+        tp = DTDTaskpool(dctx, "second")
+        for m in range(48, 48 + 64):
+            tp.insert_task(thrice, (tp.tile_of(A, m, 0), RW))
+        tp.wait(); tp.close(); dctx.wait()
+        assert len(compiles) == built, compiles[built:]
+        assert [len(ids) for _n, ids in issued] == [1] * 64
+        assert dev.batched_tasks == dev.batched_dispatches == 0
+        assert not any(isinstance(key, tuple) and key[0] is thrice
+                       for key in dtd_mod._jit_cache)
+        for m in range(48 + 64):
+            assert np.allclose(_tile(A, m), 3.0 * m)
+        # the rule is per (body, operand signature): another body's first
+        # pool on the same device still groups, and builds its ladder there
+        B = _column("NC", 64)
+        tp = DTDTaskpool(dctx, "other")
+        for m in range(64):
+            tp.insert_task(shift, (tp.tile_of(B, m, 0), RW))
+        tp.wait(); tp.close(); dctx.wait()
+        assert dev.batched_tasks > 0
+        assert {k for key in dtd_mod._jit_cache if isinstance(key, tuple)
+                for fn, k in [key] if fn is shift} == set(tpu_mod.GROUP_LADDER)
+    finally:
+        on[0] = False
+
+
+def test_a_later_pool_uses_the_ladder_its_first_pool_built(dctx):
+    """Built in the first pool, used by every later one, whoever is alive."""
+    dev = _dev(dctx)
+    A = _column("LU", 128)
+    for p in range(2):
+        tp = DTDTaskpool(dctx, f"ladder{p}")
+        for m in range(64 * p, 64 * p + 64):
+            tp.insert_task(scale, (tp.tile_of(A, m, 0), RW))
+        tp.wait(); tp.close(); dctx.wait()
+        del tp
+        assert dev.batched_tasks > 0
+        seen, dev.batched_tasks = dev.batched_tasks, 0
+    assert seen > 0
+    assert any(state is True for (fn, _sig), state in dtd_mod._ladders.items()
+               if fn is scale)
 
 
 # ------------------------------------------------------- the engagement record

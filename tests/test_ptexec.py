@@ -15,6 +15,7 @@ Three layers:
 import math
 import random
 import threading
+import time
 
 import pytest
 
@@ -408,6 +409,12 @@ def test_lane_multiworker_chain_smoke():
         ctx.wait(timeout=60)
         assert tp._ptexec_state is not None
         assert tp._ptexec_state["graph"].done()
+        # a worker adds its share when its graph.run returns, which may be
+        # after the master saw the graph done: give the workers a moment
+        deadline = time.monotonic() + 10
+        while sum(s.nb_executed for s in ctx.streams) != nt * depth \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         assert sum(s.nb_executed for s in ctx.streams) == nt * depth
     finally:
         ctx.fini()
