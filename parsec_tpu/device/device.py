@@ -57,6 +57,8 @@ class DeviceModule:
         # programs that carried several tasks, and the tasks in them
         self.batched_dispatches = 0
         self.batched_tasks = 0
+        # stage-ins that took a resident array as they found it (no copy)
+        self.adopted = 0
         self._lock = threading.Lock()
 
     # -- lifecycle ------------------------------------------------------------
@@ -223,6 +225,7 @@ class DeviceRegistry:
                 "executed_tasks": d.executed_tasks,
                 "transfer_in_bytes": d.transfer_in_bytes,
                 "transfer_out_bytes": d.transfer_out_bytes,
+                "adopted": d.adopted,
                 "batched_dispatches": d.batched_dispatches,
                 "batched_tasks": d.batched_tasks,
                 "load": d.device_load,

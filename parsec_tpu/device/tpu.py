@@ -448,31 +448,54 @@ class TPUDevice(DeviceModule):
         src = newest
         if src is None:
             raise RuntimeError(f"no valid copy to stage in for {data!r}")
+        arr = src.payload
+        if isinstance(arr, self._jax.Array) and arr.committed and \
+                arr.devices() == {self.jax_device}:
+            # adoption: the newest bytes already live on this device (a
+            # write-back that kept the device array as the host copy's
+            # payload, a tile reset on the device), so the device copy takes
+            # that array as it is: no call into the client, no byte moved.
+            # The two copies then share one buffer, as device_put onto the
+            # same device left them too. Safe only while no program donates
+            # an operand (region programs are jitted without donate_argnums):
+            # a donated buffer would be taken from the other copy as well
+            self.adopted += 1
+            return self._install(data, copy, arr, src.version, pin,
+                                 moved=False)
         sp = self._spans
         if sp is not None:
             tok = sp.begin(DEV_STAGE_IN)    # a miss: the host cost of one H2D
         try:
-            arr = self._jax.device_put(src.payload, self.jax_device)  # async H2D/D2D
-            nbytes = _nbytes(arr)
-            if self._ncoh is None:
-                self._reserve(nbytes)   # native mode: stage_in reserved above
-            if copy is None:
-                copy = data.create_copy(dev_idx, arr, COHERENCY_SHARED)
-            else:
-                copy.payload = arr
-                copy.coherency_state = COHERENCY_SHARED
-            copy.version = src.version
-            self.transfer_in_bytes += nbytes
-            self._lru_touch(self.res_key(data), copy)
-            if pin:
-                if self._ncoh is not None:
-                    with self._heap_lock:
-                        copy.readers += 1     # table half pinned in stage_in
-                else:
-                    self.pin_copy(copy)
+            arr = self._jax.device_put(arr, self.jax_device)  # async H2D/D2D
+            return self._install(data, copy, arr, src.version, pin,
+                                 moved=True)
         finally:
             if sp is not None:
                 sp.end(tok, sp.stage_in)
+
+    def _install(self, data: Data, copy: Optional[DataCopy], arr: Any,
+                 version: int, pin: bool, moved: bool) -> DataCopy:
+        """The miss path's bookkeeping: ``arr`` becomes the device copy of
+        ``data`` at ``version`` (reserve, LRU touch, the Python half of the
+        pin); ``moved`` says whether a transfer brought it."""
+        nbytes = _nbytes(arr)
+        if self._ncoh is None:
+            self._reserve(nbytes)   # native mode: stage_in reserved above
+        if copy is None:
+            copy = data.create_copy(self.device_index, arr, COHERENCY_SHARED)
+        else:
+            copy.payload = arr
+            copy.coherency_state = COHERENCY_SHARED
+        copy.version = version
+        if moved:
+            self.transfer_in_bytes += nbytes
+        self._lru_touch(self.res_key(data), copy)
+        if pin:
+            if self._ncoh is not None:
+                with self._heap_lock:
+                    copy.readers += 1     # table half pinned in stage_in
+            else:
+                self.pin_copy(copy)
         return copy
 
     def lane_stage_in(self, data: Data, pin: bool = False) -> DataCopy:

@@ -2099,7 +2099,7 @@ class PTGTaskpool(Taskpool):
                         for ci in range(len(classes))]
         graph = lane["graph"]
         cost_obs = self._ptexec_cost_obs(lane)
-        dispatch, poll = self._mk_ptexec_dev_dispatch(
+        dispatch, poll, lane["dev_held"] = self._mk_ptexec_dev_dispatch(
             flat, classes, dev_of_class, slots, mem_datas, writebacks,
             devlane, fusion={"orig_of": plan["orig_of"],
                              "dev_regions": dev_regions, "graph": graph,
@@ -2153,7 +2153,7 @@ class PTGTaskpool(Taskpool):
             dev_mask.extend([1 if dev_of_class[ci] else 0] * len(insts))
         ndev = sum(dev_mask)
         graph = lane["graph"]
-        dispatch, poll = self._mk_ptexec_dev_dispatch(
+        dispatch, poll, lane["dev_held"] = self._mk_ptexec_dev_dispatch(
             flat, classes, dev_of_class, slots, mem_datas, writebacks,
             devlane, cost_obs=self._ptexec_cost_obs(lane), bucket=bucket)
         pid = devlane.bind_pool(graph, dispatch, poll)
@@ -2189,8 +2189,16 @@ class PTGTaskpool(Taskpool):
         * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
           inflight task's outputs (cudaEventQuery, device_gpu.c:2593).
           Completed tasks perform their memory write-backs + version
-          bumps, drop their stage-in pins, and return their ids — the C
+          bumps, give up their reads, and return their ids — the C
           side then calls the graph's GIL-free ``dev_retire``.
+
+        Residency is touched once per distinct memory operand of a BATCH,
+        not per operand of every program: the push phase's stage-in takes
+        the operand's one pin (table + ``readers``), ``held`` counts the
+        programs in flight that read it, and the pin is given back when
+        the last of them retires (or at the end of ``dispatch``, where
+        no program of the batch reads it). Returns ``(dispatch, poll,
+        held)``; ``held`` is empty whenever nothing is in flight.
         """
         from ...data.data import COHERENCY_OWNED as _OWNED
         dev = devlane.device
@@ -2247,15 +2255,43 @@ class PTGTaskpool(Taskpool):
                 return dev.lane_stage_in(mem_datas[mi], pin=True)
         sp = self.ctx._spans
         if sp is not None:
+            pinned = [0]     # table pins taken so far, for ptdev.pins
+
             # ptdev.stage_in: the push phase's misses only (a hit moves
             # no bytes); on the timeline a miss is the dev.stage_in
             # annotation of TPUDevice._stage_in_copy, inside ptdev.dispatch
             def _stage(mi, _inner=_stage):
                 moved, t0 = dev.transfer_in_bytes, _pc()
                 copy = _inner(mi)
+                pinned[0] += 1      # every pin of the closure is a stage-in's
                 if dev.transfer_in_bytes != moved:
                     sp.pt_stage_in.record(_pc() - t0)
                 return copy
+        # mi -> [device copy, programs in flight that read it, pins held]:
+        # owned by the manager thread (dispatch and poll both run there
+        # with the GIL, as _obs relies on), so no lock and no table call
+        # per (program, operand). An operand staged again by a later batch
+        # while an earlier reader still flies joins the same entry; its
+        # pins nest in the table as they always did.
+        held: Dict[int, List[Any]] = {}
+
+        def _hold(mi, staged):
+            # pin=True: the eviction pin is taken inside the table's
+            # reserve critical section, so no peer thread's stage-in can
+            # evict this entry first, and staging tile k+1 of this very
+            # batch cannot evict tile k before the exec phase reads it
+            # (found by the verify drive: "dot got NoneType")
+            copy = _stage(mi)
+            h = held.get(mi)
+            if h is None:
+                h = held[mi] = [copy, 0, 0]
+            h[2] += 1
+            staged[mi] = h
+
+        def _release(mi, h):
+            del held[mi]
+            for _ in range(h[2]):
+                dev.unpin_copy(h[0])
         if fusion is not None:
             # fused pool (ISSUE 12): a device REGION dispatches as one
             # region-sized async program; its inflight/retire id is the
@@ -2270,14 +2306,9 @@ class PTGTaskpool(Taskpool):
 
         def dispatch(ids):
             # PUSH phase: issue every memory-endpoint stage-in for the
-            # whole batch before any compute dispatch. Each staged copy is
-            # pinned THE MOMENT it stages: under a tight budget, staging
-            # tile k+1 of this very batch can otherwise evict tile k
-            # before the exec phase reads it (found by the verify drive —
-            # "dot got NoneType"). Batch pins release after the exec
-            # phase has taken its per-task inflight pins.
-            staged: Dict[int, Any] = {}
-            batch_pins: List[Any] = []
+            # whole batch before any compute dispatch, each distinct
+            # operand once, pinned THE MOMENT it stages (_hold)
+            staged: Dict[int, List[Any]] = {}
             if _obs is not None and not inflight:
                 # idle -> active: restart the amortization clock so idle
                 # gaps between batches never land in any task's cost
@@ -2288,22 +2319,14 @@ class PTGTaskpool(Taskpool):
                     if r is not None:
                         for mi in r["ext_mems"]:
                             if mi not in staged:
-                                copy = _stage(mi)
-                                batch_pins.append(copy)
-                                staged[mi] = copy
+                                _hold(mi, staged)
                         continue
                     i = _forig[i]
                 base = slot_base[i]
                 for dj in range(ndflows[cls_of[i]]):
                     r = in_refs[base + dj]
                     if r < -1 and (-2 - r) not in staged:
-                        mi = -2 - r
-                        # pin=True: the eviction pin is taken inside the
-                        # table's reserve critical section, so no peer
-                        # thread's stage-in can evict this entry first
-                        copy = _stage(mi)
-                        batch_pins.append(copy)
-                        staged[mi] = copy
+                        _hold(-2 - r, staged)
             # EXEC phase: dispatch each ready device task asynchronously
             for i in ids:
                 oi = i
@@ -2313,16 +2336,14 @@ class PTGTaskpool(Taskpool):
                         # region-sized dispatch: ONE jitted program for
                         # the whole fused region, async like any task;
                         # the retire id stays the compact node id
-                        pins: List[Any] = []
                         ev: List[Any] = []
                         for kk, v in r["ext"]:
                             if kk == "slot":
                                 ev.append(slots[v])
                             else:
-                                copy = staged[v]
-                                dev.pin_copy(copy)
-                                pins.append(copy)
-                                ev.append(copy.payload)
+                                h = staged[v]
+                                h[1] += 1       # one more reader in flight
+                                ev.append(h[0].payload)
                         _graph.trace_mark(_evr, i, _fs)
                         outs, wbs_v = r["jitted"](tuple(ev))
                         _graph.trace_mark(_evr, i, _fe)
@@ -2331,8 +2352,8 @@ class PTGTaskpool(Taskpool):
                         events = tuple(v for v in tuple(outs) + tuple(wbs_v)
                                        if hasattr(v, "is_ready"))
                         inflight.append((
-                            i, events, r["wb_pairs"], list(wbs_v), pins,
-                            r["ntasks"],
+                            i, events, r["wb_pairs"], list(wbs_v),
+                            r["ext_mems"], r["ntasks"],
                             None if (_obs is None or r.get("cold")) else
                             (cnames[r["cls"]], bucket, "tpu_fused")))
                         continue
@@ -2341,7 +2362,7 @@ class PTGTaskpool(Taskpool):
                 base = slot_base[oi]
                 nd = ndflows[k]
                 vals: List[Any] = []
-                pins = []
+                reads: List[int] = []
                 for dj in range(nd):
                     r = in_refs[base + dj]
                     if r >= 0:
@@ -2349,10 +2370,10 @@ class PTGTaskpool(Taskpool):
                     elif r == -1:
                         vals.append(None)
                     else:
-                        copy = staged[-2 - r]
-                        dev.pin_copy(copy)     # readers guard while inflight
-                        pins.append(copy)
-                        vals.append(copy.payload)
+                        h = staged[-2 - r]
+                        h[1] += 1               # one more reader in flight
+                        reads.append(-2 - r)
+                        vals.append(h[0].payload)
                 fn = fns[k]
                 events = ()
                 if fn is not None:
@@ -2363,12 +2384,13 @@ class PTGTaskpool(Taskpool):
                                    if hasattr(v, "is_ready"))
                 for dj in range(nd):
                     slots[base + dj] = vals[dj]
-                inflight.append((i, events, writebacks.get(oi), vals, pins,
+                inflight.append((i, events, writebacks.get(oi), vals, reads,
                                  1,
                                  None if _obs is None else
                                  (cnames[k], bucket, "tpu")))
-            for copy in batch_pins:         # per-task pins hold from here
-                dev.unpin_copy(copy)
+            for mi, h in staged.items():
+                if not h[1]:            # staged, and no program reads it
+                    _release(mi, h)
             return len(ids)
 
         def poll():
@@ -2376,7 +2398,7 @@ class PTGTaskpool(Taskpool):
             retired: List[Tuple] = []
             for _ in range(len(inflight)):
                 ent = inflight.popleft()
-                i, events, wbs, vals, pins, w, ckey2 = ent
+                i, events, wbs, vals, reads, w, ckey2 = ent
                 if events and not all(a.is_ready() for a in events):
                     inflight.append(ent)
                     continue
@@ -2391,8 +2413,11 @@ class PTGTaskpool(Taskpool):
                         else:
                             host.payload = v
                         dref.bump_version(0)
-                for copy in pins:
-                    dev.unpin_copy(copy)
+                for mi in reads:
+                    h = held[mi]
+                    h[1] -= 1
+                    if not h[1]:        # its last reader in flight retired
+                        _release(mi, h)
                 dev.executed_tasks += w
                 retired.append((ckey2, w))
                 done.append(i)
@@ -2420,16 +2445,18 @@ class PTGTaskpool(Taskpool):
             return done
 
         if sp is None:
-            return dispatch, poll
+            return dispatch, poll, held
         retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
 
         def traced_dispatch(ids):
-            # one span a callback, recorded once per device program
-            tok = sp.begin(PTDEV_DISPATCH)
+            # one span a callback, recorded once per device program; the
+            # table pins the callback took, one record
+            tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
             try:
                 return dispatch(ids)
             finally:
                 sp.end(tok, sp.pt_dispatch, n=len(ids))
+                sp.pt_pins.record(pinned[0] - before)
 
         def traced_poll():
             # one record a pass, the retirements' own spans subtracted
@@ -2439,7 +2466,7 @@ class PTGTaskpool(Taskpool):
             finally:
                 sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
 
-        return traced_dispatch, traced_poll
+        return traced_dispatch, traced_poll, held
 
     def _ptexec_owners(self, classes: List[TaskClass],
                        flat) -> Optional[List[int]]:

@@ -18,6 +18,7 @@ import signal
 import subprocess
 import sys
 import tempfile
+import threading
 import time
 from typing import Optional
 
@@ -182,11 +183,19 @@ def _run_job(opts) -> int:
         elif opts.bind_devices:
             env.update(chip_env(rank))
         compile_cache.export(env)
+        # a rank's output reaches ours through _relay, a pipe of its own:
+        # unbuffered keeps it as prompt as the shared terminal made it
+        env.setdefault("PYTHONUNBUFFERED", "1")
         # each rank leads its own process group so cleanup can reach
         # grandchildren even if the launcher itself is killed mid-wait
         procs.append(subprocess.Popen(
             [sys.executable, opts.script, *opts.args], env=env,
-            start_new_session=True))
+            stdout=subprocess.PIPE, text=True, start_new_session=True))
+    whole_line = threading.Lock()
+    relays = [threading.Thread(target=_relay, args=(p.stdout, whole_line),
+                               daemon=True) for p in procs]
+    for t in relays:
+        t.start()
     rc = 0
     deadline = time.monotonic() + opts.timeout   # one job-wide deadline
     try:
@@ -213,7 +222,32 @@ def _run_job(opts) -> int:
                     p.wait(timeout=max(0.1, 5.0 - (time.monotonic() - t0)))
                 except subprocess.TimeoutExpired:
                     _kill_group(p, signal.SIGKILL)
+        # what the ranks wrote last; a grandchild that outlives its rank
+        # may hold a pipe open, so the wait is bounded
+        t0 = time.monotonic()
+        for t in relays:
+            t.join(timeout=max(0.1, 5.0 - (time.monotonic() - t0)))
     return rc
+
+
+def _relay(rank_out, whole_line: threading.Lock) -> None:
+    """Copy one rank's stdout to ours a whole line at a time. The ranks
+    used to share our stdout, and a ``print`` under ``PYTHONUNBUFFERED`` is
+    two writes, the text and then the newline: two ranks that report at the
+    same moment (they all do, after the last barrier) could run their lines
+    into one, which a parent that parses them line by line cannot read.
+    Where our own stdout is gone (its reader died), the rank's pipe is
+    closed, so its next write fails as it did when it wrote there itself:
+    a rank blocked on a full pipe would hold the job to its deadline."""
+    try:
+        for line in rank_out:
+            with whole_line:
+                sys.stdout.write(line)
+                sys.stdout.flush()
+    except (OSError, ValueError):
+        pass
+    finally:
+        rank_out.close()
 
 
 def cpu_budget() -> dict:

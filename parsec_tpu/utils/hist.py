@@ -60,8 +60,10 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     "dtd": ("link_ns", "stall_ns"),
     # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
     # lanes, and the ptdev manager's dispatch / stage-in / poll / retire
+    # ``pins`` is no time: one record per dispatch callback, the table
+    # pins it took
     "ptg": ("lower_ns",),
-    "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns"),
+    "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns", "pins"),
 }
 
 

@@ -20,7 +20,8 @@ instantiation, and the ``ptdev`` manager's dispatch, poll and retire),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
 alone: the ready-wait, and ``ptdev.stage_in_ns`` (a miss of the lane's
-push phase, whose annotation is the ``dev.stage_in`` it nests).
+push phase, whose annotation is the ``dev.stage_in`` it nests), and two
+counts filed the same way: ``tpudev.group_tasks`` and ``ptdev.pins``.
 One object per ``Context``, ``None`` when off, so a site is
 ``sp = self._spans`` / ``if sp is not None:``.
 """
@@ -98,6 +99,7 @@ class Spans:
         self.pt_stage_in = ptdev.cell("stage_in_ns")
         self.pt_poll = ptdev.cell("poll_ns")
         self.pt_retire = ptdev.cell("retire_ns")
+        self.pt_pins = ptdev.cell("pins")
         #: (registry kind, object) for Context._hist_attach/_hist_detach
         self.hists: List[Tuple[str, PyHistograms]] = [
             ("tpudev", tpudev), ("dtd", dtd), ("ptdtd", ready),

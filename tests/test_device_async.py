@@ -583,6 +583,19 @@ def test_device_lane_off_by_mca(dctx):
         mca.params.unset("device_native")
 
 
+#: the tiled GEMM as a JDF, one k-chain per C tile, bodies on the device
+_GEMM_SRC = ("%global MT\n%global KT\n%global descA\n%global descB\n"
+             "%global descC\n"
+             "GEMM(m, n, k)\n  m = 0 .. MT-1\n  n = 0 .. MT-1\n"
+             "  k = 0 .. KT-1\n  : descC(m, n)\n"
+             "  READ A <- descA(m, k)\n  READ B <- descB(k, n)\n"
+             "  RW   C <- (k == 0) ? descC(m, n) : C GEMM(m, n, k-1)\n"
+             "       -> (k < KT-1) ? C GEMM(m, n, k+1) : descC(m, n)\n"
+             "BODY [type=TPU]\n"
+             "  C = C + jnp.dot(A, B, preferred_element_type=jnp.float32)\n"
+             "END\n")
+
+
 def test_device_lane_under_budget_pressure(dctx):
     """Regression (found by the verify drive): under a tight HBM budget,
     staging tile k+1 of one dispatch batch must not evict tile k staged
@@ -596,23 +609,13 @@ def test_device_lane_under_budget_pressure(dctx):
     rng = np.random.default_rng(21)
     a = rng.standard_normal((n, n)).astype(np.float32)
     b = rng.standard_normal((n, n)).astype(np.float32)
-    src = ("%global MT\n%global KT\n%global descA\n%global descB\n"
-           "%global descC\n"
-           "GEMM(m, n, k)\n  m = 0 .. MT-1\n  n = 0 .. MT-1\n"
-           "  k = 0 .. KT-1\n  : descC(m, n)\n"
-           "  READ A <- descA(m, k)\n  READ B <- descB(k, n)\n"
-           "  RW   C <- (k == 0) ? descC(m, n) : C GEMM(m, n, k-1)\n"
-           "       -> (k < KT-1) ? C GEMM(m, n, k+1) : descC(m, n)\n"
-           "BODY [type=TPU]\n"
-           "  C = C + jnp.dot(A, B, preferred_element_type=jnp.float32)\n"
-           "END\n")
     A = TiledMatrix("pbA", n, n, ts, ts)
     A.fill(lambda m, k: a[m*ts:(m+1)*ts, k*ts:(k+1)*ts])
     B = TiledMatrix("pbB", n, n, ts, ts)
     B.fill(lambda m, k: b[m*ts:(m+1)*ts, k*ts:(k+1)*ts])
     C = TiledMatrix("pbC", n, n, ts, ts)
     C.fill(lambda m, k: np.zeros((ts, ts), np.float32))
-    prog = compile_ptg(src, "pb-gemm")
+    prog = compile_ptg(_GEMM_SRC, "pb-gemm")
     # per-task staging pressure under test: region fusion stages each
     # fused chain's tiles once per REGION (different pressure shape,
     # covered by tests/test_fusion.py); the in-batch pin regression
@@ -641,3 +644,385 @@ def test_device_lane_under_budget_pressure(dctx):
                     st = dev._ncoh.state(dev.res_key(M.data_of(m, nn)))
                     assert st is None or st[3] == 0, (m, nn, st)
     assert dctx._ptdev.failed() is None
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 30: residency once per operand of a batch; adoption of resident bytes
+# ---------------------------------------------------------------------------
+
+_N, _TS = 64, 16
+_NT = _N // _TS
+
+
+def _need_lane(ctx):
+    lane = ctx._ptdev_lane()
+    if lane is None or _tpu_dev(ctx)._ncoh is None:
+        pytest.skip("native _ptdev unavailable")
+    return lane
+
+
+def _gemm_operands(tag, seed):
+    """A, B, C of a 4x4-tile GEMM with small whole numbers in them: every
+    product and sum is exact in f32, so the right C is numpy's, bit for bit."""
+    rng = np.random.default_rng(seed)
+    a = rng.integers(-4, 5, (_N, _N)).astype(np.float32)
+    b = rng.integers(-4, 5, (_N, _N)).astype(np.float32)
+    mats = []
+    for name, dense in (("A", a), ("B", b), ("C", np.zeros_like(a))):
+        M = TiledMatrix(f"{tag}{name}", _N, _N, _TS, _TS)
+        M.fill(lambda m, k, d=dense: d[m*_TS:(m+1)*_TS, k*_TS:(k+1)*_TS])
+        mats.append(M)
+    return a, b, mats
+
+
+def _gemm_pool(ctx, prog, mats):
+    return prog.instantiate(
+        ctx, globals={"MT": _NT, "KT": _NT},
+        collections=dict(zip(("descA", "descB", "descC"), mats)))
+
+
+class _TableSpy:
+    """The C coherency table, with its pin traffic written to ``log``."""
+
+    def __init__(self, table, log):
+        self._table, self.log = table, log
+
+    def stage_in(self, key, nbytes, version, flags=0, pin=0):
+        if pin:
+            self.log.append(("stage", key))
+        return self._table.stage_in(key, nbytes, version, flags, pin)
+
+    def pin(self, key):
+        self.log.append(("pin", key))
+        return self._table.pin(key)
+
+    def unpin(self, key):
+        self.log.append(("unpin", key))
+        return self._table.unpin(key)
+
+    def __getattr__(self, name):
+        return getattr(self._table, name)
+
+
+def _spy_closures(tp, monkeypatch, on_dispatch=None, on_poll=None):
+    """Wrap the pool's dispatch/poll closures as the lane receives them;
+    returns the box that will hold the closure's ``held`` dict."""
+    make = tp._mk_ptexec_dev_dispatch
+    box = {}
+
+    def spied(*args, **kw):
+        dispatch, poll, held = make(*args, **kw)
+        box["held"] = held
+
+        def spy_dispatch(ids):
+            n = dispatch(ids)
+            if on_dispatch is not None:
+                on_dispatch(ids, held)
+            return n
+
+        def spy_poll():
+            done = poll()
+            if on_poll is not None:
+                on_poll(done, held)
+            return done
+        return spy_dispatch, spy_poll, held
+    monkeypatch.setattr(tp, "_mk_ptexec_dev_dispatch", spied)
+    return box
+
+
+def _assert_unpinned(dev, mats):
+    for M in mats:
+        for m in range(M.mt):
+            for n in range(M.nt):
+                data = M.data_of(m, n)
+                st = dev._ncoh.state(dev.res_key(data))
+                assert st is None or st[3] == 0, (M.name, m, n, st)
+                for c in data.copies.values():
+                    assert c.readers == 0, (M.name, m, n)
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "per-task"])
+def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, fused):
+    """The lane touches residency once per distinct memory operand of a
+    batch: the stage-in's pin is the operand's only pin (no table pin per
+    program and operand), every pin is given back, and nothing stays
+    counted or pinned after the pool."""
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    a, b, mats = _gemm_operands("po" + "fu"[fused], 30)
+    log = []
+    monkeypatch.setattr(dev, "_ncoh", _TableSpy(dev._ncoh, log))
+    mca.set("region_fusion", fused)
+    try:
+        tp = _gemm_pool(dctx, compile_ptg(_GEMM_SRC, f"po-gemm-{fused}"), mats)
+        box = _spy_closures(
+            tp, monkeypatch,
+            on_dispatch=lambda ids, held: log.append(("batch", len(ids))))
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=90)
+    finally:
+        mca.params.unset("region_fusion")
+    assert dctx._ptdev.failed() is None
+    assert np.array_equal(mats[2].to_dense(), a @ b)
+    assert box["held"] == {}
+    _assert_unpinned(dev, mats)
+    # a batch's pins: the distinct operands it staged, each once
+    batches, staged = [], []
+    for what, v in log:
+        if what == "stage":
+            staged.append(v)
+        elif what == "batch":
+            batches.append((v, staged))
+            staged = []
+    assert not staged and sum(n for n, _ in batches) == \
+        (_NT * _NT if fused else _NT ** 3)
+    for n, keys in batches:
+        assert len(keys) == len(set(keys))
+        # a fused k-chain reads 2 * KT + 1 tiles, a task at most 3
+        assert len(keys) <= min(n * (2 * _NT + 1 if fused else 3),
+                                3 * _NT * _NT)
+    pins = sum(len(keys) for _, keys in batches)
+    assert pins >= 3 * _NT * _NT
+    assert not [e for e in log if e[0] == "pin"], "a pin per program operand"
+    assert sum(1 for e in log if e[0] == "unpin") == pins
+
+
+def _staggered_is_ready():
+    """An ``is_ready`` for every array: true from the ``n``-th ask on, ``n``
+    going 0, 1, 2, 0, ... over the arrays in the order they are first asked
+    about, so the programs of one batch retire at different polls."""
+    left = {}
+
+    def is_ready(array):
+        ent = left.setdefault(id(array), [array, len(left) % 3])
+        ent[1] -= 1
+        return ent[1] < 0
+    return is_ready
+
+
+def test_operand_in_flight_is_no_victim_after_a_reader_retired(dctx,
+                                                               monkeypatch):
+    """Under a budget of four tiles for a pool that reads 48, with the
+    programs of a batch retiring at different polls: a copy that a program
+    in flight still reads is never evicted, also once another reader of it
+    has retired, and every count and pin comes back to zero."""
+    import jax
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    dev.set_budget(4 * _TS * _TS * 4, unit=1024)
+    a, b, mats = _gemm_operands("vi", 31)
+    monkeypatch.setattr(type(jax.device_put(np.zeros(1), dev.jax_device)),
+                        "is_ready", _staggered_is_ready())
+    peak, seen, bad = {}, {"partial": 0, "evictions": 0}, []
+
+    def on_dispatch(ids, held):
+        for mi, h in held.items():
+            peak[mi] = max(peak.get(mi, 0), h[1])
+
+    evict = dev._evict_key_locked
+
+    def spy_evict(key, copy, drop_table):
+        held = box.get("held", {})
+        seen["evictions"] += 1
+        seen["partial"] += any(0 < h[1] < peak.get(mi, 0)
+                               for mi, h in held.items())
+        if copy.readers or any(h[0] is copy for h in held.values()):
+            bad.append(key)
+        return evict(key, copy, drop_table)
+    monkeypatch.setattr(dev, "_evict_key_locked", spy_evict)
+    mca.set("region_fusion", False)
+    try:
+        tp = _gemm_pool(dctx, compile_ptg(_GEMM_SRC, "vi-gemm"), mats)
+        box = _spy_closures(tp, monkeypatch, on_dispatch=on_dispatch)
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=90)
+    finally:
+        mca.params.unset("region_fusion")
+    assert dctx._ptdev.failed() is None
+    assert np.array_equal(mats[2].to_dense(), a @ b)
+    assert seen["evictions"] > 0 and not bad, (seen, bad)
+    assert seen["partial"] > 0, "no eviction while a reader had retired"
+    assert box["held"] == {}
+    _assert_unpinned(dev, mats)
+
+
+def _count_puts(dev, monkeypatch):
+    puts, real = [], dev._jax.device_put
+
+    def device_put(x, *args, **kw):
+        puts.append(x)
+        return real(x, *args, **kw)
+    monkeypatch.setattr(dev._jax, "device_put", device_put)
+    return puts
+
+
+def test_stage_in_adopts_an_array_already_on_the_device(dctx, monkeypatch):
+    """A datum whose newest copy holds a committed array on this device
+    stages in without a ``device_put``: no byte counted, ``adopted`` + 1,
+    and the table and the pin as a miss leaves them."""
+    import jax
+    dev = _tpu_dev(dctx)
+    M = TiledMatrix("AD", 2 * _TS, _TS, _TS, _TS)
+    M.fill(lambda m, n: np.full((_TS, _TS), 1.0 + m, np.float32))
+    here, twin = M.data_of(0, 0), M.data_of(1, 0)
+    arr = jax.device_put(np.full((_TS, _TS), 7.0, np.float32), dev.jax_device)
+    here.get_copy(0).payload = arr
+    here.bump_version(0)
+    twin.bump_version(0)            # the same version, a numpy payload
+    puts = _count_puts(dev, monkeypatch)
+    moved, adopted, resident = \
+        dev.transfer_in_bytes, dev.adopted, dev._resident_bytes
+    copy = dev.lane_stage_in(here, pin=True)
+    assert not puts and dev.transfer_in_bytes == moved
+    assert dev.adopted == adopted + 1
+    assert copy.payload is arr and copy.version == here.version
+    assert copy.readers == 1 and dev._resident_bytes == resident + arr.nbytes
+    other = dev.lane_stage_in(twin, pin=True)       # a miss, for comparison
+    assert len(puts) == 1 and dev.transfer_in_bytes == moved + arr.nbytes
+    assert dev.adopted == adopted + 1
+    if dev._ncoh is not None:
+        assert dev._ncoh.state(dev.res_key(here)) == \
+            dev._ncoh.state(dev.res_key(twin))
+        assert dev._ncoh.state(dev.res_key(here))[3] == 1
+    dev.unpin_copy(copy)
+    dev.unpin_copy(other)
+    assert copy.readers == 0
+    # a newer version written on the device: adopted again, in place
+    newer = arr + 1.0
+    here.get_copy(0).payload = newer
+    here.bump_version(0)
+    assert dev.lane_stage_in(here) is copy and copy.payload is newer
+    assert copy.version == here.version and copy.readers == 0
+    assert dev.adopted == adopted + 2 and len(puts) == 1
+    assert dev._resident_bytes == resident + 2 * arr.nbytes
+    assert dctx.devices.statistics()[dev.name]["adopted"] == dev.adopted
+
+
+@pytest.mark.parametrize("where", ["numpy", "another device", "uncommitted"])
+def test_stage_in_still_transfers_what_is_elsewhere(dctx, monkeypatch, where):
+    """Adoption reads where the newest bytes live: a host tile, an array on
+    another device and an array no device was chosen for go through
+    ``device_put`` and count their bytes, as before."""
+    import jax
+    import jax.numpy as jnp
+    dev = _tpu_dev(dctx)
+    tile = np.full((_TS, _TS), 3.0, np.float32)
+    if where == "another device":
+        others = [d for d in jax.devices() if d != dev.jax_device]
+        if not others:
+            pytest.skip("one device only")
+        tile = jax.device_put(tile, others[0])
+    elif where == "uncommitted":
+        tile = jnp.asarray(tile)
+        assert not tile.committed
+    M = TiledMatrix("EL" + where[:2], _TS, _TS, _TS, _TS)
+    M.fill(lambda m, n: np.asarray(tile))
+    data = M.data_of(0, 0)
+    data.get_copy(0).payload = tile
+    puts = _count_puts(dev, monkeypatch)
+    moved, adopted = dev.transfer_in_bytes, dev.adopted
+    copy = dev.lane_stage_in(data)
+    assert len(puts) == 1 and puts[0] is tile
+    assert dev.transfer_in_bytes == moved + tile.nbytes
+    assert dev.adopted == adopted
+    assert copy.payload.devices() == {dev.jax_device}
+    assert np.array_equal(np.asarray(copy.payload), np.asarray(tile))
+
+
+def test_chained_pools_stage_c_in_by_adoption(dctx, monkeypatch):
+    """A second instantiation over the first pool's written C: the C tiles'
+    newest copies are the device arrays the write-back left, so they stage
+    in by adoption, and C = 2 A B, bit for bit."""
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    a, b, mats = _gemm_operands("ch", 32)
+    prog = compile_ptg(_GEMM_SRC, "ch-gemm")
+    puts = _count_puts(dev, monkeypatch)
+    for solve in (1, 2):
+        moved, adopted, put = dev.transfer_in_bytes, dev.adopted, len(puts)
+        tp = _gemm_pool(dctx, prog, mats)
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=90)
+        assert dctx._ptdev.failed() is None
+        assert np.array_equal(mats[2].to_dense(), solve * (a @ b))
+        tiles, tile_bytes = _NT * _NT, _TS * _TS * 4
+        if solve == 1:              # 48 host tiles, every one a transfer
+            assert dev.adopted == adopted and len(puts) == put + 3 * tiles
+            assert dev.transfer_in_bytes == moved + 3 * tiles * tile_bytes
+        else:                       # A and B resident, C adopted
+            assert dev.adopted == adopted + tiles and len(puts) == put
+            assert dev.transfer_in_bytes == moved
+    _assert_unpinned(dev, mats)
+
+
+@pytest.mark.parametrize("spans", [True, False], ids=["on", "off"])
+def test_ptdev_pins_records_once_a_dispatch_callback(monkeypatch, spans):
+    """``ptdev.pins``: with spans on, one record per dispatch callback
+    holding the table pins it took (3 a program where a pool surfaces as
+    one batch); with spans off, nothing."""
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    from parsec_tpu.utils.hist import HIST_NAMES, histograms
+    assert "pins" in HIST_NAMES["ptdev"]
+    mca.set("device_tpu_over_cpu", True)
+    if spans:
+        mca.set("hist_enabled", True)
+    ctx = Context(nb_cores=1)
+    try:
+        _need_lane(ctx)
+        assert (ctx._spans is not None) == spans
+        a, b, mats = _gemm_operands("ph" + "ny"[spans], 33)
+        before = histograms.snapshot().get("ptdev.pins",
+                                           {"count": 0, "sum_ns": 0})
+        log = []
+        tp = _gemm_pool(ctx, compile_ptg(_GEMM_SRC, f"ph-gemm-{spans}"), mats)
+        _spy_closures(tp, monkeypatch,
+                      on_dispatch=lambda ids, held: log.append(len(ids)))
+        dev = _tpu_dev(ctx)
+        pins = []
+        monkeypatch.setattr(dev, "_ncoh", _TableSpy(dev._ncoh, pins))
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=90)
+        assert np.array_equal(mats[2].to_dense(), a @ b)
+        after = histograms.snapshot().get("ptdev.pins",
+                                          {"count": 0, "sum_ns": 0})
+        if spans:
+            assert after["count"] - before["count"] == len(log)
+            assert after["sum_ns"] - before["sum_ns"] == \
+                sum(1 for e in pins if e[0] == "stage")
+        else:
+            assert after == before
+    finally:
+        ctx.fini()
+        mca.params.unset("hist_enabled")
+        mca.params.unset("device_tpu_over_cpu")
+
+
+@pytest.mark.parametrize("snapshot, want", [
+    ({}, None),                                     # no histograms at all
+    ({"ptdev.dispatch_ns": {"count": 1024, "sum_ns": 1}}, None),   # no counter
+    ({"ptdev.pins": {"count": 0, "sum_ns": 0},
+      "ptdev.dispatch_ns": {"count": 0, "sum_ns": 0}}, None),      # no program
+    ({"ptdev.pins": {"count": 2, "sum_ns": 6144},
+      "ptdev.dispatch_ns": {"count": 2048, "sum_ns": 1}}, 3.0),
+])
+def test_pins_per_program_reader(monkeypatch, snapshot, want):
+    """``chipbench/layers/pins_per_program.py``: ``ptdev.pins`` sum over
+    ``ptdev.dispatch_ns`` count, nothing where the program lacks either; its
+    entry is the last of BENCHMARK.json's ``per_layer``."""
+    import json
+    import os
+    from parsec_tpu.utils.hist import histograms
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from chipbench.layers import pins_per_program
+    monkeypatch.setattr(histograms, "snapshot", lambda: snapshot)
+    assert pins_per_program.read(None) == want
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = json.load(f)["per_layer"][-1]
+    assert entry == {"name": "pins_per_program", "unit": "pins/program",
+                     "better": "lower", "source": "program_counter",
+                     "layer": "device issue", "moves": "tasks_per_s",
+                     "workloads": ["ptg_gemm.ts512"]}

@@ -159,6 +159,47 @@ def test_launcher_and_smoke_parents_do_not_import_jax():
     assert p.returncode == 0, p.stderr[-2000:]
 
 
+def test_launcher_hands_on_whole_lines():
+    """Four ranks that print long lines at the same moment, unbuffered (a
+    ``print`` is then two writes, text and newline): the launcher's stdout
+    still holds every line whole. The ranks of a benchmark cell report so
+    after their last barrier, and their parent parses line by line."""
+    import re
+    env = dict(os.environ, PYTHONUNBUFFERED="1")
+    p = subprocess.run(
+        [sys.executable, "-m", "parsec_tpu.launch", "-n", "4", "--cpu",
+         os.path.join("tests", "_launch_shout.py")],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=240)
+    assert p.returncode == 0, p.stderr[-2000:]
+    lines = p.stdout.splitlines()
+    broken = [l[:80] for l in lines
+              if not re.fullmatch(r"RANK \d \d+ x{1500}", l)]
+    assert len(lines) == 4 * 150 and not broken, (len(lines), broken[:3])
+
+
+def test_launcher_ends_when_its_reader_is_gone():
+    """A launcher whose stdout reader dies does not leave its ranks blocked
+    on full pipes until the job's deadline (holding the multiproc lock all
+    the while): their next write fails and the job ends at once."""
+    import time
+    p = subprocess.Popen(
+        [sys.executable, "-m", "parsec_tpu.launch", "-n", "2", "--cpu",
+         "--timeout", "120", os.path.join("tests", "_launch_shout.py"),
+         "1000000"],
+        cwd=REPO, env=dict(os.environ, PYTHONUNBUFFERED="1"),
+        stdout=subprocess.PIPE, stderr=subprocess.DEVNULL)
+    try:
+        assert p.stdout.readline().startswith(b"RANK ")
+        t0 = time.monotonic()
+        p.stdout.close()
+        assert p.wait(timeout=60) != 0
+        assert time.monotonic() - t0 < 60
+    finally:
+        if p.poll() is None:
+            p.kill()
+            p.wait()
+
+
 def test_compile_cache_placed_from_outside(monkeypatch):
     from parsec_tpu.utils import compile_cache
     env = {}
