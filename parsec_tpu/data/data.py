@@ -225,6 +225,16 @@ class Data:
                 self.owner_device = device_index
             return self.version
 
+    def write_host(self, value: Any) -> int:
+        """A writer's ``value`` becomes the host copy's payload (the copy
+        is made OWNED where there was none) and the newest version."""
+        host = self.copies.get(0)
+        if host is None:
+            self.create_copy(0, value, COHERENCY_OWNED)
+        else:
+            host.payload = value
+        return self.bump_version(0)
+
     def __repr__(self) -> str:  # pragma: no cover
         return f"<Data key={self.key} v={self.version} copies={list(self.copies)}>"
 

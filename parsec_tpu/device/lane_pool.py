@@ -1,0 +1,333 @@
+"""A pool on the native device lane: how a ready device task's operands
+become resident, pinned, read and released, decided here and nowhere
+else. :func:`bind` takes a pool as plain data and an engine; it knows no
+DSL (the PTG compiler is its first caller). docs/device_lane.md.
+"""
+
+from __future__ import annotations
+
+import collections
+import time
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+
+from ..utils.xla_trace import PTDEV_DISPATCH, PTDEV_POLL, PTDEV_RETIRE
+from .native import PTDEV_STATS
+
+
+def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
+         in_refs, ndflows, cls_of, fns: Sequence[Optional[Callable]],
+         written: Sequence[Tuple[int, ...]], names: Sequence[str],
+         slots: List[Any], mem_datas: Sequence[Any],
+         writebacks: Dict[int, List], dev_mask: Sequence[int],
+         ndev_tasks: int, fusion: Optional[Dict[str, Any]] = None,
+         bucket: int = 0,
+         cost_obs: Optional[Dict] = None) -> Tuple[int, Dict[int, List]]:
+    """Bind a flattened data pool to ``devlane`` (device/native.py): from
+    then on a task of ``engine`` (``dev_bind``, ``dev_retire_capsule``,
+    ``trace_mark``: the ``ptexec`` ``Graph``) whose ``dev_mask`` entry is
+    set surfaces onto the lane's pending queue when it becomes ready, in
+    place of the ready structure, and retires through the engine's
+    GIL-free capsule.
+
+    Task ``i`` is instance ``i - bases[k]`` of class ``k = cls_of[i]``
+    with parameters ``params[k][i - bases[k]]``; its ``ndflows[k]`` flows
+    own the slots from ``slot_base[i]`` on, and ``in_refs[slot]`` names a
+    flow's input: a producer's slot (>= 0), nothing (-1), or
+    ``mem_datas[-2 - ref]``. Class ``k`` runs ``fns[k](*params,
+    *inputs)`` (None: inputs are forwarded) and returns the flows at
+    positions ``written[k]``; ``names[k]`` keys its entries of
+    ``cost_obs`` ((name, bucket, dev) -> [count, sum_ns]; None:
+    unobserved). ``writebacks[i]``: the (flow position, ``Data``) pairs
+    task ``i`` writes to memory. ``ndev_tasks``: the tasks under the mask.
+
+    ``fusion``, for an engine whose nodes are regions and seams:
+    ``orig_of`` maps a node to its task, ``dev_regions`` a region's node
+    to its program (``ext``, ``ext_mems``, ``out_slots``, ``jitted``,
+    ``wb_pairs``, ``ntasks``, ``cls``, ``cold``), ``marks`` is the
+    (event, start, end) of ``engine.trace_mark``.
+
+    Call it LAST: ``dev_bind`` surfaces zero-dependency device tasks at
+    once and the manager may dispatch them before this returns. Returns
+    the lane's pool id (for ``unbind_pool``) and ``held``.
+    """
+    dispatch, poll, held = _closures(
+        devlane, engine, bases, params, slot_base, in_refs, ndflows, cls_of,
+        fns, written, names, slots, mem_datas, writebacks, fusion, bucket,
+        cost_obs)
+    pid = devlane.bind_pool(engine, dispatch, poll)
+    PTDEV_STATS["pools_engaged"] += 1
+    PTDEV_STATS["tasks_engaged"] += ndev_tasks
+    engine.dev_bind(devlane.submit_capsule(), pid, dev_mask)
+    devlane.clane.notify()
+    return pid, held
+
+
+def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
+              cls_of, fns, written, names, slots, mem_datas, writebacks,
+              fusion, bucket, cost_obs):
+    """The pool's dispatch/poll pair, both run on the lane's manager
+    thread with the GIL held:
+
+    * ``dispatch(ids)`` — the push+exec phases of the reference's stream
+      pipeline (device_gpu.c:3438) on XLA's async runtime: FIRST every
+      memory operand of the whole batch stages in (version-checked
+      through the C coherency table; ``device_put`` is asynchronous, so
+      the transfers overlap compute already in flight), THEN each
+      program dispatches (async) and its future outputs land in the
+      slots at once — safe because no consumer runs before this task
+      RETIRES, which only happens after its completion events fire;
+    * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
+      inflight program's outputs (cudaEventQuery, device_gpu.c:2593).
+      Completed tasks write back to memory, give up their reads and
+      return their ids; the C side then calls the engine's ``dev_retire``.
+
+    Residency is touched once per distinct memory operand of a BATCH:
+    the push phase's stage-in takes the operand's one pin (table +
+    ``readers``), ``held`` counts the programs in flight that read it,
+    and the pin is given back when the last of them retires (or at the
+    end of ``dispatch``, where no program of the batch reads it).
+    Returns ``(dispatch, poll, held)``; ``held`` is empty whenever
+    nothing is in flight.
+    """
+    dev = devlane.device
+    inflight: "collections.deque" = collections.deque()
+    # device-side cost observation (ISSUE 18): each inflight entry is
+    # stamped at dispatch and observed at retire — the elapsed window
+    # covers the async compute, the output-ready wait, AND the lane's
+    # poll cadence, i.e. the throughput a task actually experiences
+    # on this path (what placement must compare against the CPU
+    # lane's batch-amortized cost). Stage-ins time separately into
+    # the __stage_in__ pseudo-class. All writes happen on the
+    # manager thread; the fold reads after unbind.
+    _pc = time.perf_counter_ns
+    dev_clock = [0]      # batch-amortization mark (see poll)
+    if cost_obs is not None:
+        from ..core.costmodel import STAGE_IN as _STG, shape_bucket
+
+        def _obs(key, w, ns):
+            e = cost_obs.get(key)
+            if e is None:
+                cost_obs[key] = [w, ns]
+            else:
+                e[0] += w
+                e[1] += ns
+
+        def _stage(mi):
+            t0 = _pc()
+            copy = dev.lane_stage_in(mem_datas[mi], pin=True)
+            nb = getattr(getattr(copy, "payload", None), "nbytes", 0)
+            _obs((_STG, shape_bucket(nb), "tpu"), 1, _pc() - t0)
+            return copy
+    else:
+        _obs = None
+
+        def _stage(mi):
+            return dev.lane_stage_in(mem_datas[mi], pin=True)
+    sp = devlane.ctx._spans
+    if sp is not None:
+        pinned = [0]     # table pins taken so far, for ptdev.pins
+
+        # ptdev.stage_in: the push phase's misses only (a hit moves
+        # no bytes); on the timeline a miss is the dev.stage_in
+        # annotation of TPUDevice._stage_in_copy, inside ptdev.dispatch
+        def _stage(mi, _inner=_stage):
+            moved, t0 = dev.transfer_in_bytes, _pc()
+            copy = _inner(mi)
+            pinned[0] += 1      # every pin of the closure is a stage-in's
+            if dev.transfer_in_bytes != moved:
+                sp.pt_stage_in.record(_pc() - t0)
+            return copy
+    # mi -> [device copy, programs in flight that read it, pins held]:
+    # owned by the manager thread (dispatch and poll both run there
+    # with the GIL, as _obs relies on), so no lock and no table call
+    # per (program, operand). An operand staged again by a later batch
+    # while an earlier reader still flies joins the same entry; its
+    # pins nest in the table as they always did.
+    held: Dict[int, List[Any]] = {}
+
+    def _hold(mi, staged):
+        # pin=True: the eviction pin is taken inside the table's
+        # reserve critical section, so no peer thread's stage-in can
+        # evict this entry first, and staging tile k+1 of this very
+        # batch cannot evict tile k before the exec phase reads it
+        # (found by the verify drive: "dot got NoneType")
+        copy = _stage(mi)
+        h = held.get(mi)
+        if h is None:
+            h = held[mi] = [copy, 0, 0]
+        h[2] += 1
+        staged[mi] = h
+
+    def _release(mi, h):
+        del held[mi]
+        for _ in range(h[2]):
+            dev.unpin_copy(h[0])
+    if fusion is not None:
+        # fused pool (ISSUE 12): a device REGION dispatches as one
+        # region-sized async program; its inflight/retire id is the
+        # COMPACT node id (what the C release walk expects), while
+        # slot/param arrays index by original id via orig_of
+        _forig = fusion["orig_of"]
+        _dregs = fusion["dev_regions"]
+        _graph = engine
+        _evr, _fs, _fe = fusion["marks"]
+    else:
+        _forig = _dregs = _graph = None
+
+    def dispatch(ids):
+        # PUSH phase: issue every memory-endpoint stage-in for the
+        # whole batch before any compute dispatch, each distinct
+        # operand once, pinned THE MOMENT it stages (_hold)
+        staged: Dict[int, List[Any]] = {}
+        if _obs is not None and not inflight:
+            # idle -> active: restart the amortization clock so idle
+            # gaps between batches never land in any task's cost
+            dev_clock[0] = _pc()
+        for i in ids:
+            if _dregs is not None:
+                r = _dregs.get(i)
+                if r is not None:
+                    for mi in r["ext_mems"]:
+                        if mi not in staged:
+                            _hold(mi, staged)
+                    continue
+                i = _forig[i]
+            base = slot_base[i]
+            for dj in range(ndflows[cls_of[i]]):
+                r = in_refs[base + dj]
+                if r < -1 and (-2 - r) not in staged:
+                    _hold(-2 - r, staged)
+        # EXEC phase: dispatch each ready device task asynchronously
+        for i in ids:
+            oi = i
+            if _dregs is not None:
+                r = _dregs.get(i)
+                if r is not None:
+                    # region-sized dispatch: ONE jitted program for
+                    # the whole fused region, async like any task;
+                    # the retire id stays the compact node id
+                    ev: List[Any] = []
+                    for kk, v in r["ext"]:
+                        if kk == "slot":
+                            ev.append(slots[v])
+                        else:
+                            h = staged[v]
+                            h[1] += 1       # one more reader in flight
+                            ev.append(h[0].payload)
+                    _graph.trace_mark(_evr, i, _fs)
+                    outs, wbs_v = r["jitted"](tuple(ev))
+                    _graph.trace_mark(_evr, i, _fe)
+                    for s, v in zip(r["out_slots"], outs):
+                        slots[s] = v
+                    events = tuple(v for v in tuple(outs) + tuple(wbs_v)
+                                   if hasattr(v, "is_ready"))
+                    inflight.append((
+                        i, events, r["wb_pairs"], list(wbs_v),
+                        r["ext_mems"], r["ntasks"],
+                        None if (_obs is None or r.get("cold")) else
+                        (names[r["cls"]], bucket, "tpu_fused")))
+                    continue
+                oi = _forig[i]
+            k = cls_of[oi]
+            base = slot_base[oi]
+            nd = ndflows[k]
+            vals: List[Any] = []
+            reads: List[int] = []
+            for dj in range(nd):
+                r = in_refs[base + dj]
+                if r >= 0:
+                    vals.append(slots[r])
+                elif r == -1:
+                    vals.append(None)
+                else:
+                    h = staged[-2 - r]
+                    h[1] += 1               # one more reader in flight
+                    reads.append(-2 - r)
+                    vals.append(h[0].payload)
+            fn = fns[k]
+            events = ()
+            if fn is not None:
+                outs = fn(*params[k][oi - bases[k]], *vals)
+                for oj, dj in enumerate(written[k]):
+                    vals[dj] = outs[oj]
+                events = tuple(v for v in outs
+                               if hasattr(v, "is_ready"))
+            for dj in range(nd):
+                slots[base + dj] = vals[dj]
+            inflight.append((i, events, writebacks.get(oi), vals, reads,
+                             1,
+                             None if _obs is None else
+                             (names[k], bucket, "tpu")))
+        for mi, h in staged.items():
+            if not h[1]:            # staged, and no program reads it
+                _release(mi, h)
+        return len(ids)
+
+    def poll():
+        done: List[int] = []
+        retired: List[Tuple] = []
+        for _ in range(len(inflight)):
+            ent = inflight.popleft()
+            i, events, wbs, vals, reads, w, ckey2 = ent
+            if events and not all(a.is_ready() for a in events):
+                inflight.append(ent)
+                continue
+            if sp is not None:
+                tok = sp.begin(PTDEV_RETIRE)
+            if wbs:
+                for dj, dref in wbs:
+                    dref.write_host(vals[dj])
+            for mi in reads:
+                h = held[mi]
+                h[1] -= 1
+                if not h[1]:        # its last reader in flight retired
+                    _release(mi, h)
+            dev.executed_tasks += w
+            retired.append((ckey2, w))
+            done.append(i)
+            if sp is not None:
+                retired_ns[0] += sp.end(tok, sp.pt_retire)
+        if retired and _obs is not None:
+            # batch amortization, the SAME semantics as the C lane's
+            # exec bump: the wall window since the last retire sweep
+            # (or the idle->active mark) divides across every task
+            # weight retired in it. Per-entry dispatch->retire spans
+            # overlap under pipelining, so summing them would bill
+            # the same wall clock N-inflight times over and make the
+            # device look slower than the wall it actually consumed
+            # — placement would then mis-compare against the CPU
+            # lane's throughput-denominated cost. Keyless entries
+            # (cold regions) still weigh in the denominator: they
+            # consumed part of the window.
+            now = _pc()
+            total_w = sum(w for _, w in retired)
+            per = (now - dev_clock[0]) / max(total_w, 1)
+            for ckey2, w in retired:
+                if ckey2 is not None:
+                    _obs(ckey2, w, per * w)
+            dev_clock[0] = now
+        return done
+
+    if sp is None:
+        return dispatch, poll, held
+    retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
+
+    def traced_dispatch(ids):
+        # one span a callback, recorded once per device program; the
+        # table pins the callback took, one record
+        tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
+        try:
+            return dispatch(ids)
+        finally:
+            sp.end(tok, sp.pt_dispatch, n=len(ids))
+            sp.pt_pins.record(pinned[0] - before)
+
+    def traced_poll():
+        # one record a pass, the retirements' own spans subtracted
+        tok, before = sp.begin(PTDEV_POLL), retired_ns[0]
+        try:
+            return poll()
+        finally:
+            sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
+
+    return traced_dispatch, traced_poll, held

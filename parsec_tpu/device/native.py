@@ -13,8 +13,8 @@ everything around it:
   created lazily the first time a TPU-bodied pool prepares for the
   native execution lane and torn down at ``Context.fini``;
 * **pool routing** — the manager calls ONE ``dispatch(pool, ids)`` /
-  ``poll()`` pair; this module routes them to the per-pool closures the
-  PTG compiler builds (input gather from the lane's slot array,
+  ``poll()`` pair; this module routes them to the per-pool closures of
+  ``device/lane_pool.py`` (input gather from the pool's slot array,
   version-checked stage-in through the C coherency table, async jitted
   dispatch, write-backs at completion);
 * **counters** — ``PTDEV_STATS`` engagement accounting plus the C-side
@@ -135,15 +135,26 @@ def make_coh_table(budget: int):
         return None
 
 
-class _PoolState:
-    """One bound pool's dispatch/poll closures (built by the compiler)."""
+def admits_pool(cross_rank: bool) -> bool:
+    """May a pool with device-bodied classes use the lane at all? Asked
+    before placement. The device and the cross-rank lanes are not
+    combined yet, and ``--mca device_native 0`` keeps the interpreted
+    device module: both by design, counted ``pools_ineligible``."""
+    if cross_rank or not mca.get("device_native", True):
+        PTDEV_STATS["pools_ineligible"] += 1
+        return False
+    return True
 
-    __slots__ = ("dispatch", "poll", "engine")
 
-    def __init__(self, dispatch: Callable, poll: Callable, engine) -> None:
-        self.dispatch = dispatch
-        self.poll = poll
-        self.engine = engine
+def lane_for_pool(ctx) -> Optional["NativeDeviceLane"]:
+    """The context's lane for a pool that placed classes on the device,
+    or None, counted ``pools_fallback``: eligible, device present, and
+    the ``_ptdev`` module or the lane is missing (the silent-regression
+    signal)."""
+    lane = ctx._ptdev_lane()
+    if lane is None:
+        PTDEV_STATS["pools_fallback"] += 1
+    return lane
 
 
 class NativeDeviceLane:
@@ -178,7 +189,8 @@ class NativeDeviceLane:
         self.device = device          # the TPUDevice whose chip we drive
         self._mod = load_ptdev()
         self.clane = self._mod.Lane()
-        self._pools: Dict[int, _PoolState] = {}
+        #: pool id -> its (dispatch, poll) closures (device/lane_pool.py)
+        self._pools: Dict[int, Tuple[Callable, Callable]] = {}
         self._next_pool = 1
         self._stats_cache: Tuple[float, Optional[dict]] = (0.0, None)
         self._coh_cache: Tuple[float, Optional[dict]] = (0.0, None)
@@ -203,7 +215,7 @@ class NativeDeviceLane:
         pid = self._next_pool
         self._next_pool += 1
         self.clane.bind_pool(pid, engine.dev_retire_capsule(), engine)
-        self._pools[pid] = _PoolState(dispatch, poll, engine)
+        self._pools[pid] = (dispatch, poll)
         return pid
 
     def unbind_pool(self, pool_id: int) -> None:
@@ -227,15 +239,13 @@ class NativeDeviceLane:
     # safe. A pool unbound between submit and dispatch just drops its
     # ids here (the C side counts unrouted retires as late_retires).
     def _dispatch(self, pool: int, ids: List[int]) -> int:
-        st = self._pools.get(pool)
-        if st is None:
-            return 0
-        return st.dispatch(ids)
+        closures = self._pools.get(pool)
+        return 0 if closures is None else closures[0](ids)
 
     def _poll(self):
         done = []
-        for pid, st in list(self._pools.items()):
-            for tid in st.poll():
+        for pid, (_dispatch, poll) in list(self._pools.items()):
+            for tid in poll():
                 done.append((pid, tid))
         return done
 

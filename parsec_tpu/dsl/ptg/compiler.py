@@ -41,11 +41,10 @@ from ...core.task import (
 from ...core.futures import DataCopyFuture
 from ...data.data import COHERENCY_OWNED, DataCopy
 from ...data.reshape import NamedDatatype, default_datatype
+from ...device import native as dev_native
 from ...device.tpu import make_tpu_hook
 from ...utils import mca, output
-from ...utils.xla_trace import (
-    PTDEV_DISPATCH, PTDEV_POLL, PTDEV_RETIRE, PTG_LOWER,
-)
+from ...utils.xla_trace import PTG_LOWER
 from . import parser as P
 
 mca.register("ptg_agglomerate", True,
@@ -1474,13 +1473,7 @@ class PTGTaskpool(Taskpool):
             # dispatch — exactly the chore the interpreted FSM's device
             # selection would pick on a CPU-only host.
             if ctx.devices.by_type(DEV_TPU):
-                from ...device.native import PTDEV_STATS
-                if lane_comm is not None or not mca.get("device_native",
-                                                        True):
-                    # device + cross-rank lanes are not combined yet, and
-                    # --mca device_native 0 keeps the interpreted device
-                    # module: both ineligible-by-design
-                    PTDEV_STATS["pools_ineligible"] += 1
+                if not dev_native.admits_pool(lane_comm is not None):
                     return None
                 use_dev = True
         self._ptexec_refusal = "fallback"
@@ -1511,12 +1504,8 @@ class PTGTaskpool(Taskpool):
                 use_dev = False
         devlane = None
         if use_dev:
-            devlane = ctx._ptdev_lane()
+            devlane = dev_native.lane_for_pool(ctx)
             if devlane is None:
-                # eligible, device present, but the ptdev module/lane is
-                # missing: the silent-regression signal
-                from ...device.native import PTDEV_STATS
-                PTDEV_STATS["pools_fallback"] += 1
                 return None
         # consumer (b): measured fusion limits (dsl/fusion.py). The
         # decline set and the break-even cap shape the fusion plan, so
@@ -1614,35 +1603,25 @@ class PTGTaskpool(Taskpool):
                           flat["prio"], data["in_off"], data["in_slots"],
                           slot_uses)
         slots: List[Any] = [None] * data["n_slots"]
-        mem_datas = []
-        for dc_name, idx in data["mem_reads"]:
-            dc = self.collections.get(dc_name)
-            if dc is None:
-                output.fatal(f"PTG taskpool {self.name}: unknown "
-                             f"collection {dc_name!r}")
-            mem_datas.append(dc.data_of(*idx))
-        writebacks: Dict[int, List] = {}
-        for tid, dj, dc_name, idx in data["writebacks"]:
-            dc = self.collections.get(dc_name)
-            if dc is None:
-                output.fatal(f"PTG taskpool {self.name}: unknown "
-                             f"collection {dc_name!r}")
-            writebacks.setdefault(tid, []).append((dj, dc.data_of(*idx)))
+        mem_datas, writebacks = self._ptexec_mem(data["mem_reads"],
+                                                 data["writebacks"])
         lane = {"graph": graph, "slots": slots,
                 "n": flat["n"], "finalized": False}
         self._ptexec_cost_bind(lane, graph, flat, names, bucket)
         if owners is not None:
             self._ptexec_bind_comm(lane, lane_comm, owners)
+        class_fns = self._ptexec_class_fns(classes, data)
         lane["callback"] = self._mk_ptexec_data_callback(
             flat, classes, slots, mem_datas, writebacks,
             comm=None if comm_info is None else dict(
-                comm_info, lane=lane_comm, pool_id=lane["pool_id"]))
+                comm_info, lane=lane_comm, pool_id=lane["pool_id"]),
+            class_fns=class_fns)
         if use_dev:
             # bind LAST: dev_bind surfaces zero-dep device seeds onto the
             # lane immediately, and the manager may dispatch them before
             # this function returns — every closure it touches (slots,
             # mem_datas, writebacks) exists by now
-            self._ptexec_bind_dev(lane, devlane, flat, classes,
+            self._ptexec_bind_dev(lane, devlane, flat, class_fns, names,
                                   place_dev, slots, mem_datas, writebacks,
                                   bucket)
         return lane
@@ -1914,6 +1893,28 @@ class PTGTaskpool(Taskpool):
                 "dev_mask": dev_mask2, "ndev_tasks": ndev_tasks,
                 "n_seam": n - n_fused, "n_fused": n_fused}
 
+    def _ptexec_datas(self, keys) -> List[Any]:
+        """The ``Data`` behind each (collection name, static index) of a
+        flattened pool, resolved against THIS instantiation's collections
+        (the cached CSR names memory symbolically)."""
+        datas = []
+        for dc_name, idx in keys:
+            dc = self.collections.get(dc_name)
+            if dc is None:
+                output.fatal(f"PTG taskpool {self.name}: unknown "
+                             f"collection {dc_name!r}")
+            datas.append(dc.data_of(*idx))
+        return datas
+
+    def _ptexec_mem(self, mem_reads, wbs):
+        """A lane pool's memory endpoints: the operands its tasks read,
+        and per writing task its (flow position, ``Data``) pairs."""
+        drefs = self._ptexec_datas((dcn, idx) for _t, _dj, dcn, idx in wbs)
+        writebacks: Dict[int, List] = {}
+        for (tid, dj, _dcn, _idx), dref in zip(wbs, drefs):
+            writebacks.setdefault(tid, []).append((dj, dref))
+        return self._ptexec_datas(mem_reads), writebacks
+
     def _ptexec_class_fns(self, classes: List[TaskClass], data):
         """Per-class (dispatch fn, written flow positions): the jitted
         body for data classes, the raw body for CTL classes, None for
@@ -1945,7 +1946,6 @@ class PTGTaskpool(Taskpool):
         write-backs in serialization order (one version bump per member
         write, like the per-task path). Brackets the body in EV_REGION
         ring events so merged timelines show regions vs seams."""
-        from ...data.data import COHERENCY_OWNED as _OWNED
         ext, out_slots = rp["ext"], rp["out_slots"]
         evr, fs, fe = mod.EV_REGION, mod.FLAG_START, mod.FLAG_END
 
@@ -1966,12 +1966,7 @@ class PTGTaskpool(Taskpool):
                         f"(slot {s}, native lane)")
                 slots[s] = v
             for dref, v in zip(wb_datas, wbs):
-                host = dref.get_copy(0)
-                if host is None:
-                    dref.create_copy(0, v, _OWNED)
-                else:
-                    host.payload = v
-                dref.bump_version(0)
+                dref.write_host(v)
             graph.trace_mark(evr, cid, fe)
         return run_region
 
@@ -1994,20 +1989,8 @@ class PTGTaskpool(Taskpool):
                           plan["slot_uses"])
         graph.region_bind(plan["weights"])
         slots: List[Any] = [None] * data["n_slots"]
-        mem_datas = []
-        for dc_name, idx in data["mem_reads"]:
-            dc = self.collections.get(dc_name)
-            if dc is None:
-                output.fatal(f"PTG taskpool {self.name}: unknown "
-                             f"collection {dc_name!r}")
-            mem_datas.append(dc.data_of(*idx))
-        writebacks: Dict[int, List] = {}
-        for tid, dj, dc_name, idx in plan["writebacks"]:
-            dc = self.collections.get(dc_name)
-            if dc is None:
-                output.fatal(f"PTG taskpool {self.name}: unknown "
-                             f"collection {dc_name!r}")
-            writebacks.setdefault(tid, []).append((dj, dc.data_of(*idx)))
+        mem_datas, writebacks = self._ptexec_mem(data["mem_reads"],
+                                                 plan["writebacks"])
         fns, written_by_class = self._ptexec_class_fns(classes, data)
         cache = self.program.region_programs
         # the flatten key names every primitive global; a region program
@@ -2040,13 +2023,7 @@ class PTGTaskpool(Taskpool):
             jitted, hit = programs[rp["shape"]]
             if not hit:
                 cold_regions.add(ri)
-            wb_datas = []
-            for dcn, idx in rp["wb_keys"]:
-                dc = self.collections.get(dcn)
-                if dc is None:
-                    output.fatal(f"PTG taskpool {self.name}: unknown "
-                                 f"collection {dcn!r}")
-                wb_datas.append(dc.data_of(*idx))
+            wb_datas = self._ptexec_datas(rp["wb_keys"])
             cid = plan["rcid"][ri]
             if rp["kind"] == "dev":
                 dev_regions[cid] = {
@@ -2072,401 +2049,59 @@ class PTGTaskpool(Taskpool):
         PTEXEC_STATS["fused_tasks"] += plan["n_fused"]
         PTEXEC_STATS["seam_tasks"] += plan["n_seam"]
         if devlane is not None and plan["dev_mask"] is not None:
-            self._ptexec_bind_dev_fused(lane, devlane, flat, plan,
-                                        classes, slots, mem_datas,
-                                        writebacks, dev_regions, mod,
-                                        place_dev, bucket)
+            self._ptexec_bind_dev(
+                lane, devlane, flat, (fns, written_by_class), names,
+                place_dev, slots, mem_datas, writebacks, bucket, plan,
+                {"orig_of": plan["orig_of"], "dev_regions": dev_regions,
+                 "marks": (mod.EV_REGION, mod.FLAG_START, mod.FLAG_END)})
         return lane
 
-    def _ptexec_bind_dev_fused(self, lane: Dict[str, Any], devlane, flat,
-                               plan, classes: List[TaskClass],
-                               slots: List[Any], mem_datas,
-                               writebacks: Dict[int, List],
-                               dev_regions: Dict[int, Dict], mod,
-                               place_dev: List[bool],
-                               bucket: int = 0) -> None:
-        """Device binding for a fused pool: same contract as
-        :meth:`_ptexec_bind_dev`, but the mask covers compact nodes and
-        device REGIONS dispatch as one region-sized async program on
-        the lane (ptdev needs nothing new beyond that region-sized
-        dispatch — the retire capsule walks the fused node exactly like
-        any device task, weighted back to original tasks). ``place_dev``
-        is the cost model's EFFECTIVE placement (ISSUE 18), not the
-        static has-a-device-body shape — the fusion plan's dev_mask was
-        built from the same list, and the two must agree."""
-        data = flat["data"]
-        dev_of_class = [place_dev[ci] and data["ndflows"][ci] > 0
-                        for ci in range(len(classes))]
-        graph = lane["graph"]
-        cost_obs = self._ptexec_cost_obs(lane)
-        dispatch, poll, lane["dev_held"] = self._mk_ptexec_dev_dispatch(
-            flat, classes, dev_of_class, slots, mem_datas, writebacks,
-            devlane, fusion={"orig_of": plan["orig_of"],
-                             "dev_regions": dev_regions, "graph": graph,
-                             "evr": mod.EV_REGION, "fls": mod.FLAG_START,
-                             "fle": mod.FLAG_END},
-            cost_obs=cost_obs, bucket=bucket)
-        pid = devlane.bind_pool(graph, dispatch, poll)
-        lane["dev"] = devlane
-        lane["dev_pool"] = pid
-        from ...device.native import PTDEV_STATS
-        PTDEV_STATS["pools_engaged"] += 1
-        PTDEV_STATS["tasks_engaged"] += plan["ndev_tasks"]
-        PTEXEC_STATS["pools_device"] += 1
-        PTEXEC_STATS["tasks_device"] += plan["ndev_tasks"]
-        graph.dev_bind(devlane.submit_capsule(), pid, plan["dev_mask"])
-        devlane.clane.notify()
-
-    def _ptexec_cost_obs(self, lane: Dict[str, Any]):
-        """The device lane's observation dict (ISSUE 18): (class name,
-        bucket, dev) -> [count, sum_ns], written only by the lane's
-        manager thread (dispatch/poll run there — no lock needed) and
-        folded into the cost model at the lane's detach."""
-        from ...core import costmodel as _cm
-        if not _cm.enabled():
-            return None
-        obs = lane.setdefault("cost_dev", {})
-        return obs
-
     def _ptexec_bind_dev(self, lane: Dict[str, Any], devlane, flat,
-                         classes: List[TaskClass], dev_classes: List[bool],
-                         slots: List[Any], mem_datas,
-                         writebacks: Dict[int, List],
-                         bucket: int = 0) -> None:
-        """Bind a flattened data graph to the native device lane (ISSUE
-        10): build the per-pool dispatch/poll closures, register them
-        with the lane (the retire capsule routes completions back into
-        the graph's GIL-free release walk), and hand the graph the submit
-        vtable + per-task device mask — from then on a device-bodied task
-        becoming ready surfaces onto the lane's MPSC pending queue
-        instead of the ready structure."""
+                         class_fns, names: Tuple[str, ...],
+                         place_dev: List[bool], slots: List[Any],
+                         mem_datas, writebacks: Dict[int, List],
+                         bucket: int, plan=None, fusion=None) -> None:
+        """Hand the pool's device tasks to the native device lane
+        (device/lane_pool.py, which owns dispatch, residency and retire):
+        the per-task device mask and its task count are what this side
+        knows. ``place_dev`` is the cost model's EFFECTIVE placement
+        (ISSUE 18), not the static has-a-device-body shape. With a fusion
+        ``plan`` the mask covers compact nodes (the plan built it from
+        the same placement) and ``fusion`` has the device REGIONS, each
+        dispatched as one program. Call it LAST: ready device tasks
+        surface at once."""
         data = flat["data"]
-        # only data-carrying TPU classes ride the device plane; a CTL-only
-        # [type=TPU] class has no arrays to place and runs its raw body
-        # through the ordinary CPU dispatch
-        dev_of_class = [d and nd > 0
-                        for d, nd in zip(dev_classes, data["ndflows"])]
-        if not any(dev_of_class):
-            return
-        dev_mask: List[int] = []
-        for ci, insts in enumerate(flat["params"]):
-            dev_mask.extend([1 if dev_of_class[ci] else 0] * len(insts))
-        ndev = sum(dev_mask)
-        graph = lane["graph"]
-        dispatch, poll, lane["dev_held"] = self._mk_ptexec_dev_dispatch(
-            flat, classes, dev_of_class, slots, mem_datas, writebacks,
-            devlane, cost_obs=self._ptexec_cost_obs(lane), bucket=bucket)
-        pid = devlane.bind_pool(graph, dispatch, poll)
-        lane["dev"] = devlane
-        lane["dev_pool"] = pid
-        from ...device.native import PTDEV_STATS
-        PTDEV_STATS["pools_engaged"] += 1
-        PTDEV_STATS["tasks_engaged"] += ndev
+        if plan is None:
+            # only data-carrying TPU classes ride the device plane; a
+            # CTL-only [type=TPU] class has no arrays to place and runs
+            # its raw body through the ordinary CPU dispatch
+            dev_of_class = [d and nd > 0
+                            for d, nd in zip(place_dev, data["ndflows"])]
+            if not any(dev_of_class):
+                return
+            dev_mask: List[int] = []
+            for ci, insts in enumerate(flat["params"]):
+                dev_mask.extend([1 if dev_of_class[ci] else 0] * len(insts))
+            ndev = sum(dev_mask)
+        else:
+            dev_mask, ndev = plan["dev_mask"], plan["ndev_tasks"]
+        from ...core import costmodel as _cm
+        from ...device import lane_pool
         PTEXEC_STATS["pools_device"] += 1
         PTEXEC_STATS["tasks_device"] += ndev
-        graph.dev_bind(devlane.submit_capsule(), pid, dev_mask)
-        devlane.clane.notify()
-
-    def _mk_ptexec_dev_dispatch(self, flat, classes: List[TaskClass],
-                                dev_of_class: List[bool], slots: List[Any],
-                                mem_datas, writebacks: Dict[int, List],
-                                devlane, fusion=None, cost_obs=None,
-                                bucket=0):
-        """The device lane's per-pool dispatch/poll pair, both run on the
-        lane's manager thread with the GIL held:
-
-        * ``dispatch(ids)`` — the push+exec phases of the reference's
-          stream pipeline (device_gpu.c:3438), collapsed onto XLA's async
-          runtime: FIRST every memory-endpoint input of the whole batch
-          stages in (version-checked through the C coherency table;
-          ``device_put`` is asynchronous, so these H2D transfers overlap
-          whatever compute is already in flight — the early-push overlap
-          the interpreted path never had), THEN each task's jitted body
-          dispatches (async) and its future outputs land in the lane's
-          slot array immediately — safe because no consumer can run
-          before this task RETIRES, which only happens after its
-          completion events fire;
-        * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
-          inflight task's outputs (cudaEventQuery, device_gpu.c:2593).
-          Completed tasks perform their memory write-backs + version
-          bumps, give up their reads, and return their ids — the C
-          side then calls the graph's GIL-free ``dev_retire``.
-
-        Residency is touched once per distinct memory operand of a BATCH,
-        not per operand of every program: the push phase's stage-in takes
-        the operand's one pin (table + ``readers``), ``held`` counts the
-        programs in flight that read it, and the pin is given back when
-        the last of them retires (or at the end of ``dispatch``, where
-        no program of the batch reads it). Returns ``(dispatch, poll,
-        held)``; ``held`` is empty whenever nothing is in flight.
-        """
-        from ...data.data import COHERENCY_OWNED as _OWNED
-        dev = devlane.device
-        bases = flat["bases"]
-        params_by_class = flat["params"]
-        data = flat["data"]
-        slot_base = data["slot_base"]
-        in_refs = data["in_refs"]
-        ndflows = data["ndflows"]
-        cls_of = data["cls_of"]
-        fns, written_by_class = [], []
-        for ci, tc in enumerate(classes):
-            empty = tc._ptg_spec.bodies[0].source.strip() in ("", "pass")
-            fns.append(None if empty or not dev_of_class[ci]
-                       else tc._ptg_body_fn)
-            written_by_class.append(tuple(
-                dj for dj, fi in enumerate(data["dflow_idx"][ci])
-                if tc.flows[fi].access & FLOW_ACCESS_WRITE))
-        import collections as _collections
-        inflight: "_collections.deque" = _collections.deque()
-        # device-side cost observation (ISSUE 18): each inflight entry is
-        # stamped at dispatch and observed at retire — the elapsed window
-        # covers the async compute, the output-ready wait, AND the lane's
-        # poll cadence, i.e. the throughput a task actually experiences
-        # on this path (what placement must compare against the CPU
-        # lane's batch-amortized cost). Stage-ins time separately into
-        # the __stage_in__ pseudo-class. All writes happen on the
-        # manager thread; the fold reads after unbind.
-        _pc = time.perf_counter_ns
-        dev_clock = [0]      # batch-amortization mark (see poll)
-        if cost_obs is not None:
-            from ...core.costmodel import STAGE_IN as _STG, shape_bucket
-            cnames = [f"{self.program.spec.name}.{tc._ptg_spec.name}"
-                      for tc in classes]
-
-            def _obs(key, w, ns):
-                e = cost_obs.get(key)
-                if e is None:
-                    cost_obs[key] = [w, ns]
-                else:
-                    e[0] += w
-                    e[1] += ns
-
-            def _stage(mi):
-                t0 = _pc()
-                copy = dev.lane_stage_in(mem_datas[mi], pin=True)
-                nb = getattr(getattr(copy, "payload", None), "nbytes", 0)
-                _obs((_STG, shape_bucket(nb), "tpu"), 1, _pc() - t0)
-                return copy
-        else:
-            _obs = None
-
-            def _stage(mi):
-                return dev.lane_stage_in(mem_datas[mi], pin=True)
-        sp = self.ctx._spans
-        if sp is not None:
-            pinned = [0]     # table pins taken so far, for ptdev.pins
-
-            # ptdev.stage_in: the push phase's misses only (a hit moves
-            # no bytes); on the timeline a miss is the dev.stage_in
-            # annotation of TPUDevice._stage_in_copy, inside ptdev.dispatch
-            def _stage(mi, _inner=_stage):
-                moved, t0 = dev.transfer_in_bytes, _pc()
-                copy = _inner(mi)
-                pinned[0] += 1      # every pin of the closure is a stage-in's
-                if dev.transfer_in_bytes != moved:
-                    sp.pt_stage_in.record(_pc() - t0)
-                return copy
-        # mi -> [device copy, programs in flight that read it, pins held]:
-        # owned by the manager thread (dispatch and poll both run there
-        # with the GIL, as _obs relies on), so no lock and no table call
-        # per (program, operand). An operand staged again by a later batch
-        # while an earlier reader still flies joins the same entry; its
-        # pins nest in the table as they always did.
-        held: Dict[int, List[Any]] = {}
-
-        def _hold(mi, staged):
-            # pin=True: the eviction pin is taken inside the table's
-            # reserve critical section, so no peer thread's stage-in can
-            # evict this entry first, and staging tile k+1 of this very
-            # batch cannot evict tile k before the exec phase reads it
-            # (found by the verify drive: "dot got NoneType")
-            copy = _stage(mi)
-            h = held.get(mi)
-            if h is None:
-                h = held[mi] = [copy, 0, 0]
-            h[2] += 1
-            staged[mi] = h
-
-        def _release(mi, h):
-            del held[mi]
-            for _ in range(h[2]):
-                dev.unpin_copy(h[0])
-        if fusion is not None:
-            # fused pool (ISSUE 12): a device REGION dispatches as one
-            # region-sized async program; its inflight/retire id is the
-            # COMPACT node id (what the C release walk expects), while
-            # slot/param arrays index by original id via orig_of
-            _forig = fusion["orig_of"]
-            _dregs = fusion["dev_regions"]
-            _graph = fusion["graph"]
-            _evr, _fs, _fe = fusion["evr"], fusion["fls"], fusion["fle"]
-        else:
-            _forig = _dregs = _graph = None
-
-        def dispatch(ids):
-            # PUSH phase: issue every memory-endpoint stage-in for the
-            # whole batch before any compute dispatch, each distinct
-            # operand once, pinned THE MOMENT it stages (_hold)
-            staged: Dict[int, List[Any]] = {}
-            if _obs is not None and not inflight:
-                # idle -> active: restart the amortization clock so idle
-                # gaps between batches never land in any task's cost
-                dev_clock[0] = _pc()
-            for i in ids:
-                if _dregs is not None:
-                    r = _dregs.get(i)
-                    if r is not None:
-                        for mi in r["ext_mems"]:
-                            if mi not in staged:
-                                _hold(mi, staged)
-                        continue
-                    i = _forig[i]
-                base = slot_base[i]
-                for dj in range(ndflows[cls_of[i]]):
-                    r = in_refs[base + dj]
-                    if r < -1 and (-2 - r) not in staged:
-                        _hold(-2 - r, staged)
-            # EXEC phase: dispatch each ready device task asynchronously
-            for i in ids:
-                oi = i
-                if _dregs is not None:
-                    r = _dregs.get(i)
-                    if r is not None:
-                        # region-sized dispatch: ONE jitted program for
-                        # the whole fused region, async like any task;
-                        # the retire id stays the compact node id
-                        ev: List[Any] = []
-                        for kk, v in r["ext"]:
-                            if kk == "slot":
-                                ev.append(slots[v])
-                            else:
-                                h = staged[v]
-                                h[1] += 1       # one more reader in flight
-                                ev.append(h[0].payload)
-                        _graph.trace_mark(_evr, i, _fs)
-                        outs, wbs_v = r["jitted"](tuple(ev))
-                        _graph.trace_mark(_evr, i, _fe)
-                        for s, v in zip(r["out_slots"], outs):
-                            slots[s] = v
-                        events = tuple(v for v in tuple(outs) + tuple(wbs_v)
-                                       if hasattr(v, "is_ready"))
-                        inflight.append((
-                            i, events, r["wb_pairs"], list(wbs_v),
-                            r["ext_mems"], r["ntasks"],
-                            None if (_obs is None or r.get("cold")) else
-                            (cnames[r["cls"]], bucket, "tpu_fused")))
-                        continue
-                    oi = _forig[i]
-                k = cls_of[oi]
-                base = slot_base[oi]
-                nd = ndflows[k]
-                vals: List[Any] = []
-                reads: List[int] = []
-                for dj in range(nd):
-                    r = in_refs[base + dj]
-                    if r >= 0:
-                        vals.append(slots[r])
-                    elif r == -1:
-                        vals.append(None)
-                    else:
-                        h = staged[-2 - r]
-                        h[1] += 1               # one more reader in flight
-                        reads.append(-2 - r)
-                        vals.append(h[0].payload)
-                fn = fns[k]
-                events = ()
-                if fn is not None:
-                    outs = fn(*params_by_class[k][oi - bases[k]], *vals)
-                    for oj, dj in enumerate(written_by_class[k]):
-                        vals[dj] = outs[oj]
-                    events = tuple(v for v in outs
-                                   if hasattr(v, "is_ready"))
-                for dj in range(nd):
-                    slots[base + dj] = vals[dj]
-                inflight.append((i, events, writebacks.get(oi), vals, reads,
-                                 1,
-                                 None if _obs is None else
-                                 (cnames[k], bucket, "tpu")))
-            for mi, h in staged.items():
-                if not h[1]:            # staged, and no program reads it
-                    _release(mi, h)
-            return len(ids)
-
-        def poll():
-            done: List[int] = []
-            retired: List[Tuple] = []
-            for _ in range(len(inflight)):
-                ent = inflight.popleft()
-                i, events, wbs, vals, reads, w, ckey2 = ent
-                if events and not all(a.is_ready() for a in events):
-                    inflight.append(ent)
-                    continue
-                if sp is not None:
-                    tok = sp.begin(PTDEV_RETIRE)
-                if wbs:
-                    for dj, dref in wbs:
-                        v = vals[dj]
-                        host = dref.get_copy(0)
-                        if host is None:
-                            dref.create_copy(0, v, _OWNED)
-                        else:
-                            host.payload = v
-                        dref.bump_version(0)
-                for mi in reads:
-                    h = held[mi]
-                    h[1] -= 1
-                    if not h[1]:        # its last reader in flight retired
-                        _release(mi, h)
-                dev.executed_tasks += w
-                retired.append((ckey2, w))
-                done.append(i)
-                if sp is not None:
-                    retired_ns[0] += sp.end(tok, sp.pt_retire)
-            if retired and _obs is not None:
-                # batch amortization, the SAME semantics as the C lane's
-                # exec bump: the wall window since the last retire sweep
-                # (or the idle->active mark) divides across every task
-                # weight retired in it. Per-entry dispatch->retire spans
-                # overlap under pipelining, so summing them would bill
-                # the same wall clock N-inflight times over and make the
-                # device look slower than the wall it actually consumed
-                # — placement would then mis-compare against the CPU
-                # lane's throughput-denominated cost. Keyless entries
-                # (cold regions) still weigh in the denominator: they
-                # consumed part of the window.
-                now = _pc()
-                total_w = sum(w for _, w in retired)
-                per = (now - dev_clock[0]) / max(total_w, 1)
-                for ckey2, w in retired:
-                    if ckey2 is not None:
-                        _obs(ckey2, w, per * w)
-                dev_clock[0] = now
-            return done
-
-        if sp is None:
-            return dispatch, poll, held
-        retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
-
-        def traced_dispatch(ids):
-            # one span a callback, recorded once per device program; the
-            # table pins the callback took, one record
-            tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
-            try:
-                return dispatch(ids)
-            finally:
-                sp.end(tok, sp.pt_dispatch, n=len(ids))
-                sp.pt_pins.record(pinned[0] - before)
-
-        def traced_poll():
-            # one record a pass, the retirements' own spans subtracted
-            tok, before = sp.begin(PTDEV_POLL), retired_ns[0]
-            try:
-                return poll()
-            finally:
-                sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
-
-        return traced_dispatch, traced_poll, held
+        lane["dev"] = devlane
+        lane["dev_pool"], lane["dev_held"] = lane_pool.bind(
+            devlane, lane["graph"], bases=flat["bases"],
+            params=flat["params"], slot_base=data["slot_base"],
+            in_refs=data["in_refs"], ndflows=data["ndflows"],
+            cls_of=data["cls_of"], fns=class_fns[0], written=class_fns[1],
+            names=names, slots=slots, mem_datas=mem_datas,
+            writebacks=writebacks, dev_mask=dev_mask, ndev_tasks=ndev,
+            fusion=fusion, bucket=bucket,
+            # the lane's observations, folded into the cost model at
+            # detach (Context._cost_fold)
+            cost_obs=lane.setdefault("cost_dev", {}) if _cm.enabled()
+            else None)
 
     def _ptexec_owners(self, classes: List[TaskClass],
                        flat) -> Optional[List[int]]:
@@ -2600,7 +2235,6 @@ class PTGTaskpool(Taskpool):
         their activations, so the per-link FIFO makes eager data
         race-free by construction.
         """
-        from ...data.data import COHERENCY_OWNED as _OWNED
         bases = flat["bases"]
         params_by_class = flat["params"]
         data = flat["data"]
@@ -2750,13 +2384,7 @@ class PTGTaskpool(Taskpool):
                     if wbs is None:
                         continue
                 for dj, dref in wbs:
-                    v = vals[dj]
-                    host = dref.get_copy(0)
-                    if host is None:
-                        dref.create_copy(0, v, _OWNED)
-                    else:
-                        host.payload = v
-                    dref.bump_version(0)
+                    dref.write_host(vals[dj])
         return run_batch
 
     def _ptexec_finalize(self, lane: Dict[str, Any]) -> None:

@@ -16,6 +16,12 @@ if "xla_force_host_platform_device_count" not in _flags:
 import pytest  # noqa: E402
 
 
+def pytest_configure(config):
+    config.addinivalue_line(
+        "markers", "slow: wall-clock legs that need a host of their own; "
+        "tier-1 runs with -m 'not slow'")
+
+
 @pytest.fixture(autouse=True)
 def _fresh_cost_model():
     """The online cost model (ISSUE 18) is process-global by design —

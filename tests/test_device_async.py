@@ -707,7 +707,8 @@ class _TableSpy:
 def _spy_closures(tp, monkeypatch, on_dispatch=None, on_poll=None):
     """Wrap the pool's dispatch/poll closures as the lane receives them;
     returns the box that will hold the closure's ``held`` dict."""
-    make = tp._mk_ptexec_dev_dispatch
+    from parsec_tpu.device import lane_pool
+    make = lane_pool._closures
     box = {}
 
     def spied(*args, **kw):
@@ -726,7 +727,7 @@ def _spy_closures(tp, monkeypatch, on_dispatch=None, on_poll=None):
                 on_poll(done, held)
             return done
         return spy_dispatch, spy_poll, held
-    monkeypatch.setattr(tp, "_mk_ptexec_dev_dispatch", spied)
+    monkeypatch.setattr(lane_pool, "_closures", spied)
     return box
 
 
@@ -1026,3 +1027,61 @@ def test_pins_per_program_reader(monkeypatch, snapshot, want):
                      "better": "lower", "source": "program_counter",
                      "layer": "device issue", "moves": "tasks_per_s",
                      "workloads": ["ptg_gemm.ts512"]}
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: the lane binds a pool from plain data (device/lane_pool.py)
+# ---------------------------------------------------------------------------
+
+def test_lane_pool_binds_plain_data(dctx):
+    """A pool made of lists and one jitted callable, on a bare ``ptexec``
+    ``Graph``: no ``compile_ptg``, no ``TaskClass``. A chain of three tasks
+    ``x <- x + k * m``, the first ``x`` and every ``m`` read from memory,
+    the last ``x`` written back."""
+    import time as _t
+    import jax
+    from parsec_tpu import native as native_mod
+    from parsec_tpu.data.data import data_from_array
+    from parsec_tpu.device import lane_pool
+    from parsec_tpu.device.native import PTDEV_STATS
+    devlane = _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    m = data_from_array(np.full((8, 8), 2.0, np.float32))
+    x0 = data_from_array(np.ones((8, 8), np.float32))
+    out = data_from_array(np.zeros((8, 8), np.float32))
+    # task i owns slots 2i (x, written) and 2i + 1 (m, read); memory
+    # operand mi is the in_ref -2 - mi
+    graph = native_mod.load_ptexec().Graph(
+        [0, 1, 1], [0, 1, 2, 2], [1, 2], None,       # goals, off, succs, prio
+        [0, 0, 1, 2], [0, 2], [1, 0, 1, 0, 0, 0])    # in_off, in_slots, uses
+    slots = [None] * 6
+
+    def cpu_batch(ids, retired):        # every task is the device's
+        assert not list(ids)
+        for j in retired:
+            slots[j] = None
+    before = PTDEV_STATS.snapshot()
+    pid, held = lane_pool.bind(
+        devlane, graph, bases=[0], params=[[(1,), (2,), (3,)]],
+        slot_base=[0, 2, 4], in_refs=[-3, -2, 0, -2, 2, -2], ndflows=[2],
+        cls_of=[0, 0, 0], fns=[jax.jit(lambda k, x, m: (x + k * m,))],
+        written=[(0,)], names=["hand.acc"], slots=slots, mem_datas=[m, x0],
+        writebacks={2: [(0, out)]}, dev_mask=[1, 1, 1], ndev_tasks=3)
+    try:
+        deadline = _t.monotonic() + 30
+        while not graph.done() and _t.monotonic() < deadline:
+            assert devlane.failed() is None
+            graph.run(cpu_batch, 256, 4096, 0)
+            _t.sleep(1e-3)
+        assert graph.done() and devlane.failed() is None
+    finally:
+        devlane.unbind_pool(pid)
+    np.testing.assert_array_equal(np.asarray(out.get_copy(0).payload),
+                                  np.full((8, 8), 13.0, np.float32))
+    assert out.version == 1 and held == {}
+    for d in (m, x0):
+        st = dev._ncoh.state(dev.res_key(d))
+        assert st is not None and st[3] == 0, st
+        assert all(c.readers == 0 for c in d.copies.values())
+    delta = PTDEV_STATS.delta(before)
+    assert (delta["pools_engaged"], delta["tasks_engaged"]) == (1, 3)

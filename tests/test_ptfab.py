@@ -351,53 +351,77 @@ def test_gateway_routes_by_advertised_headroom():
 
 # ----------------------------------------------------------- 2-OS-rank legs
 
-def test_two_rank_antagonist_isolation_and_shares():
-    """The acceptance scenario with real processes: the antagonist
-    floods both ranks through the gateway; the victim's p99 stays
-    within 2x of its unloaded p99; remote backpressure engaged with
-    zero hot-path round trips (spends local, verified by wire
-    counters); and the reconciled cross-rank shares converge within
-    25% of the global 2:1 weights.
-
-    The p99 leg is LOAD-SENSITIVE on a 2-core host (a p99 over ~200
-    samples is near max-of-samples, and OS scheduling noise can hit the
-    two phases asymmetrically), so it follows the bounded-retry
-    discipline of the deflake satellites: a systematic isolation
-    failure violates the bound on EVERY attempt; a host-load flap does
-    not survive three."""
+def _fabric_2rank(**sizes):
+    """One run of the acceptance program on two OS ranks."""
     from parsec_tpu.serving.harness import fabric_2rank_program
+    res = run_distributed_procs(
+        2, functools.partial(fabric_2rank_program, **sizes), timeout=300)
+    for r in res:
+        if not r.get("fabric"):
+            pytest.skip(f"serving fabric unavailable: {r.get('reason')}")
+    return res
+
+
+def _assert_fabric_engaged(res):
+    """What holds on EVERY run (engagement, not timing); returns the
+    tenants' served counts over the shares window."""
+    # the antagonist actually flooded and actually hit the wall
+    assert sum(r["antagonist_rejects"] for r in res) > 0
+    assert sum(r["antagonist_served"] for r in res) > 0
+    # zero hot-path round trips: spends dwarf credit frames
+    for r in res:
+        w = r["wire"]
+        assert w["creds_spent"] > 0
+        assert w["cred_frames_rx"] < \
+            w["creds_spent"] + w["creds_granted_rx"]
+        assert w["frame_errors"] == 0
+    assert sum(r["wire"]["creds_granted_tx"] for r in res) > 0
+    sv = sum(r["shares_window"]["sv"] for r in res)
+    sa = sum(r["shares_window"]["sa"] for r in res)
+    assert sv > 0 and sa > 0
+    assert res[0]["reconcile_rounds"] > 0
+    for r in res:
+        assert r["weight_adjusts"] > 0   # nudges landed on BOTH ranks
+    return sv, sa
+
+
+def test_two_rank_antagonist_isolation_and_shares():
+    """The acceptance scenario with real processes, its counter
+    invariants: the antagonist floods both ranks through the gateway and
+    is both served and rejected; remote backpressure engaged with zero
+    hot-path round trips (spends local, verified by wire counters); the
+    reconciler ran and nudged the weights on both ranks.
+
+    Bodies are cut to ONE BLAS pass and the phases shortened: nothing
+    here reads a clock, and at the program's own sizes a run takes
+    minutes on a shared host (threaded OpenBLAS, 5 ms a pass where the
+    tuning contract counts 20 us). What is wall-clock is the ``slow``
+    test below."""
+    _assert_fabric_engaged(_fabric_2rank(
+        isolation_s=0.6, loaded_s=1.0, shares_s=2.0,
+        work_victim=500_000, work_shares=500_000))
+
+
+@pytest.mark.slow
+def test_two_rank_antagonist_p99_and_share_ratio():
+    """The wall-clock half, at the program's own sizes: the victim's
+    p99 stays within 2x of its unloaded p99, and the reconciled
+    cross-rank shares converge within 25% of the global 2:1 weights.
+
+    Both need a host whose cores the ranks own (a p99 over ~200 samples
+    is near max-of-samples, and a starved reconciler makes no round).
+    The p99 leg follows the bounded-retry discipline of the deflake
+    satellites: a systematic isolation failure violates the bound on
+    EVERY attempt; a host-load flap does not survive three."""
     attempts = []
     for attempt in range(3):
-        res = run_distributed_procs(
-            2, functools.partial(fabric_2rank_program), timeout=300)
-        for r in res:
-            if not r.get("fabric"):
-                pytest.skip(
-                    f"serving fabric unavailable: {r.get('reason')}")
-        # --- these hold on EVERY attempt (engagement, not timing) -----
-        # the antagonist actually flooded and actually hit the wall
-        assert sum(r["antagonist_rejects"] for r in res) > 0
-        assert sum(r["antagonist_served"] for r in res) > 0
-        # zero hot-path round trips: spends dwarf credit frames
-        for r in res:
-            w = r["wire"]
-            assert w["creds_spent"] > 0
-            assert w["cred_frames_rx"] < \
-                w["creds_spent"] + w["creds_granted_rx"]
-            assert w["frame_errors"] == 0
-        assert sum(r["wire"]["creds_granted_tx"] for r in res) > 0
+        res = _fabric_2rank()
+        sv, sa = _assert_fabric_engaged(res)
         # cross-rank share convergence (measured over the second half)
-        sv = sum(r["shares_window"]["sv"] for r in res)
-        sa = sum(r["shares_window"]["sa"] for r in res)
-        assert sv > 0 and sa > 0
         ratio = sv / sa
         assert abs(ratio - 2.0) / 2.0 < 0.25, \
             f"cross-rank shares {sv}:{sa} (ratio {ratio:.2f}) vs " \
             f"weights 2:1"
-        assert res[0]["reconcile_rounds"] > 0
-        for r in res:
-            assert r["weight_adjusts"] > 0   # nudges landed on BOTH ranks
-        # --- the load-sensitive p99 bound (bounded retry) -------------
         base = [x for r in res for x in r["victim_lats_base_ns"]]
         load = [x for r in res for x in r["victim_lats_load_ns"]]
         assert len(base) > 40 and len(load) > 40, (len(base), len(load))
