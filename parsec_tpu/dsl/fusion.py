@@ -12,6 +12,11 @@ DSLs share:
   program replaying the region in a valid serialization order; the
   scheduler handles only the un-fusable seams.
 
+* :func:`pack_source_regions` — the second pass, for PTG pools: device
+  regions with no edge to the outside that share memory operands become
+  one region, so their program takes each shared operand once (its own
+  soundness argument is in its docstring).
+
 * :class:`ExecCache` — the persistent compiled-program cache shared
   across pool instantiations, with hit/miss/evict counters exported
   through the unified registry (``capture.cache_{hits,misses,
@@ -328,3 +333,99 @@ def partition_regions(n: int, off: Sequence[int], succs: Sequence[int],
     # cached plan): sort by first member's topo position
     regions.sort(key=lambda m: topo_ix[m[0]])
     return regions
+
+
+def pack_source_regions(sizes: Sequence[int],
+                        kinds: Sequence[Optional[Hashable]],
+                        ext_in: Sequence[int], ext_out: Sequence[int],
+                        reads: Sequence[Sequence[Hashable]],
+                        writes: Sequence[Sequence[Hashable]],
+                        max_size: int = 128) -> List[List[int]]:
+    """Pack sibling device regions that share memory operands (ISSUE 32):
+    a region program then takes each shared operand once, where its
+    members' programs each took it again (four k-chains of one row of a
+    tiled GEMM: 164 operands in one call, not 4 x 65 in four).
+
+    Region ``r`` of :func:`partition_regions` has ``sizes[r]`` tasks of
+    kind ``kinds[r]``, ``ext_in[r]`` / ``ext_out[r]`` graph edges from /
+    to tasks outside it, and reads / writes the memory locations
+    ``reads[r]`` / ``writes[r]`` (any hashable). Returns the packs as
+    lists of region indices, every region in exactly one, ordered by
+    first region; the caller chains the members' lists in that order
+    (each is in topological order, and independent regions serialize in
+    any order).
+
+    *Candidates* are the ``'dev'`` regions with no external edge at all:
+    no producer outside, so they are ready the moment the pool is bound
+    and surface to the device lane together, and no consumer outside, so
+    nobody waits longer because a sibling was packed in (their results
+    leave by write-back). Every other region is a pack of its own. That
+    leaves out regions fed by other tasks (the updates of a
+    factorization), however much they share: packing those needs a rule
+    for what may wait for what, and waits for the cell that can show it
+    (ROADMAP M5).
+
+    Two candidates share a pack only if neither writes a location the
+    other reads or writes: the pack's trace-time memory env would
+    otherwise give an order to what the graph left unordered. A pack
+    holds at most ``max_size`` tasks, the fusion pass's own hard bound on
+    XLA program size.
+
+    The choice is greedy and deterministic in region order (the plan is
+    cached and instantiations must agree): a pack starts at the first
+    unpacked candidate and takes, while the bound allows, the candidate
+    that shares the most read locations with the pack so far, the lowest
+    index on a tie; one that shares none is never taken.
+
+    Soundness: a candidate has no external in-edge, so no path enters
+    one, so a union of candidates has none either and cannot lie on a
+    cycle of the condensed graph.
+    """
+    nr = len(sizes)
+    cand = [kinds[r] == "dev" and not ext_in[r] and not ext_out[r]
+            for r in range(nr)]
+    readers: Dict[Hashable, List[int]] = {}
+    for r in range(nr):
+        if cand[r]:
+            for k in dict.fromkeys(reads[r]):
+                readers.setdefault(k, []).append(r)
+    smallest = min((sizes[r] for r in range(nr) if cand[r]), default=0)
+    packed = [False] * nr
+    packs: List[List[int]] = []
+    for first in range(nr):
+        if packed[first]:
+            continue
+        pack, total = [first], sizes[first]
+        packed[first] = True
+        packs.append(pack)
+        if not cand[first]:
+            continue
+        p_reads: set = set()
+        p_writes: set = set()
+        share: Dict[int, int] = {}   # unpacked candidate -> shared reads
+        refused: set = set()         # too large for, or ordered against, it
+        r: Optional[int] = first
+        while r is not None and total + smallest <= max_size:
+            p_writes.update(writes[r])
+            for k in reads[r]:
+                if k not in p_reads:
+                    p_reads.add(k)
+                    for c in readers[k]:
+                        if not packed[c] and c not in refused:
+                            share[c] = share.get(c, 0) + 1
+            r = None
+            while share and r is None:
+                c = max(share, key=lambda c: (share[c], -c))
+                del share[c]
+                if total + sizes[c] > max_size \
+                        or any(k in p_reads or k in p_writes
+                               for k in writes[c]) \
+                        or any(k in p_writes for k in reads[c]):
+                    refused.add(c)
+                else:
+                    r = c
+            if r is not None:
+                pack.append(r)
+                packed[r] = True
+                total += sizes[r]
+    return packs

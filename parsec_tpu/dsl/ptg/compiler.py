@@ -74,7 +74,8 @@ mca.register("ptg_native_exec", True,
 #: ``ptexec.*`` for live_view and the SDE-style snapshot
 from ...utils.counters import LaneStats as _LaneStats
 from ..fusion import (
-    ExecCache, adaptive_fusion_limits, device_fingerprint, partition_regions,
+    ExecCache, adaptive_fusion_limits, device_fingerprint,
+    pack_source_regions, partition_regions,
 )
 
 PTEXEC_STATS = _LaneStats(pools_engaged=0, tasks_engaged=0,
@@ -87,7 +88,10 @@ PTEXEC_STATS = _LaneStats(pools_engaged=0, tasks_engaged=0,
                           # region executables BUILT (ISSUE 29): one per
                           # distinct shape of region a program's pools
                           # have shown, not one per region
-                          region_programs=0)
+                          region_programs=0,
+                          # regions of the partition that share a program
+                          # with a sibling (ISSUE 32, pack_source_regions)
+                          packed_regions=0)
 
 _ACCESS_MAP = {
     P.FLOW_READ: FLOW_ACCESS_READ,
@@ -1700,6 +1704,7 @@ class PTGTaskpool(Taskpool):
         in_off, in_slots = data["in_off"], data["in_slots"]
         slot_base, in_refs = data["slot_base"], data["in_refs"]
         mem_reads = data["mem_reads"]
+        regions, n_packed = self._ptexec_pack(regions, kind, flat, fus_max)
         reg_of = [-1] * n
         for ri, members in enumerate(regions):
             for m in members:
@@ -1891,7 +1896,55 @@ class PTGTaskpool(Taskpool):
                 "writebacks": [w for w in data["writebacks"]
                                if reg_of[w[0]] < 0],
                 "dev_mask": dev_mask2, "ndev_tasks": ndev_tasks,
-                "n_seam": n - n_fused, "n_fused": n_fused}
+                "n_seam": n - n_fused, "n_fused": n_fused,
+                "n_packed": n_packed}
+
+    @staticmethod
+    def _ptexec_pack(regions: List[List[int]], kind, flat,
+                     max_size: int) -> Tuple[List[List[int]], int]:
+        """Pack sibling device regions that share memory operands into
+        one region (ISSUE 32): gather what only this side knows of each
+        region of the partition (its edges to the outside, the memory it
+        reads and writes) as plain lists, let
+        :func:`~parsec_tpu.dsl.fusion.pack_source_regions` choose, and
+        chain the members of each pack. Returns the regions and how many
+        of the partition's went into a pack of two or more."""
+        data = flat["data"]
+        off, succs = flat["off"], flat["succs"]
+        slot_base, in_refs = data["slot_base"], data["in_refs"]
+        ndflows, cls_of = data["ndflows"], data["cls_of"]
+        mem_reads = data["mem_reads"]
+        reg_of = [-1] * flat["n"]
+        for ri, members in enumerate(regions):
+            for m in members:
+                reg_of[m] = ri
+        ext_in, ext_out = [0] * len(regions), [0] * len(regions)
+        for i, ri in enumerate(reg_of):
+            for k in range(off[i], off[i + 1]):
+                rt = reg_of[succs[k]]
+                if rt != ri or ri < 0:
+                    if ri >= 0:
+                        ext_out[ri] += 1
+                    if rt >= 0:
+                        ext_in[rt] += 1
+        reads: List[List[Tuple]] = [[] for _ in regions]
+        writes: List[List[Tuple]] = [[] for _ in regions]
+        for ri, members in enumerate(regions):
+            for m in members:
+                b = slot_base[m]
+                reads[ri].extend(mem_reads[-2 - in_refs[b + dj]]
+                                 for dj in range(ndflows[cls_of[m]])
+                                 if in_refs[b + dj] < -1)
+        for tid, _dj, dcn, idx in data["writebacks"]:
+            if reg_of[tid] >= 0:
+                writes[reg_of[tid]].append((dcn, idx))
+        packs = pack_source_regions(
+            [len(m) for m in regions], [kind[m[0]] for m in regions],
+            ext_in, ext_out, reads, writes, max_size)
+        n_packed = sum(len(p) for p in packs if len(p) > 1)
+        if n_packed:
+            regions = [[m for ri in p for m in regions[ri]] for p in packs]
+        return regions, n_packed
 
     def _ptexec_datas(self, keys) -> List[Any]:
         """The ``Data`` behind each (collection name, static index) of a
@@ -2046,6 +2099,7 @@ class PTGTaskpool(Taskpool):
             fusion={"orig_of": plan["orig_of"], "regions": runners},
             class_fns=(fns, written_by_class))
         PTEXEC_STATS["fused_regions"] += len(plan["regions"])
+        PTEXEC_STATS["packed_regions"] += plan["n_packed"]
         PTEXEC_STATS["fused_tasks"] += plan["n_fused"]
         PTEXEC_STATS["seam_tasks"] += plan["n_seam"]
         if devlane is not None and plan["dev_mask"] is not None:

@@ -742,28 +742,37 @@ def _assert_unpinned(dev, mats):
                     assert c.readers == 0, (M.name, m, n)
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "per-task"])
-def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, fused):
+@pytest.mark.parametrize("bound", [128, 16, 0],
+                         ids=["one-pack", "packs-of-4", "per-task"])
+def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
     """The lane touches residency once per distinct memory operand of a
     batch: the stage-in's pin is the operand's only pin (no table pin per
     program and operand), every pin is given back, and nothing stays
-    counted or pinned after the pool."""
+    counted or pinned after the pool. A region program is a pack of
+    k-chains (ISSUE 32: all 16, or the 4 of a row under a bound of 16
+    tasks), and an operand that several chains of one program read counts
+    as one reader of that program."""
     from parsec_tpu.dsl.ptg.compiler import compile_ptg
     _need_lane(dctx)
     dev = _tpu_dev(dctx)
-    a, b, mats = _gemm_operands("po" + "fu"[fused], 30)
-    log = []
+    a, b, mats = _gemm_operands(f"po{bound}", 30)
+    log, readers = [], {}
     monkeypatch.setattr(dev, "_ncoh", _TableSpy(dev._ncoh, log))
-    mca.set("region_fusion", fused)
+
+    def on_dispatch(ids, held):
+        log.append(("batch", len(ids)))
+        for mi, h in held.items():
+            readers[mi] = max(readers.get(mi, 0), h[1])
+    mca.set("region_fusion", bool(bound))
+    mca.set("region_fusion_max", bound or 128)
     try:
-        tp = _gemm_pool(dctx, compile_ptg(_GEMM_SRC, f"po-gemm-{fused}"), mats)
-        box = _spy_closures(
-            tp, monkeypatch,
-            on_dispatch=lambda ids, held: log.append(("batch", len(ids))))
+        tp = _gemm_pool(dctx, compile_ptg(_GEMM_SRC, f"po-gemm-{bound}"), mats)
+        box = _spy_closures(tp, monkeypatch, on_dispatch=on_dispatch)
         dctx.add_taskpool(tp)
         dctx.wait(timeout=90)
     finally:
         mca.params.unset("region_fusion")
+        mca.params.unset("region_fusion_max")
     assert dctx._ptdev.failed() is None
     assert np.array_equal(mats[2].to_dense(), a @ b)
     assert box["held"] == {}
@@ -776,17 +785,26 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, fused):
         elif what == "batch":
             batches.append((v, staged))
             staged = []
-    assert not staged and sum(n for n, _ in batches) == \
-        (_NT * _NT if fused else _NT ** 3)
+    programs = {128: 1, 16: _NT, 0: _NT ** 3}[bound]
+    assert not staged and sum(n for n, _ in batches) == programs
+    # chains a program, and the tiles a program reads: a row of A for the
+    # chains of a row, a column of B and a C tile for each
+    chains = _NT * _NT // programs if bound else 0
+    tiles = {128: 3 * _NT * _NT, 16: _NT + chains * (_NT + 1), 0: 3}[bound]
     for n, keys in batches:
         assert len(keys) == len(set(keys))
-        # a fused k-chain reads 2 * KT + 1 tiles, a task at most 3
-        assert len(keys) <= min(n * (2 * _NT + 1 if fused else 3),
-                                3 * _NT * _NT)
+        assert len(keys) <= min(n * tiles, 3 * _NT * _NT)
     pins = sum(len(keys) for _, keys in batches)
     assert pins >= 3 * _NT * _NT
     assert not [e for e in log if e[0] == "pin"], "a pin per program operand"
     assert sum(1 for e in log if e[0] == "unpin") == pins
+    # readers in flight count PROGRAMS: the A tiles of a row are read by its
+    # four chains and by one program; a B tile by a chain of every row
+    assert len(readers) == 3 * _NT * _NT
+    if bound:
+        assert max(readers.values()) <= programs
+        assert sum(1 for r in readers.values() if r == 1) >= \
+            (3 if bound == 128 else 2) * _NT * _NT
 
 
 def _staggered_is_ready():

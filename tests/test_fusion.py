@@ -6,6 +6,9 @@ Layers:
   plus a randomized soundness harness — regions must be kind-homogeneous,
   size-bounded, and the condensed graph (regions + seams) must stay a DAG
   (a condensed cycle is a runtime deadlock);
+* the packing rule over its regions (`pack_source_regions`, ISSUE 32):
+  which siblings share a program, which stay as they were, and the same
+  condensed-DAG property over the packed result;
 * the C region support (`ptexec.cpp region_bind`): weighted
   completed/pending/done accounting, reset replay, misuse refusals,
   trace_mark;
@@ -28,7 +31,8 @@ import pytest
 import parsec_tpu as pt
 from parsec_tpu import native as native_mod
 from parsec_tpu.dsl.fusion import (CAPTURE_CACHE_STATS, ExecCache,
-                                   partition_regions, topo_order)
+                                   pack_source_regions, partition_regions,
+                                   topo_order)
 from parsec_tpu.dsl.ptg.compiler import PTEXEC_STATS, compile_ptg
 from parsec_tpu.utils import mca
 
@@ -156,6 +160,164 @@ def test_partition_randomized_soundness(seed):
         assert [t_ix[m] for m in members] == sorted(t_ix[m]
                                                     for m in members)
     assert _condensed_is_dag(n, off, succs, regions)
+
+
+# ------------------------------------------------- packing (ISSUE 32)
+
+def _gemm_regions(mt, nt, kt):
+    """The k-chains of a tiled GEMM as the packing rule sees them: region
+    (m, n) has ``kt`` tasks, reads row m of A, column n of B and C(m, n),
+    writes C(m, n), and has no edge to the outside."""
+    reads, writes = [], []
+    for m in range(mt):
+        for n in range(nt):
+            rd = []
+            for k in range(kt):
+                rd += [("A", m, k), ("B", k, n)]
+                if k == 0:
+                    rd.append(("C", m, n))
+            reads.append(rd)
+            writes.append([("C", m, n)])
+    nr = mt * nt
+    return [kt] * nr, ["dev"] * nr, [0] * nr, [0] * nr, reads, writes
+
+
+@pytest.mark.parametrize("max_size, chains", [
+    (128, 4),       # the bound the fusion pass has: four chains of 32
+    (64, 2),        # a lowered bound: two
+    (100, 3),       # 96 tasks fit, a fourth chain does not
+    (32, 1),        # a chain fills the bound: nothing packs
+    (31, 1),
+])
+def test_pack_fills_the_bound_and_goes_no_further(max_size, chains):
+    mt, nt, kt = 3, 12, 32
+    args = _gemm_regions(mt, nt, kt)
+    packs = pack_source_regions(*args, max_size)
+    assert packs == pack_source_regions(*args, max_size)    # the same twice
+    assert sorted(r for p in packs for r in p) == list(range(mt * nt))
+    assert [p[0] for p in packs] == sorted(p[0] for p in packs)
+    # siblings of one row, in region order: they share its 32 A tiles
+    assert packs == [list(range(lo, lo + chains))
+                     for lo in range(0, mt * nt, chains)]
+    operands = {len({k for r in p for k in args[4][r]}) for p in packs}
+    assert operands == {kt + chains * (kt + 1)}     # 164 at four, 65 at one
+
+
+def test_pack_of_a_row_that_does_not_divide_takes_the_next_row_too():
+    # six chains a row in packs of four: the two left over share B tiles
+    # with the two under them, so the grid gives two shapes of pack
+    args = _gemm_regions(2, 6, 4)
+    assert pack_source_regions(*args, 16) == [
+        [0, 1, 2, 3], [4, 5, 10, 11], [6, 7, 8, 9]]
+
+
+@pytest.mark.parametrize("what", ["producer", "consumer", "cpu", "seam"])
+def test_pack_leaves_what_is_no_source_region_as_it_was(what):
+    sizes, kinds, ext_in, ext_out, reads, writes = _gemm_regions(1, 3, 4)
+    if what == "producer":
+        ext_in[1] = 1           # a slot (or a CTL edge) from outside
+    elif what == "consumer":
+        ext_out[1] = 2          # a slot of its own consumed outside
+    else:
+        kinds[1] = {"cpu": "cpu", "seam": None}[what]
+    assert pack_source_regions(sizes, kinds, ext_in, ext_out, reads,
+                               writes, 128) == [[0, 2], [1]]
+
+
+@pytest.mark.parametrize("rd1, wr1", [
+    (["S", "X"], ["Y"]),        # reads what region 0 writes
+    (["S"], ["R"]),             # writes what region 0 reads
+    (["S"], ["X"]),             # writes what region 0 writes
+])
+def test_pack_never_orders_what_the_graph_left_unordered(rd1, wr1):
+    reads = [["S", "R"], rd1, ["S"]]
+    writes = [["X"], wr1, ["Z"]]
+    packs = pack_source_regions([2] * 3, ["dev"] * 3, [0] * 3, [0] * 3,
+                                reads, writes, 128)
+    assert packs == [[0, 2], [1]]
+
+
+def test_pack_takes_the_most_shared_and_nothing_that_shares_nothing():
+    reads = [["a", "b", "c"], ["a"], ["a", "b"], ["q"], ["b", "c", "a"],
+             ["q", "r"]]
+    packs = pack_source_regions([2] * 6, ["dev"] * 6, [0] * 6, [0] * 6,
+                                reads, [[] for _ in reads], 6)
+    # 4 shares three with region 0, then 2 (two) before 1 (one); 3 and 5
+    # share nothing with that pack and "q" with each other
+    assert packs == [[0, 4, 2], [1], [3, 5]]
+    alone = pack_source_regions([2] * 2, ["dev"] * 2, [0] * 2, [0] * 2,
+                                [["a"], ["b"]], [["a"], ["b"]], 128)
+    assert alone == [[0], [1]]
+    assert pack_source_regions([], [], [], [], [], [], 128) == []
+
+
+def _region_edges(n, off, succs, regions):
+    reg_of = [-1] * n
+    for ri, members in enumerate(regions):
+        for m in members:
+            reg_of[m] = ri
+    ext_in, ext_out = [0] * len(regions), [0] * len(regions)
+    for u in range(n):
+        for k in range(off[u], off[u + 1]):
+            a, b = reg_of[u], reg_of[succs[k]]
+            if a != b or a < 0:
+                if a >= 0:
+                    ext_out[a] += 1
+                if b >= 0:
+                    ext_in[b] += 1
+    return ext_in, ext_out
+
+
+def _check_random_packs(seed):
+    """Pack a random DAG of many components with random kinds and random
+    operands, check the result, and say how many packs hold two or more."""
+    rng = random.Random(100 + seed)
+    edges, kind = [], []
+    for _ in range(rng.randrange(12, 40)):      # small trees, a few joined
+        lo, k = len(kind), rng.choice(["dev", "dev", "dev", "cpu"])
+        for v in range(lo, lo + rng.randrange(2, 7)):
+            kind.append(k if rng.random() < 0.9 else None)
+            if v > lo:
+                edges.append((rng.randrange(lo, v), v))
+            if lo and rng.random() < 0.04:
+                edges.append((rng.randrange(0, lo), v))
+    n = len(kind)
+    off, succs = _csr(n, edges)
+    mx = rng.choice([8, 16, 128])
+    regions = partition_regions(n, off, succs, kind, min_size=2,
+                                max_size=mx)
+    ext_in, ext_out = _region_edges(n, off, succs, regions)
+    pool = [f"m{i}" for i in range(12)]
+    reads = [rng.sample(pool, rng.randrange(1, 5)) for _ in regions]
+    writes = [rng.sample(pool, rng.choice([0, 0, 1])) for _ in regions]
+    rkind = [kind[m[0]] for m in regions]
+    packs = pack_source_regions([len(m) for m in regions], rkind, ext_in,
+                                ext_out, reads, writes, mx)
+    assert sorted(r for p in packs for r in p) == list(range(len(regions)))
+    for p in packs:
+        if len(p) == 1:
+            continue
+        assert sum(len(regions[r]) for r in p) <= mx
+        for r in p:
+            assert rkind[r] == "dev" and not ext_in[r] and not ext_out[r]
+            touched = {k for q in p if q != r for k in reads[q] + writes[q]}
+            assert not touched & set(writes[r])
+            assert any(set(reads[r]) & set(reads[q]) for q in p if q != r)
+    packed = [[m for r in p for m in regions[r]] for p in packs]
+    assert _condensed_is_dag(n, off, succs, packed)
+    return sum(len(p) > 1 for p in packs)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5, 6, 7])
+def test_packed_partition_randomized_soundness(seed):
+    """The property test of the partition, over the packed result: every
+    pack is within the bound, of source regions that order nothing against
+    each other, and the condensed graph is still acyclic."""
+    _check_random_packs(seed)
+
+
+def test_the_randomized_packs_are_not_all_singletons():
+    assert sum(_check_random_packs(seed) for seed in range(8)) >= 16
 
 
 # ----------------------------------------------------- C region support

@@ -2,8 +2,9 @@
 through ``ptexec`` + region fusion + ``ptdev`` (the device module over a
 host device), against the plain reference ``ops/gemm.py:gemm_reference``.
 A region's executable is built once per *shape* of region, a task parameter
-enters the shape only where the body names it, and the path's spans record
-where ``hist_enabled`` says so. Counts and results only: no test here reads
+enters the shape only where the body names it, sibling k-chains that share
+operands pack into one region up to ``region_fusion_max`` tasks (ISSUE 32),
+and the path's spans record where ``hist_enabled`` says so. Counts and results only: no test here reads
 a clock."""
 
 import os
@@ -56,6 +57,14 @@ def _operands(mt, nt, kt, ts=TS, seed=0):
     return a, b, (A, B, C)
 
 
+@pytest.fixture()
+def fusion_max():
+    """Set the fusion pass's bound on a region's tasks (the packing rule's
+    only limit) for a test; back to the default after it."""
+    yield lambda n: mca.set("region_fusion_max", n)
+    mca.params.unset("region_fusion_max")
+
+
 def _solve(ctx, prog, mats, mt, nt, kt):
     A, B, C = mats
     tp = prog.instantiate(ctx, globals={"MT": mt, "NT": nt, "KT": kt},
@@ -82,9 +91,10 @@ class _JaxWork:
 
 
 def test_ex06_through_the_lanes_against_the_reference(dctx):
-    """MT = NT = KT = 4: 64 tasks in 16 fused k-chains, every one on the
-    device lane, ONE region program for the 16 regions; a second and a
-    third instantiation build, trace and load nothing."""
+    """MT = NT = KT = 4: 64 tasks in 16 fused k-chains that share their A
+    and B tiles, so one pack of 16 chains (64 tasks, under the bound of
+    128) on the device lane, ONE region program; a second and a third
+    instantiation build, trace and load nothing."""
     a, b, mats = _operands(4, 4, 4)
     prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
     assert prog.body_names["GEMM"] >= {"A", "B", "C"}
@@ -100,7 +110,8 @@ def test_ex06_through_the_lanes_against_the_reference(dctx):
             assert dx["pools_fallback"] == dx["pools_ineligible"] == 0
             assert dd["pools_engaged"] == 1 and dd["tasks_engaged"] == 64
             assert dd["pools_fallback"] == dd["pools_ineligible"] == 0
-            assert dx["fused_regions"] == 16 and dx["fused_tasks"] == 64
+            assert dx["fused_regions"] == 1 and dx["fused_tasks"] == 64
+            assert dx["packed_regions"] == 16
             assert dx["region_programs"] == (1 if solves == 1 else 0)
             if solves > 1:
                 assert (work.traces, work.compiles) == before
@@ -114,11 +125,19 @@ def test_ex06_through_the_lanes_against_the_reference(dctx):
         work.on = False
 
 
-def test_two_hundred_equal_regions_are_one_program_and_evict_nothing(dctx):
-    """200 C tiles, so 200 structurally equal regions: keyed by region index
+@pytest.mark.parametrize("bound, packs, packed, programs", [
+    (2, 200, 0, 1),     # a chain fills the bound: 200 regions, one shape
+    (8, 50, 200, 2),    # packs of four: a row of ten is 4 + 4 + a 2 x 2
+                        # block with the row under it, so two shapes
+])
+def test_two_hundred_equal_regions_are_few_programs_and_evict_nothing(
+        dctx, fusion_max, bound, packs, packed, programs):
+    """200 C tiles, so 200 structurally equal k-chains: keyed by region index
     they walked the 128-entry LRU in order and a second instantiation hit
-    nothing; keyed by shape there is one entry and no eviction."""
+    nothing; keyed by shape there is one entry a shape and no eviction,
+    packed or not."""
     mt, nt, kt, ts = 20, 10, 2, 8
+    fusion_max(bound)
     a, b, mats = _operands(mt, nt, kt, ts)
     prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
     c0, x0 = CAPTURE_CACHE_STATS.snapshot(), PTEXEC_STATS.snapshot()
@@ -127,8 +146,11 @@ def test_two_hundred_equal_regions_are_one_program_and_evict_nothing(dctx):
         ref = np.asarray(gemm_reference(a, b, np.zeros_like(got), solves))
         np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4 * solves)
     dx, dc = PTEXEC_STATS.delta(x0), CAPTURE_CACHE_STATS.delta(c0)
-    assert dx["fused_regions"] == 2 * 200 and dx["region_programs"] == 1
-    assert dc == {"cache_hits": 1, "cache_misses": 1, "cache_evictions": 0}
+    assert dx["fused_regions"] == 2 * packs and dx["fused_tasks"] == 2 * 400
+    assert dx["packed_regions"] == 2 * packed
+    assert dx["region_programs"] == programs
+    assert dc == {"cache_hits": programs, "cache_misses": programs,
+                  "cache_evictions": 0}
     assert prog.region_programs.evictions == 0
 
 
@@ -138,17 +160,25 @@ SRC_READS_M = ex06_gemm_ptg.SRC.replace(
     "C = C + jnp.dot(", "C = C + (m + 1.0) * jnp.dot(")
 
 
-def test_a_body_that_names_a_parameter_is_not_merged(dctx):
-    """One program per distinct tuple of the parameters the body names (the
-    MT rows), not one for all 16 regions and not 16; the answer is right,
-    and a second instantiation still builds nothing."""
+@pytest.mark.parametrize("bound, programs", [
+    (4, 4),         # a chain a region: one program a row, as before packing
+    (16, 4),        # a row a pack: its four chains name one m
+    (128, 1),       # the whole grid one pack, that names all four
+])
+def test_a_body_that_names_a_parameter_is_not_merged(dctx, fusion_max,
+                                                     bound, programs):
+    """One program per distinct shape, and the parameters a body names are
+    part of the shape: the MT rows are never one program for all 16 chains
+    unless one pack holds them all, and never 16; the answer is right, and
+    a second instantiation still builds nothing."""
     assert SRC_READS_M != ex06_gemm_ptg.SRC
+    fusion_max(bound)
     a, b, mats = _operands(4, 4, 4)
     prog = compile_ptg(SRC_READS_M, "gemm_m")
     assert "m" in prog.body_names["GEMM"] and "k" not in prog.body_names["GEMM"]
     x0 = PTEXEC_STATS.snapshot()
     got = _solve(dctx, prog, mats, 4, 4, 4)
-    assert PTEXEC_STATS.delta(x0)["region_programs"] == 4
+    assert PTEXEC_STATS.delta(x0)["region_programs"] == programs
     ref = np.asarray(gemm_reference(a, b, np.zeros_like(got))) \
         * np.repeat(np.arange(1.0, 5.0, dtype=np.float32), TS)[:, None]
     np.testing.assert_allclose(got, ref, rtol=0, atol=4e-4)
@@ -158,31 +188,77 @@ def test_a_body_that_names_a_parameter_is_not_merged(dctx):
     np.testing.assert_allclose(got2, 2 * ref, rtol=0, atol=8e-4)
 
 
-def test_a_global_enters_the_key_only_where_a_body_names_it(dctx):
-    """The GEMM body names no global: a wider grid of the same k-chains
-    reuses the program. A body that names ``NT`` does not share across
-    values of ``NT``."""
+def test_a_global_enters_the_key_only_where_a_body_names_it(dctx, fusion_max):
+    """The GEMM body names no global: a taller grid of the same packs (2 x 2
+    blocks of k-chains) reuses the program. A body that names ``NT`` does
+    not share across values of ``NT``."""
     prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
     assert prog.globals_named is not None
     assert not prog.globals_named & {"MT", "NT", "KT"}
+    fusion_max(16)                  # four chains of four
     x0 = PTEXEC_STATS.snapshot()
-    for mt, nt in ((2, 2), (4, 3)):
+    for mt, nt in ((2, 2), (4, 2)):
         a, b, mats = _operands(mt, nt, 4, seed=mt)
         got = _solve(dctx, prog, mats, mt, nt, 4)
         np.testing.assert_allclose(
             got, np.asarray(gemm_reference(a, b, np.zeros_like(got))),
             rtol=0, atol=1e-4)
-    assert PTEXEC_STATS.delta(x0)["region_programs"] == 1
+    dx = PTEXEC_STATS.delta(x0)
+    assert dx["region_programs"] == 1 and dx["fused_regions"] == 1 + 2
     scaled = compile_ptg(ex06_gemm_ptg.SRC.replace(
         "C = C + jnp.dot(", "C = C + (1.0 / NT) * jnp.dot("), "gemm_nt")
     assert "NT" in scaled.globals_named
+    fusion_max(4)                   # two chains of two, whatever NT
     x0 = PTEXEC_STATS.snapshot()
-    for nt in (2, 3):               # the same k-chains, another NT
+    for nt in (2, 4):               # the same pairs of k-chains, another NT
         a, b, mats = _operands(2, nt, 2, seed=nt)
         got = _solve(dctx, scaled, mats, 2, nt, 2)
         ref = np.asarray(gemm_reference(a, b, np.zeros_like(got))) / nt
         np.testing.assert_allclose(got, ref, rtol=0, atol=1e-4)
-    assert PTEXEC_STATS.delta(x0)["region_programs"] == 2
+    dx = PTEXEC_STATS.delta(x0)
+    assert dx["region_programs"] == 2 and dx["fused_regions"] == 2 + 4
+
+
+def test_the_benchmark_cell_is_256_programs_of_164_operands(dctx):
+    """``ptg_gemm.ts512``'s pool (NT = 32, tiny tiles here): 1,024 k-chains
+    of 32 at the default bound of 128 are 256 packs of the four chains of a
+    row, each passing its 32 A tiles once: 164 operands where four programs
+    took 4 x 65; one shape, so one executable."""
+    nt = 32
+    a, b, mats = _operands(nt, nt, nt, ts=4)
+    prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
+    x0 = PTEXEC_STATS.snapshot()
+    got = _solve(dctx, prog, mats, nt, nt, nt)
+    dx = PTEXEC_STATS.delta(x0)
+    assert dx["packed_regions"] == 1024 and dx["fused_regions"] == 256
+    assert dx["fused_tasks"] == dx["tasks_device"] == nt ** 3
+    assert dx["region_programs"] == 1
+    (ent,) = prog._ptexec_cache.values()
+    plan = ent["fusion"]
+    assert {len(r["ext"]) for r in plan["regions"]} == {164}
+    assert {len(r["members"]) for r in plan["regions"]} == {128}
+    # a pack is four neighbours of one row: its C tiles, in order
+    assert plan["regions"][9]["wb_keys"] == [
+        ("descC", (1, n)) for n in (4, 5, 6, 7)]
+    np.testing.assert_allclose(
+        got, np.asarray(gemm_reference(a, b, np.zeros_like(got))),
+        rtol=0, atol=1e-3)
+
+
+def test_a_pool_with_nothing_to_pack_counts_none(dctx, fusion_max):
+    """One k-chain has no sibling, and chains that fill the bound take none:
+    ``packed_regions`` stays 0 and the regions are the partition's."""
+    prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
+    for bound, (mt, nt), regions in ((128, (1, 1), 1), (4, (3, 3), 9)):
+        fusion_max(bound)
+        a, b, mats = _operands(mt, nt, 4)
+        x0 = PTEXEC_STATS.snapshot()
+        got = _solve(dctx, prog, mats, mt, nt, 4)
+        dx = PTEXEC_STATS.delta(x0)
+        assert dx["packed_regions"] == 0 and dx["fused_regions"] == regions
+        np.testing.assert_allclose(
+            got, np.asarray(gemm_reference(a, b, np.zeros_like(got))),
+            rtol=0, atol=1e-4)
 
 
 @pytest.mark.parametrize("body, named", [
@@ -199,10 +275,19 @@ def test_what_a_body_names(body, named):
     assert _names_in(body) == named
 
 
-def test_fused_equals_unfused(dctx):
+@pytest.mark.parametrize("bound", [4, 8, 128],
+                         ids=["chains", "packs-of-2", "one-pack"])
+def test_fused_equals_unfused(dctx, fusion_max, bound):
+    """Packed or not, a C tile is the same dependent dots in the same
+    order: bit for bit the unfused run's."""
+    fusion_max(bound)
     a, b, mats = _operands(4, 4, 4, seed=3)
+    x0 = PTEXEC_STATS.snapshot()
     fused = _solve(dctx, compile_ptg(ex06_gemm_ptg.SRC, "gemm"),
                    mats, 4, 4, 4)
+    dx = PTEXEC_STATS.delta(x0)
+    assert dx["fused_regions"] == {4: 16, 8: 8, 128: 1}[bound]
+    assert dx["packed_regions"] == (0 if bound == 4 else 16)
     mca.set("region_fusion", False)
     try:
         _a, _b, mats = _operands(4, 4, 4, seed=3)
@@ -228,11 +313,12 @@ def _counts():
 def test_the_path_records_its_spans_where_hist_enabled_says_so():
     """One ``ptg.lower_ns`` record an instantiation; one
     ``ptdev.dispatch_ns`` and one ``ptdev.retire_ns`` a device program (a
-    fused region); ``ptdev.stage_in_ns`` the misses of the push phase that
+    fused region: here a pack of the four k-chains of a row); ``ptdev.stage_in_ns`` the misses of the push phase that
     moved bytes; ``ptdev.pins`` one record a dispatch callback;
     ``ptdev.poll_ns`` the manager's passes."""
     mca.set("device_tpu_over_cpu", True)
     mca.set("hist_enabled", True)
+    mca.set("region_fusion_max", 16)
     try:
         n0 = _counts()
         ctx = Context(nb_cores=1)
@@ -246,14 +332,15 @@ def test_the_path_records_its_spans_where_hist_enabled_says_so():
         def delta(key):
             return n1.get(key, 0) - n0.get(key, 0)
         assert delta("ptg.lower_ns") == 2
-        assert delta("ptdev.dispatch_ns") == delta("ptdev.retire_ns") == 32
+        assert delta("ptdev.dispatch_ns") == delta("ptdev.retire_ns") == 8
         # A, B and C staged in once; in the second solve C's new version is
         # the device array the write-back left: adopted, no byte moved
         assert delta("ptdev.stage_in_ns") == 48
-        assert 2 <= delta("ptdev.pins") <= 32
+        assert 2 <= delta("ptdev.pins") <= 8
         assert 1 <= delta("ptdev.poll_ns")
         ctx.fini()
     finally:
+        mca.params.unset("region_fusion_max")
         mca.params.unset("hist_enabled")
         mca.params.unset("device_tpu_over_cpu")
 
