@@ -314,7 +314,10 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
 
     def traced_dispatch(ids):
         # one span a callback, recorded once per device program; the
-        # table pins the callback took, one record
+        # table pins the callback took and the programs it found in
+        # flight (the depth of the device's queue as the host left it),
+        # one record each
+        sp.pt_inflight.record(len(inflight))
         tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
         try:
             return dispatch(ids)

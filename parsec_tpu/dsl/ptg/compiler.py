@@ -28,6 +28,7 @@ import ast
 import textwrap
 import threading
 import time
+import types
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -91,7 +92,11 @@ PTEXEC_STATS = _LaneStats(pools_engaged=0, tasks_engaged=0,
                           region_programs=0,
                           # regions of the partition that share a program
                           # with a sibling (ISSUE 32, pack_source_regions)
-                          packed_regions=0)
+                          packed_regions=0,
+                          # fused regions whose members are of more than
+                          # one class (ISSUE 33): a factorization's, where
+                          # a one-class graph has none
+                          mixed_regions=0)
 
 _ACCESS_MAP = {
     P.FLOW_READ: FLOW_ACCESS_READ,
@@ -176,7 +181,7 @@ def _timed_region_program(fn, n_members: int):
     return call
 
 
-def _mk_region_program(rp: Dict[str, Any], fns, written_by_class):
+def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes):
     """The fused super-task's body (ISSUE 12): ONE traceable program
     replaying the region's members in serialization order (topo order of
     the member subgraph — a valid serialization, the DTD-capture
@@ -191,7 +196,9 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class):
     every region of one shape: ``rp`` is then the shape's canonical plan
     (:func:`_region_shape`), whose slot and memory ids are the region's
     own numbering. ``rp["name"]`` names the program, hence its XLA
-    module (``jit_ptg_region_<classes>``)."""
+    module (``jit_ptg_region_<classes>``); ``scopes[ci]`` is class
+    ``ci``'s name, the ``jax.named_scope`` of its members' bodies."""
+    import jax
     steps, out_slots = rp["steps"], rp["out_slots"]
 
     def region_program(ext_vals):
@@ -211,7 +218,10 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class):
                     vals.append(None)
             fn = fns[ci]
             if fn is not None:
-                outs = fn(*key, *vals)
+                # the class's name on the member's operations, so a device
+                # trace of a mixed region says whose time it is
+                with jax.named_scope(scopes[ci]):
+                    outs = fn(*key, *vals)
                 for oj, dj in enumerate(written_by_class[ci]):
                     vals[dj] = outs[oj]
             for dj in range(nd):
@@ -299,6 +309,9 @@ class PTGTaskpool(Taskpool):
             prologue_names = {k: v for k, v in pns.items()
                               if not k.startswith("__") and k != "np"}
             self.env_base.update(prologue_names)
+        #: what the prologue defined and ``globals=`` did not replace: new
+        #: objects in every instantiation, so never part of a cache key
+        self._prologue_names = frozenset(prologue_names) - set(globals_)
         self.env_base.update(globals_)
         self.collections = collections
         missing = [g for g in program.spec.globals
@@ -356,13 +369,12 @@ class PTGTaskpool(Taskpool):
 
     def _build_class(self, tcs: P.TaskClassSpec, tc: TaskClass) -> None:
         spec = self.program.spec
-        # ranges
-        ranges = [(r.param, _Expr(r.lo_expr), _Expr(r.hi_expr), _Expr(r.step_expr))
-                  for r in tcs.ranges]
-        # order ranges by parameter declaration order
-        order = {p: i for i, p in enumerate(tcs.params)}
-        ranges.sort(key=lambda r: order[r[0]])
-        tc._ptg_ranges = ranges
+        # ranges, in the order they are declared (a JDF's locals): a bound
+        # may read any local declared above it, and the parser has refused
+        # one that reads a local declared below. Keys and parameter
+        # tuples stay in parameter order
+        tc._ptg_ranges = [(r.param, _Expr(r.lo_expr), _Expr(r.hi_expr),
+                           _Expr(r.step_expr)) for r in tcs.ranges]
         tc._ptg_spec = tcs
         # header property block (ref: udf.jdf user-defined functions):
         # names resolve against the taskpool globals at instantiate time
@@ -949,7 +961,8 @@ class PTGTaskpool(Taskpool):
     def _enum_class_fast(self, tc: TaskClass):
         """Param-value tuples via itertools.product when every range bound
         is static (depends on globals only); None when bounds reference
-        other params (triangular spaces fall back to the dict walk)."""
+        other params (triangular spaces fall back to the dict walk) or
+        the ranges are not declared in parameter order."""
         import itertools
         env0 = self._env({})
         rs = []
@@ -1063,9 +1076,14 @@ class PTGTaskpool(Taskpool):
     def _ptexec_cache_key(self, names: Tuple[str, ...], place: Tuple):
         """Cache signature for the flattened graph: the task space and the
         edge structure depend only on the program text and the globals the
-        range/guard/index expressions read. Non-primitive globals (incl.
-        user callables a guard might invoke) make the instantiation
-        uncacheable — flatten still runs, per pool.
+        range/guard/index expressions read. A module-level function handed
+        in ``globals=`` (the kernels a JDF's bodies call by name) enters
+        the signature by identity, as a jitted function keys its callee:
+        the key holds the function, so the identity stays its own. Any
+        other non-primitive global — a nested function or a lambda made
+        per call, a prologue's definitions (``exec``'d per instantiation:
+        new objects every pool), an array, a module — makes the
+        instantiation uncacheable; flatten still runs, per pool.
 
         ``place`` is the placement fingerprint (ISSUE 12 satellite):
         (nb_ranks, comm lane, device lane, device fingerprint, fusion
@@ -1078,11 +1096,15 @@ class PTGTaskpool(Taskpool):
         for k, v in self.env_base.items():
             if k == "__builtins__" or self._PTEXEC_SAFE_ENV.get(k) is v:
                 continue
-            if v is None or isinstance(v, (int, float, str, bool)):
+            if v is None or isinstance(v, (int, float, str, bool)) or (
+                    isinstance(v, types.FunctionType)
+                    and "<locals>" not in v.__qualname__
+                    and k not in self._prologue_names):
                 sig.append((k, v))
             else:
                 return None
-        return (tuple(sorted(sig)), names, place)
+        # names are unique, so the sort never compares two values
+        return (tuple(sorted(sig, key=lambda kv: kv[0])), names, place)
 
     def _ptexec_flatten(self, classes: List[TaskClass]):
         """Emit the flattened tables the native lane consumes (the jdf2c
@@ -1897,7 +1919,10 @@ class PTGTaskpool(Taskpool):
                                if reg_of[w[0]] < 0],
                 "dev_mask": dev_mask2, "ndev_tasks": ndev_tasks,
                 "n_seam": n - n_fused, "n_fused": n_fused,
-                "n_packed": n_packed}
+                "n_packed": n_packed,
+                "n_mixed": sum(
+                    len({cls_of[m] for m in members}) > 1
+                    for members in regions)}
 
     @staticmethod
     def _ptexec_pack(regions: List[List[int]], kind, flat,
@@ -2045,6 +2070,7 @@ class PTGTaskpool(Taskpool):
         mem_datas, writebacks = self._ptexec_mem(data["mem_reads"],
                                                  plan["writebacks"])
         fns, written_by_class = self._ptexec_class_fns(classes, data)
+        scopes = [tc._ptg_spec.name for tc in classes]
         cache = self.program.region_programs
         # the flatten key names every primitive global; a region program
         # depends on those a body names, so pools that differ in the
@@ -2064,7 +2090,7 @@ class PTGTaskpool(Taskpool):
                 None if rkey is None else (rkey, shape["sig"]),
                 lambda shape=shape: _timed_region_program(
                     jax.jit(_mk_region_program(shape, fns,
-                                               written_by_class)),
+                                               written_by_class, scopes)),
                     len(shape["steps"])))
             if not hit:
                 PTEXEC_STATS["region_programs"] += 1
@@ -2100,8 +2126,13 @@ class PTGTaskpool(Taskpool):
             class_fns=(fns, written_by_class))
         PTEXEC_STATS["fused_regions"] += len(plan["regions"])
         PTEXEC_STATS["packed_regions"] += plan["n_packed"]
+        PTEXEC_STATS["mixed_regions"] += plan["n_mixed"]
         PTEXEC_STATS["fused_tasks"] += plan["n_fused"]
         PTEXEC_STATS["seam_tasks"] += plan["n_seam"]
+        sp = self.ctx._spans
+        if sp is not None:
+            for rp in plan["regions"]:
+                sp.region_tasks.record(len(rp["members"]))
         if devlane is not None and plan["dev_mask"] is not None:
             self._ptexec_bind_dev(
                 lane, devlane, flat, (fns, written_by_class), names,

@@ -32,6 +32,7 @@ give per-device chores (ref: __parsec_chore_t incarnations).
 
 from __future__ import annotations
 
+import ast
 import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Tuple
@@ -452,6 +453,16 @@ def parse(source: str, name: str = "ptg") -> ProgramSpec:
     return prog
 
 
+def _names(expr: str) -> set:
+    """The identifiers an expression names (none, where it does not
+    parse: the compiler reports that with the expression's text)."""
+    try:
+        tree = ast.parse(expr.strip(), mode="eval")
+    except SyntaxError:
+        return set()
+    return {n.id for n in ast.walk(tree) if isinstance(n, ast.Name)}
+
+
 def _validate(prog: ProgramSpec) -> None:
     """Compile-time sanity checks (the ptgpp negative-test battery role)."""
     if not prog.task_classes:
@@ -467,6 +478,18 @@ def _validate(prog: ProgramSpec) -> None:
         if missing:
             raise PTGSyntaxError(
                 f"task class {tc.name}: parameters {missing} have no range")
+        # ranges are evaluated in the order they are declared, as a JDF's
+        # locals are: a bound may read any local declared above it
+        later = set(tc.params)
+        for r in tc.ranges:
+            for src in (r.lo_expr, r.hi_expr, r.step_expr):
+                ahead = _names(src) & later
+                if ahead:
+                    raise PTGSyntaxError(
+                        f"task class {tc.name}: the range of {r.param!r} "
+                        f"reads {sorted(ahead)}, declared at or below it "
+                        f"(ranges are evaluated in declaration order)")
+            later.discard(r.param)
         for f in tc.flows:
             # WRITE-only flows are scratch outputs (ref: write_check.jdf's
             # "WRITE A1 -> ..." — allocated at run time, body fills them);

@@ -18,6 +18,8 @@ DTD tile chains, exactly like the insert-task Cholesky of the reference
 
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 
 from ..data.matrix import TiledMatrix
@@ -89,6 +91,92 @@ def insert_potrf_tasks(tp: DTDTaskpool, A: TiledMatrix) -> int:
                                (tp.tile_of(A, m, n), RW | AFFINITY),
                                priority=prio, name="GEMM")
     return tp.inserted - n0
+
+
+#: the same DAG as a PTG: DPLASMA's ``src/zpotrf_L.jdf`` (dpotrf, lower,
+#: right-looking), its four classes ``potrf_zpotrf(k)``, ``potrf_ztrsm(m, k)``,
+#: ``potrf_zherk(k, m)``, ``potrf_zgemm(m, n, k)`` over their triangular
+#: task space, ranges declared in DPLASMA's order (``GEMM``'s ``k`` first:
+#: a bound reads the locals declared above it). The bodies call the tile
+#: functions above by name, handed in as globals by :func:`potrf_taskpool`;
+#: the priorities are :func:`insert_potrf_tasks`'s (DPLASMA's own formulas
+#: are not in this tree).
+POTRF_JDF = """
+%global NT
+%global descA
+
+POTRF(k)
+  k = 0 .. NT-1
+  : descA(k, k)
+  priority = (NT - k) * 10000 + 3000
+  RW T <- (k == 0) ? descA(k, k) : T SYRK(k-1, k)
+       -> T TRSM(k+1 .. NT-1, k)
+       -> descA(k, k)
+BODY [type=TPU]
+  T = tile_potrf(T)
+END
+
+TRSM(m, k)
+  m = 1 .. NT-1
+  k = 0 .. m-1
+  : descA(m, k)
+  priority = (NT - k) * 10000 + 2000
+  READ T <- T POTRF(k)
+  RW C <- (k == 0) ? descA(m, k) : C GEMM(m, k, k-1)
+       -> A SYRK(k, m)
+       -> A GEMM(m, k+1 .. m-1, k)
+       -> B GEMM(m+1 .. NT-1, m, k)
+       -> descA(m, k)
+BODY [type=TPU]
+  C = tile_trsm(T, C)
+END
+
+SYRK(k, m)
+  k = 0 .. NT-2
+  m = k+1 .. NT-1
+  : descA(m, m)
+  priority = (NT - k) * 10000 + 1000
+  READ A <- C TRSM(m, k)
+  RW T <- (k == 0) ? descA(m, m) : T SYRK(k-1, m)
+       -> (m == k+1) ? T POTRF(m) : T SYRK(k+1, m)
+BODY [type=TPU]
+  T = tile_syrk(A, T)
+END
+
+GEMM(m, n, k)
+  k = 0 .. NT-3
+  m = k+2 .. NT-1
+  n = k+1 .. m-1
+  : descA(m, n)
+  priority = (NT - k) * 10000
+  READ A <- C TRSM(m, k)
+  READ B <- C TRSM(n, k)
+  RW C <- (k == 0) ? descA(m, n) : C GEMM(m, n, k-1)
+       -> (n == k+1) ? C TRSM(m, n) : C GEMM(m, n, k+1)
+BODY [type=TPU]
+  C = tile_gemm_update(A, B, C)
+END
+"""
+
+
+@functools.lru_cache(maxsize=None)
+def potrf_program():
+    """:data:`POTRF_JDF` compiled, once a process: its pools share one
+    flatten, one fusion plan and one set of region executables."""
+    from ..dsl.ptg.compiler import compile_ptg
+    return compile_ptg(POTRF_JDF, "potrf")
+
+
+def potrf_taskpool(ctx, A: TiledMatrix):
+    """A new taskpool of :data:`POTRF_JDF` over ``A`` (lower), as DPLASMA
+    creates one per call of ``dplasma_dpotrf``; the caller adds it to
+    ``ctx``. ``A.mt * (A.mt + 1) * (A.mt + 2) // 6`` tasks."""
+    assert A.mt == A.nt, "POTRF needs a square tile grid"
+    return potrf_program().instantiate(
+        ctx, globals={"NT": A.mt, "tile_potrf": tile_potrf,
+                      "tile_trsm": tile_trsm, "tile_syrk": tile_syrk,
+                      "tile_gemm_update": tile_gemm_update},
+        collections={"descA": A})
 
 
 def potrf_flops(N: int) -> float:

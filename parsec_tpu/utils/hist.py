@@ -48,7 +48,9 @@ _FOLD_AT = 1024
 
 #: the histogram names each lane kind exports (hist_snapshot() keys)
 HIST_NAMES: Dict[str, Tuple[str, ...]] = {
-    "ptexec": ("exec_ns", "ready_wait_ns"),
+    # ``region_tasks`` is no time and not the lane's: one record per fused
+    # region a PTG pool binds, its members (utils/xla_trace.py Spans)
+    "ptexec": ("exec_ns", "ready_wait_ns", "region_tasks"),
     "ptdtd": ("exec_ns", "ready_wait_ns"),
     "ptcomm": ("rdv_rtt_ns", "act_queue_ns"),
     "sched": ("queue_ns",),     # plane push->pop wait (ISSUE 9)
@@ -60,10 +62,12 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     "dtd": ("link_ns", "stall_ns"),
     # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
     # lanes, and the ptdev manager's dispatch / stage-in / poll / retire
-    # ``pins`` is no time: one record per dispatch callback, the table
-    # pins it took
+    # ``pins`` and ``inflight`` are no times: one record each per dispatch
+    # callback, the table pins it took and the programs already in flight
+    # when it was called
     "ptg": ("lower_ns",),
-    "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns", "pins"),
+    "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns", "pins",
+              "inflight"),
 }
 
 

@@ -20,8 +20,9 @@ instantiation, and the ``ptdev`` manager's dispatch, poll and retire),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
 alone: the ready-wait, and ``ptdev.stage_in_ns`` (a miss of the lane's
-push phase, whose annotation is the ``dev.stage_in`` it nests), and two
-counts filed the same way: ``tpudev.group_tasks`` and ``ptdev.pins``.
+push phase, whose annotation is the ``dev.stage_in`` it nests), and four
+counts filed the same way: ``tpudev.group_tasks``, ``ptdev.pins``,
+``ptdev.inflight`` and ``ptexec.region_tasks``.
 One object per ``Context``, ``None`` when off, so a site is
 ``sp = self._spans`` / ``if sp is not None:``.
 """
@@ -100,10 +101,16 @@ class Spans:
         self.pt_poll = ptdev.cell("poll_ns")
         self.pt_retire = ptdev.cell("retire_ns")
         self.pt_pins = ptdev.cell("pins")
+        self.pt_inflight = ptdev.cell("inflight")
+        # a fused region's members, one record a region a pool binds:
+        # filed beside the ptexec lane's own histograms, as the ready-wait
+        # is beside ptdtd's
+        regions = PyHistograms(("region_tasks",))
+        self.region_tasks = regions.cell("region_tasks")
         #: (registry kind, object) for Context._hist_attach/_hist_detach
         self.hists: List[Tuple[str, PyHistograms]] = [
             ("tpudev", tpudev), ("dtd", dtd), ("ptdtd", ready),
-            ("ptg", ptg), ("ptdev", ptdev)]
+            ("ptg", ptg), ("ptdev", ptdev), ("ptexec", regions)]
 
     def begin(self, name: str):
         ann = None

@@ -1030,7 +1030,7 @@ def test_ptdev_pins_records_once_a_dispatch_callback(monkeypatch, spans):
 def test_pins_per_program_reader(monkeypatch, snapshot, want):
     """``chipbench/layers/pins_per_program.py``: ``ptdev.pins`` sum over
     ``ptdev.dispatch_ns`` count, nothing where the program lacks either; its
-    entry is the last of BENCHMARK.json's ``per_layer``."""
+    entry lists the PTG cells (ISSUE 33 appended the factorization's)."""
     import json
     import os
     from parsec_tpu.utils.hist import histograms
@@ -1040,11 +1040,12 @@ def test_pins_per_program_reader(monkeypatch, snapshot, want):
     monkeypatch.setattr(histograms, "snapshot", lambda: snapshot)
     assert pins_per_program.read(None) == want
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        entry = json.load(f)["per_layer"][-1]
+        entry, = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == "pins_per_program"]
     assert entry == {"name": "pins_per_program", "unit": "pins/program",
                      "better": "lower", "source": "program_counter",
                      "layer": "device issue", "moves": "tasks_per_s",
-                     "workloads": ["ptg_gemm.ts512"]}
+                     "workloads": ["ptg_gemm.ts512", "ptg_potrf.ts512"]}
 
 
 # ---------------------------------------------------------------------------

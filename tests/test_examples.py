@@ -20,7 +20,7 @@ from parsec_tpu.parallel.multihost import cpu_collectives_available
 # timed in threaded-BLAS passes) takes minutes on a shared host: slow
 EXAMPLES = [f"ex0{i}" for i in range(9)] + [
     "ex10", "ex11", "ex12", "ex13", "ex14", "ex15", "ex16",
-    pytest.param("ex17", marks=pytest.mark.slow)]
+    pytest.param("ex17", marks=pytest.mark.slow), "ex18"]
 EX_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                       "examples")
 

@@ -460,6 +460,79 @@ def test_negative(case):
             ctx.fini()
 
 
+# --------------------------------------------------------------------------
+# ranges are evaluated in declaration order, as a JDF's locals are
+# --------------------------------------------------------------------------
+
+def _tri_src(ranges):
+    return f"""
+%global NT
+%global A
+GEMM(m, n, k)
+{ranges}
+  : A(0, 0)
+  CTL c <- (k > 0) ? c GEMM(m, n, k-1)
+        -> (k < n-1) ? c GEMM(m, n, k+1)
+BODY
+  pass
+END
+"""
+
+
+#: DPLASMA's potrf_zgemm(m, n, k): k first, m reads k, n reads both
+DECLARED_KMN = _tri_src("  k = 0 .. NT-3\n  m = k+2 .. NT-1\n  n = k+1 .. m-1")
+#: the same set, respelled so that declaration order is parameter order
+DECLARED_MNK = _tri_src("  m = 2 .. NT-1\n  n = 1 .. m-1\n  k = 0 .. n-1")
+
+
+def _space(src, nt, ctx):
+    tp = compile_ptg(src, "tri").instantiate(
+        ctx, globals={"NT": nt}, collections={"A": None})
+    tc = tp._classes["GEMM"]
+    return tp, tc, [tuple(loc[p] for p in ("m", "n", "k"))
+                    for loc in tp._enum_class(tc)]
+
+
+@pytest.mark.parametrize("nt", [3, 4, 7])
+def test_ranges_in_declaration_order_enumerate_the_same_set(ctx, nt):
+    """A bound may read any local declared above it, whatever the
+    parameter order: k, m, n enumerates what m, n, k does, k outermost;
+    task keys stay in parameter order."""
+    tp, tc, kmn = _space(DECLARED_KMN, nt, ctx)
+    _tp, _tc, mnk = _space(DECLARED_MNK, nt, ctx)
+    want = {(m, n, k) for k in range(nt - 2) for m in range(k + 2, nt)
+            for n in range(k + 1, m)}
+    assert len(kmn) == len(want) == nt * (nt - 1) * (nt - 2) // 6
+    assert set(kmn) == set(mnk) == want
+    assert kmn == sorted(kmn, key=lambda t: (t[2], t[0], t[1]))
+    assert mnk == sorted(mnk)
+    assert tc.make_key(tp, {"k": 0, "m": 2, "n": 1}) == (2, 1, 0)
+    assert tp._enum_class_fast(tc) is None      # the dict walk, not product
+
+
+def test_a_bound_that_reads_a_later_local_is_a_syntax_error():
+    """At compile time, not a NameError at instantiation."""
+    src = _tri_src("  m = k+2 .. NT-1\n  k = 0 .. NT-3\n  n = k+1 .. m-1")
+    with pytest.raises(P.PTGSyntaxError, match=r"range of 'm' reads \['k'\]"):
+        compile_ptg(src, "tri")
+    own = _tri_src("  k = 0 .. k\n  m = k+2 .. NT-1\n  n = k+1 .. m-1")
+    with pytest.raises(P.PTGSyntaxError, match="range of 'k'"):
+        compile_ptg(own, "tri")
+
+
+def test_a_source_declared_in_parameter_order_enumerates_unchanged(ctx):
+    """``GEMM_SRC`` (m, n, k over static bounds): the product order, and
+    the fast enumerator still takes it."""
+    tp = compile_ptg(GEMM_SRC, "gemm").instantiate(
+        ctx, globals={"MT": 2, "NT": 3, "KT": 2},
+        collections={"descA": None, "descB": None, "descC": None})
+    tc = tp._classes["GEMM"]
+    want = [(m, n, k) for m in range(2) for n in range(3) for k in range(2)]
+    assert [tuple(loc[p] for p in ("m", "n", "k"))
+            for loc in tp._enum_class(tc)] == want
+    assert list(tp._enum_class_fast(tc)) == want
+
+
 def test_descending_range(ctx):
     """Negative-step ranges include both endpoints (countdown chains)."""
     src = """

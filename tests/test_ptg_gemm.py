@@ -362,7 +362,7 @@ def test_the_new_histograms_are_registered_and_collide_with_nothing():
     (``utils/counters.py``) without taking a name one of them has."""
     assert H.HIST_NAMES["ptg"] == ("lower_ns",)
     assert H.HIST_NAMES["ptdev"] == ("dispatch_ns", "stage_in_ns", "poll_ns",
-                                     "retire_ns", "pins")
+                                     "retire_ns", "pins", "inflight")
     from parsec_tpu.device.native import COH_COUNTER_KEYS, DEV_COUNTER_KEYS
     taken = set(DEV_COUNTER_KEYS) | set(COH_COUNTER_KEYS) | set(PTDEV_STATS)
     assert not any(k.startswith("hist") for k in taken)
