@@ -42,9 +42,13 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
 
     ``fusion``, for an engine whose nodes are regions and seams:
     ``orig_of`` maps a node to its task, ``dev_regions`` a region's node
-    to its program (``ext``, ``ext_mems``, ``out_slots``, ``jitted``,
-    ``wb_pairs``, ``ntasks``, ``cls``, ``cold``), ``marks`` is the
-    (event, start, end) of ``engine.trace_mark``.
+    to its program: ``jitted(donated, kept)`` over the operands ``ext``
+    (the leading ones are the slots ``given``, which the program keeps,
+    see below; the memory operands are also ``ext_mems``) returns two
+    tuples, ``outs``
+    and ``wb_pairs`` say which of them (flattened) lands in which slot
+    and in which ``Data``; ``ntasks``, ``cls``, ``cold``. ``marks`` is
+    the (event, start, end) of ``engine.trace_mark``.
 
     Call it LAST: ``dev_bind`` surfaces zero-dependency device tasks at
     once and the manager may dispatch them before this returns. Returns
@@ -88,6 +92,14 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     end of ``dispatch``, where no program of the batch reads it).
     Returns ``(dispatch, poll, held)``; ``held`` is empty whenever
     nothing is in flight.
+
+    Ownership: a table copy is the residency table's and a written-back
+    array its ``Data``'s; a slot value is the pool's until its last
+    reader takes it. A fused region's leading operands, ``given``, are
+    slots it is the one reader of (the plan's finding): its program is
+    jitted with them donated, so ``dispatch`` clears those slots after
+    the call (``PTDEV_STATS["donated"]``, of ``["region_outputs"]``
+    arrays returned).
     """
     dev = devlane.device
     inflight: "collections.deque" = collections.deque()
@@ -214,15 +226,28 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                             h = staged[v]
                             h[1] += 1       # one more reader in flight
                             ev.append(h[0].payload)
+                    # the operands this region is the last reader of
+                    # lead, and the program keeps them: each is a slot
+                    # value the pool owns, so the slot retires here
+                    given = r["given"]
+                    nd = len(given)
                     _graph.trace_mark(_evr, i, _fs)
-                    outs, wbs_v = r["jitted"](tuple(ev))
+                    first, rest = r["jitted"](tuple(ev[:nd]),
+                                              tuple(ev[nd:]))
                     _graph.trace_mark(_evr, i, _fe)
-                    for s, v in zip(r["out_slots"], outs):
-                        slots[s] = v
-                    events = tuple(v for v in tuple(outs) + tuple(wbs_v)
+                    vals = first + rest
+                    for s, p in r["outs"]:
+                        slots[s] = vals[p]
+                    if nd:
+                        for s in given:
+                            slots[s] = None
+                        if ev[0].is_deleted():
+                            PTDEV_STATS["donated"] += nd
+                    PTDEV_STATS["region_outputs"] += len(vals)
+                    events = tuple(v for v in vals
                                    if hasattr(v, "is_ready"))
                     inflight.append((
-                        i, events, r["wb_pairs"], list(wbs_v),
+                        i, events, r["wb_pairs"], vals,
                         r["ext_mems"], r["ntasks"],
                         None if (_obs is None or r.get("cold")) else
                         (names[r["cls"]], bucket, "tpu_fused")))

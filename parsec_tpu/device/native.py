@@ -55,8 +55,13 @@ mca.register("device_native_poll_us", 100,
 #: distributed pools, DTD pools this PR); ``pools_fallback`` counts
 #: eligible pools that still declined (native module missing) — the
 #: silent-regression signal the ci.sh gate asserts is zero.
+#: ``donated`` / ``region_outputs`` (ISSUE 34): slot operands fused
+#: region programs were given for good, and the arrays those programs
+#: returned (write-backs included): what of a solve's output buffers
+#: reuses an input's.
 PTDEV_STATS = LaneStats(lanes_up=0, pools_engaged=0, tasks_engaged=0,
-                        pools_fallback=0, pools_ineligible=0)
+                        pools_fallback=0, pools_ineligible=0,
+                        donated=0, region_outputs=0)
 
 #: live lanes, for the process-wide ``ptdev.*`` counter samplers
 _lanes: "weakref.WeakSet[NativeDeviceLane]" = weakref.WeakSet()

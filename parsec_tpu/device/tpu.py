@@ -456,9 +456,11 @@ class TPUDevice(DeviceModule):
             # payload, a tile reset on the device), so the device copy takes
             # that array as it is: no call into the client, no byte moved.
             # The two copies then share one buffer, as device_put onto the
-            # same device left them too. Safe only while no program donates
-            # an operand (region programs are jitted without donate_argnums):
-            # a donated buffer would be taken from the other copy as well
+            # same device left them too. Safe because no program is ever
+            # given such a buffer for good: a table copy is the residency
+            # table's and a written-back array is its Data's; a fused PTG
+            # region donates only slot values its own pool wrote and
+            # nothing else reads (docs/device_lane.md, "Who owns what")
             self.adopted += 1
             return self._install(data, copy, arr, src.version, pin,
                                  moved=False)

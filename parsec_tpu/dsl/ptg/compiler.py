@@ -25,6 +25,7 @@ against task locals + user globals.
 from __future__ import annotations
 
 import ast
+import collections
 import textwrap
 import threading
 import time
@@ -158,6 +159,38 @@ def _index_expr(src: str):
     return _Expr(src)
 
 
+class _DonationRefused(Exception):
+    """Raised inside a donating region program's trace: its outputs cannot
+    take every donated buffer, shape by shape and dtype by dtype."""
+
+
+def _jit_region_program(shape: Dict[str, Any], fns, written_by_class, scopes):
+    """jit the program of a region shape (:func:`_mk_region_program`). A
+    shape that donates (ISSUE 34) is jitted with its first argument
+    donated. The plan finds an output for every donated operand by
+    structure and cannot see a tile's shape, so such a program counts in
+    its own trace: where a body returns its RW flow in another shape or
+    dtype and the outputs cannot take every donated buffer, the trace
+    raises before anything runs (no buffer is given up), and the shape
+    runs undonated from then on, where JAX would have warned of unusable
+    donations at each trace."""
+    import jax
+    if not shape["n_donated"]:
+        return jax.jit(_mk_region_program(shape, fns, written_by_class,
+                                          scopes))
+    fn = [jax.jit(_mk_region_program(shape, fns, written_by_class, scopes,
+                                     check=True), donate_argnums=(0,))]
+
+    def call(donated, kept):
+        try:
+            return fn[0](donated, kept)
+        except _DonationRefused:
+            fn[0] = jax.jit(_mk_region_program(shape, fns, written_by_class,
+                                               scopes))
+            return fn[0](donated, kept)
+    return call
+
+
 def _timed_region_program(fn, n_members: int):
     """Wrap a jitted region program so its FIRST call — the one paying
     the XLA trace+compile — feeds the cost model's ``__region_trace__``
@@ -168,20 +201,21 @@ def _timed_region_program(fn, n_members: int):
     boolean check."""
     state = [True]
 
-    def call(ev):
+    def call(donated, kept):
         if state[0]:
             state[0] = False
             t0 = time.perf_counter_ns()
-            out = fn(ev)
+            out = fn(donated, kept)
             from ...core.costmodel import model
             model.note_region_trace("cpu", n_members,
                                     time.perf_counter_ns() - t0)
             return out
-        return fn(ev)
+        return fn(donated, kept)
     return call
 
 
-def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes):
+def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes,
+                       check: bool = False):
     """The fused super-task's body (ISSUE 12): ONE traceable program
     replaying the region's members in serialization order (topo order of
     the member subgraph — a valid serialization, the DTD-capture
@@ -190,21 +224,29 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes):
     dependencies and re-fuses across task boundaries); member memory
     WRITES feed later members' memory READS of the same (collection,
     index) through a trace-time mem env, matching the per-task path's
-    release-edge ordering. Returns (externally-consumed slot values,
-    member write-back values in emission order). Pure w.r.t. its inputs
-    — safe to jit once and reuse across pool instantiations, and across
-    every region of one shape: ``rp`` is then the shape's canonical plan
-    (:func:`_region_shape`), whose slot and memory ids are the region's
-    own numbering. ``rp["name"]`` names the program, hence its XLA
-    module (``jit_ptg_region_<classes>``); ``scopes[ci]`` is class
-    ``ci``'s name, the ``jax.named_scope`` of its members' bodies."""
+    release-edge ordering. Takes the external operands as (donated, kept)
+    and returns the slot values ``rp["ret"]`` names, two tuples: the
+    externally-consumed slots then the members' write-backs in emission
+    order, or, for a shape that donates (ISSUE 34), first the outputs
+    the donated operands' buffers go to, in the operands' order (JAX
+    aliases the i-th donated argument to the i-th output of its shape
+    and dtype), then the rest. ``check``: refuse the trace where the
+    outputs cannot take every donated buffer, per shape and dtype
+    (:func:`_jit_region_program`). Pure
+    w.r.t. its inputs — safe to jit once and reuse across pool
+    instantiations, and across every region of one shape: ``rp`` is then
+    the shape's canonical plan (:func:`_region_shape`), whose slot and
+    memory ids are the region's own numbering. ``rp["name"]`` names the
+    program, hence its XLA module (``jit_ptg_region_<classes>``);
+    ``scopes[ci]`` is class ``ci``'s name, the ``jax.named_scope`` of its
+    members' bodies."""
     import jax
-    steps, out_slots = rp["steps"], rp["out_slots"]
+    steps, (ret_a, ret_b) = rp["steps"], rp["ret"]
 
-    def region_program(ext_vals):
+    def region_program(donated, kept):
+        ext_vals = donated + kept
         env: Dict[int, Any] = {}
         menv: Dict[Tuple, Any] = {}
-        wb_vals: List[Any] = []
         for ci, key, srcs, base, nd, wbs in steps:
             vals: List[Any] = []
             for kk, v in srcs:
@@ -228,14 +270,23 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes):
                 env[base + dj] = vals[dj]
             for dj, mk in wbs:
                 menv[mk] = vals[dj]
-                wb_vals.append(vals[dj])
-        return (tuple(env[s] for s in out_slots), tuple(wb_vals))
+        out = (tuple(env[s] for s in ret_a), tuple(env[s] for s in ret_b))
+        if check:
+            # what is donated is capped at what the outputs can take, per
+            # shape and dtype: JAX warns of a donated buffer it cannot use
+            room = collections.Counter(
+                (v.shape, v.dtype) for v in out[0] + out[1])
+            room.subtract((d.shape, d.dtype) for d in donated)
+            if min(room.values()) < 0:
+                raise _DonationRefused()
+        return out
     if rp.get("name"):
         region_program.__name__ = region_program.__qualname__ = rp["name"]
     return region_program
 
 
-def _region_shape(kind: str, steps, out_slots, reads, class_names):
+def _region_shape(kind: str, steps, out_slots, reads, class_names,
+                  donated=()):
     """The canonical plan of a region (ISSUE 29): its steps with slot
     and memory ids renumbered by first appearance, each member's parameter
     tuple cut to the positions its body names (``reads[ci]``; the others
@@ -243,10 +294,19 @@ def _region_shape(kind: str, steps, out_slots, reads, class_names):
     when their canonical plans are equal, so the plan, a nest of tuples,
     is its own signature and the executable cache's key; external
     operands, externally-consumed outputs and write-backs keep their
-    positions, so a region hands the shared program its own lists."""
+    positions, so a region hands the shared program its own lists.
+
+    ``donated`` (ISSUE 34): per leading external operand the program is
+    given for good, the slot its update chain ends in. Those lead what
+    the program returns, so what is donated, and to which output, is
+    part of the shape; one that donates nothing keeps the signature it
+    always had. ``ret`` is what the program returns, as two
+    tuples of canonical slots; ``out_pos`` and ``wb_pos`` say where in
+    them (flattened) each of ``out_slots`` and each write-back lies."""
     slot_of: Dict[int, int] = {}
     mem_of: Dict[Tuple, int] = {}
     canon: List[Tuple] = []
+    wb_slots: List[int] = []
     for ci, key, srcs, base, nd, wbs in steps:
         csrcs = tuple(
             (kk, slot_of[v]) if kk == "int" else
@@ -257,12 +317,45 @@ def _region_shape(kind: str, steps, out_slots, reads, class_names):
             slot_of[base + dj] = cbase + dj
         cwbs = tuple((dj, mem_of.setdefault(mk, len(mem_of)))
                      for dj, mk in wbs)
+        wb_slots.extend(cbase + dj for dj, _mk in wbs)
         ckey = tuple(v if i in reads[ci] else 0 for i, v in enumerate(key))
         canon.append((ci, ckey, csrcs, cbase, nd, cwbs))
     steps, outs = tuple(canon), tuple(slot_of[s] for s in out_slots)
     names = dict.fromkeys(class_names[step[0]] for step in steps)
-    return {"sig": (kind, steps, outs), "steps": steps, "out_slots": outs,
-            "name": "ptg_region_" + "_".join(names)}
+    shape = {"steps": steps, "n_donated": len(donated),
+             "name": "ptg_region_" + "_".join(names)}
+    if not donated:
+        shape["sig"] = (kind, steps, outs)
+        shape["ret"] = (outs, tuple(wb_slots))
+        shape["out_pos"] = list(range(len(outs)))
+        shape["wb_pos"] = list(range(len(outs), len(outs) + len(wb_slots)))
+        return shape
+    # the chains' ends first, each serving the externally-consumed slot
+    # it is, or else the (first) write-back of it; then the others. Turned
+    # by one against the operands: operand i's buffer takes the end of
+    # chain i + 1. Paired with its own chain's end, every member of a chain
+    # would write the tile it reads, and the TPU compiler's fusions that
+    # update in place are three times the code (a 128-member program's
+    # executable 95 MB for 31, 1.6x the compile, +0.5 s a load and 2.3 GB
+    # more of the chip held by a pool's programs; PERF.md sections 5-6),
+    # for a kernel 6 % faster on a chip that waits for the host. Turned,
+    # XLA orders the members so that each buffer is read before it is
+    # rewritten and copies one tile a program.
+    first = tuple(slot_of[s] for s in donated[1:] + donated[:1])
+    free = {s: i for i, s in enumerate(first)}
+    rest: List[int] = []
+
+    def place(s):
+        i = free.pop(s, None)
+        if i is None:
+            i = len(first) + len(rest)
+            rest.append(s)
+        return i
+    shape["out_pos"] = [place(s) for s in outs]
+    shape["wb_pos"] = [place(s) for s in wb_slots]
+    shape["ret"] = (first, tuple(rest))
+    shape["sig"] = (kind, steps, outs, shape["ret"])
+    return shape
 
 
 class PTGTaskpool(Taskpool):
@@ -1829,6 +1922,19 @@ class PTGTaskpool(Taskpool):
             reads.append(frozenset(
                 i for i, p in enumerate(tc._ptg_spec.params)
                 if named is None or p in named))
+        # the flows a member's body writes, and the slots a region program
+        # of this pool wrote on the device: what the pool owns (ISSUE 34)
+        body_writes = [frozenset(w) if fn is not None else frozenset()
+                       for fn, w in zip(*self._ptexec_class_fns(classes,
+                                                                data))]
+        wb_slots = {slot_base[tid] + dj
+                    for tid, dj, _dcn, _idx in data["writebacks"]}
+
+        def owned(r):
+            p = task_of_slot[r]
+            return (reg_of[p] >= 0 and kind[p] == "dev"
+                    and r - slot_base[p] in body_writes[cls_of[p]]
+                    and r not in wb_slots)
         shapes: List[Dict[str, Any]] = []
         shape_ix: Dict[Tuple, int] = {}
         rplans: List[Dict[str, Any]] = []
@@ -1847,6 +1953,9 @@ class PTGTaskpool(Taskpool):
             produced: set = set()
             memw: set = set()
             wb_keys: List[Tuple] = []
+            # slot -> the member flows that read it, as (class, flow
+            # position, the flow's own slot)
+            readers: Dict[int, List[Tuple]] = {}
             for m in members:
                 ci = cls_of[m]
                 b = slot_base[m]
@@ -1859,6 +1968,7 @@ class PTGTaskpool(Taskpool):
                     elif r >= 0:
                         srcs.append(("int", r) if r in produced
                                     else ("ext", eix(("slot", r))))
+                        readers.setdefault(r, []).append((ci, dj, b + dj))
                     else:
                         mi = -2 - r
                         mk = mem_reads[mi]
@@ -1876,8 +1986,25 @@ class PTGTaskpool(Taskpool):
             outs = [slot_base[m] + dj for m in members
                     for dj in range(ndflows[cls_of[m]])
                     if slot_uses2[slot_base[m] + dj] > 0]
+            donated: List[Tuple[int, int]] = []
+            if kind[members[0]] == "dev":
+                donated = self._ptexec_donations(
+                    ext, readers, slot_uses2, wb_slots, owned, body_writes,
+                    empty_body)
+            if donated:
+                # the donated operands lead, in the order of their outputs
+                given = {j for j, _end in donated}
+                order = [j for j, _end in donated] + [
+                    j for j in range(len(ext)) if j not in given]
+                at = {j: i for i, j in enumerate(order)}
+                ext = [ext[j] for j in order]
+                steps = [(ci, key, tuple(
+                    (kk, at[v]) if kk == "ext" else (kk, v)
+                    for kk, v in srcs), b, nd_, wbs)
+                    for ci, key, srcs, b, nd_, wbs in steps]
             shape = _region_shape(kind[members[0]], steps, outs, reads,
-                                  class_names)
+                                  class_names,
+                                  [end for _j, end in donated])
             si = shape_ix.get(shape["sig"])
             if si is None:
                 si = shape_ix[shape["sig"]] = len(shapes)
@@ -1923,6 +2050,66 @@ class PTGTaskpool(Taskpool):
                 "n_mixed": sum(
                     len({cls_of[m] for m in members}) > 1
                     for members in regions)}
+
+    @staticmethod
+    def _ptexec_donations(ext, readers, slot_uses, wb_slots, owned,
+                          body_writes, empty_body) -> List[Tuple[int, int]]:
+        """The external operands a device region is the last reader of
+        (ISSUE 34), each with the slot of the output its update chain ends
+        in: ``(position in ext, slot)`` pairs, in ``ext``'s order. The
+        region's program is given those operands for good.
+
+        A slot operand ``r`` qualifies when the pool owns its value (a
+        member of a device region wrote it in its body and nobody wrote
+        it back to memory: ``owned``), every use of it is this region's,
+        and exactly one member flow reads the array, one its body also
+        writes. The array goes by every slot that forwards it (a flow the
+        body does not write, a class with no body): a read, an outside
+        reader or a write-back under any of those names refuses it. A
+        memory operand is the residency table's, never the pool's. The
+        chain then follows the one writer of each value, under whatever
+        name, to the first that leaves the region (an externally-consumed
+        slot or a write-back): an output of the operand's shape, so every
+        donated buffer has one to go to (:func:`_region_shape` says which).
+        A chain that ends inside the region donates nothing: its operand
+        would have no output to become. The plan is single-rank
+        (``_ptexec_prepare``), so no slot here has a remote reader."""
+        def leaves(s):
+            return slot_uses[s] > 0 or s in wb_slots
+
+        def array_of(s):
+            # every slot the array in ``s`` goes by inside the region, and
+            # per member flow whose body reads it the slot that flow
+            # writes (None: it only reads)
+            names, reads = [s], []
+            for nm in names:
+                for ci, dj, own in readers.get(nm, ()):
+                    if dj in body_writes[ci]:
+                        reads.append(own)
+                    else:
+                        names.append(own)
+                        if not empty_body[ci]:
+                            reads.append(None)
+            return names, reads
+
+        out: List[Tuple[int, int]] = []
+        for j, (kk, r) in enumerate(ext):
+            if kk != "slot" or not owned(r) \
+                    or slot_uses[r] != len(readers[r]):
+                continue
+            names, reads = array_of(r)
+            if len(reads) != 1 or any(leaves(nm) for nm in names[1:]):
+                continue
+            end = reads[0]
+            while end is not None:
+                names, reads = array_of(end)
+                gone = [nm for nm in names if leaves(nm)]
+                if gone:
+                    out.append((j, gone[0]))
+                    break
+                nxt = [own for own in reads if own is not None]
+                end = nxt[0] if len(nxt) == 1 else None
+        return out
 
     @staticmethod
     def _ptexec_pack(regions: List[List[int]], kind, flat,
@@ -2036,7 +2223,9 @@ class PTGTaskpool(Taskpool):
                 else:
                     copy = mem_datas[v].newest_copy()
                     ev.append(None if copy is None else copy.payload)
-            outs, wbs = jitted(tuple(ev))
+            # a host region donates nothing, so its program returns its
+            # outputs and write-backs in the plan's own order
+            outs, wbs = jitted((), tuple(ev))
             for s, v in zip(out_slots, outs):
                 if v is None:
                     raise RuntimeError(
@@ -2060,7 +2249,6 @@ class PTGTaskpool(Taskpool):
         plan — regions of one shape share one program, and a second
         instantiation builds, traces and loads nothing), and the
         region-aware dispatch callbacks."""
-        import jax
         data = flat["data"]
         graph = mod.Graph(plan["goals"], plan["off"], plan["succs"],
                           plan["prio"], plan["in_off"], plan["in_slots"],
@@ -2089,8 +2277,8 @@ class PTGTaskpool(Taskpool):
             jitted, hit = cache.get_or_build(
                 None if rkey is None else (rkey, shape["sig"]),
                 lambda shape=shape: _timed_region_program(
-                    jax.jit(_mk_region_program(shape, fns,
-                                               written_by_class, scopes)),
+                    _jit_region_program(shape, fns, written_by_class,
+                                        scopes),
                     len(shape["steps"])))
             if not hit:
                 PTEXEC_STATS["region_programs"] += 1
@@ -2105,10 +2293,14 @@ class PTGTaskpool(Taskpool):
             wb_datas = self._ptexec_datas(rp["wb_keys"])
             cid = plan["rcid"][ri]
             if rp["kind"] == "dev":
+                shape = plan["shapes"][rp["shape"]]
                 dev_regions[cid] = {
                     "ext": rp["ext"], "ext_mems": rp["ext_mems"],
-                    "out_slots": rp["out_slots"], "jitted": jitted,
-                    "wb_pairs": list(enumerate(wb_datas)),
+                    "given": [s for _kk, s in
+                              rp["ext"][:shape["n_donated"]]],
+                    "outs": list(zip(rp["out_slots"], shape["out_pos"])),
+                    "jitted": jitted,
+                    "wb_pairs": list(zip(shape["wb_pos"], wb_datas)),
                     "ntasks": len(rp["members"]),
                     "cls": data["cls_of"][rp["members"][0]],
                     "cold": not hit}

@@ -823,3 +823,128 @@ def test_exec_cache_lru_eviction_counted():
     v, hit = c.get_or_build(None, lambda: "fresh")
     assert v == "fresh" and not hit
     assert stats["cache_misses"] == 4
+
+
+# ------------------------------------------------ donation (ISSUE 34)
+
+#: three classes: W writes its flow, R reads it (and so forwards it), E has
+#: no body (forwards it unread)
+_W, _R, _E = 0, 1, 2
+
+
+@pytest.mark.parametrize("readers, outside, written_back, want", [
+    # the one reader updates it, and the update leaves the region
+    ({10: [(_W, 0, 30)]}, {30}, (), [(0, 30)]),
+    # ... through a class with no body: the array under another name
+    ({10: [(_E, 0, 20)], 20: [(_W, 0, 30)]}, {30}, (), [(0, 30)]),
+    # a forwarded slot counts with the array it aliases: R read it too
+    ({10: [(_R, 0, 20)], 20: [(_W, 0, 30)]}, {30}, (), []),
+    # ... it is read outside the region under its other name
+    ({10: [(_E, 0, 20)], 20: [(_W, 0, 30)]}, {20, 30}, (), []),
+    # ... it is written back to memory under its other name
+    ({10: [(_E, 0, 20)], 20: [(_W, 0, 30)]}, {30}, (20,), []),
+    # two flows of one member read it
+    ({10: [(_W, 0, 30), (_W, 1, 31)]}, {30, 31}, (), []),
+    # the only reader does not write it: no output it could become
+    ({10: [(_R, 0, 20)]}, (), (), []),
+    # the chain runs on inside the region, past a forwarder, to a write-back
+    ({10: [(_W, 0, 30)], 30: [(_W, 0, 40), (_R, 1, 41)],
+      40: [(_E, 0, 50)]}, (), (50,), [(0, 50)]),
+    # ... and ends inside it: nothing leaves, nothing is donated
+    ({10: [(_W, 0, 30)], 30: [(_R, 0, 40)]}, (), (), []),
+])
+def test_which_slot_operands_a_region_is_given(readers, outside,
+                                               written_back, want):
+    """``_ptexec_donations`` on plain data: one slot operand (10) of a
+    device region, its readers inside the region as (class, flow, the
+    flow's own slot), the slots read outside it and those written back."""
+    from parsec_tpu.dsl.ptg.compiler import PTGTaskpool
+    uses = {s: 0 for s in range(60)}
+    uses[10] = len(readers[10])
+    for s in outside:
+        uses[s] = 1
+    got = PTGTaskpool._ptexec_donations(
+        [("slot", 10)], readers, uses, set(written_back), lambda r: True,
+        [frozenset((0,)), frozenset(), frozenset()], [False, False, True])
+    assert got == want
+    assert PTGTaskpool._ptexec_donations(      # not the pool's to give
+        [("slot", 10)], readers, uses, set(written_back), lambda r: False,
+        [frozenset((0,)), frozenset(), frozenset()], [False, False, True]) \
+        == []
+
+
+@pytest.mark.parametrize("bound, donated", [(2, [0] * 6), (3, [0, 1, 0, 1]),
+                                            (5, [0, 1, 0])])
+def test_a_forwarded_slot_is_donated_with_the_array_or_not_at_all(bound,
+                                                                  donated):
+    """S(k) adds one, F(k) has no body and hands S(k)'s tile to S(k+1):
+    one array under two names. A region that reads S's slot through F and
+    updates it once takes it (bounds 3 and 5); a region that reads F's slot
+    is handed a value no body of the pool wrote, and keeps its hands off
+    (every region at bound 2, every other one at 3)."""
+    if native_mod.load_ptdev() is None:
+        pytest.skip("native _ptdev unavailable")
+    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.device.native import PTDEV_STATS
+    src = ("%global N\n%global descX\n"
+           "S(k)\n  k = 0 .. N-1\n  : descX(0, 0)\n"
+           "  RW X <- (k == 0) ? descX(0, 0) : V F(k-1)\n       -> V F(k)\n"
+           "BODY [type=TPU]\n  X = X + 1.0\nEND\n"
+           "F(k)\n  k = 0 .. N-1\n  : descX(0, 0)\n  RW V <- X S(k)\n"
+           "       -> (k < N-1) ? X S(k+1) : descX(0, 0)\n"
+           "BODY [type=TPU]\n  pass\nEND\n")
+    mca.set("device_tpu_over_cpu", True)
+    mca.set("region_fusion_max", bound)
+    ctx = pt.Context(nb_cores=1)
+    try:
+        X = TiledMatrix("fwX", 8, 8, 8, 8)
+        X.fill(lambda m, n: np.zeros((8, 8), np.float32))
+        prog = compile_ptg(src, "fwd")
+        d0 = PTDEV_STATS.snapshot()
+        tp = prog.instantiate(ctx, globals={"N": 6},
+                              collections={"descX": X})
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=120)
+        assert tp.completed and ctx._ptdev.failed() is None
+        (ent,) = prog._ptexec_cache.values()
+        plan = ent["fusion"]
+        assert [plan["shapes"][r["shape"]]["n_donated"]
+                for r in plan["regions"]] == donated
+        dd = PTDEV_STATS.delta(d0)
+        assert dd["donated"] == sum(donated)
+        assert dd["region_outputs"] == len(donated)
+        np.testing.assert_array_equal(
+            np.asarray(X.data_of(0, 0).newest_copy().payload), 6.0)
+    finally:
+        ctx.fini()
+        mca.params.unset("region_fusion_max")
+        mca.params.unset("device_tpu_over_cpu")
+
+
+def test_a_donated_buffer_takes_the_next_chains_end():
+    """``_region_shape`` on plain data: three one-member chains, each
+    updating the operand it is given, the third also written back. The
+    chain ends lead what the program returns, turned by one against the
+    operands (JAX aliases the i-th donated argument to the i-th output),
+    so no member writes the tile it reads; ``out_pos`` / ``wb_pos`` say
+    where each slot and write-back went. Without donations the program
+    returns the slots, then the write-backs, under the signature it always
+    had."""
+    from parsec_tpu.dsl.ptg.compiler import _region_shape
+    steps = [(0, (k,), (("ext", k),), 10 + k, 1,
+              ((0, ("descA", (k,))),) if k == 2 else ()) for k in range(3)]
+    reads, names = [frozenset()], ["S"]
+    plain = _region_shape("dev", steps, [10, 11, 12], reads, names)
+    assert plain["ret"] == ((0, 1, 2), (2,)) and plain["n_donated"] == 0
+    assert (plain["out_pos"], plain["wb_pos"]) == ([0, 1, 2], [3])
+    assert plain["sig"] == ("dev", plain["steps"], (0, 1, 2))
+    given = _region_shape("dev", steps, [10, 11, 12], reads, names,
+                          [10, 11, 12])
+    assert given["n_donated"] == 3 and given["steps"] == plain["steps"]
+    assert given["ret"] == ((1, 2, 0), (2,))
+    assert (given["out_pos"], given["wb_pos"]) == ([2, 0, 1], [3])
+    assert given["sig"] != plain["sig"] and given["name"] == "ptg_region_S"
+    # a chain that ends in a write-back alone: the write-back leads
+    only_wb = _region_shape("dev", steps, [10, 11], reads, names, [10, 12])
+    assert only_wb["ret"] == ((2, 0), (1,))
+    assert (only_wb["out_pos"], only_wb["wb_pos"]) == ([1, 2], [0])

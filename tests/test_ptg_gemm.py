@@ -366,3 +366,63 @@ def test_the_new_histograms_are_registered_and_collide_with_nothing():
     from parsec_tpu.device.native import COH_COUNTER_KEYS, DEV_COUNTER_KEYS
     taken = set(DEV_COUNTER_KEYS) | set(COH_COUNTER_KEYS) | set(PTDEV_STATS)
     assert not any(k.startswith("hist") for k in taken)
+
+
+def _program_as_it_was(shape, fns, written):
+    """A region program as PR 33 built it: one tuple of operands in, the
+    externally-consumed slots and the write-backs out."""
+    def region_program(ext_vals):
+        env, wb_vals = {}, []
+        for ci, key, srcs, base, nd, wbs in shape["steps"]:
+            vals = [env[v] if kk == "int" else ext_vals[v] for kk, v in srcs]
+            outs = fns[ci](*key, *vals)
+            for oj, dj in enumerate(written[ci]):
+                vals[dj] = outs[oj]
+            for dj in range(nd):
+                env[base + dj] = vals[dj]
+            wb_vals.extend(vals[dj] for dj, _mk in wbs)
+        return (tuple(env[s] for s in shape["sig"][2]), tuple(wb_vals))
+    region_program.__name__ = region_program.__qualname__ = shape["name"]
+    return region_program
+
+
+def test_the_k_chain_plan_donates_nothing_and_keeps_its_program(
+        dctx, monkeypatch):
+    """The control of ISSUE 34: every operand of ex06's region programs is
+    a memory read and every result a write-back, so the plan finds nothing
+    to donate: the shape's signature is (kind, steps, outs) as it was, and
+    the program lowers to the module PR 33's builder gave, text for text,
+    so the persistent compile cache finds the executable it already has."""
+    import jax
+    import jax.numpy as jnp
+    from parsec_tpu.dsl.ptg import compiler as C
+
+    built = []
+    make = C._mk_region_program
+    monkeypatch.setattr(
+        C, "_mk_region_program",
+        lambda *a, **k: built.append((a, k, make(*a, **k))) or built[-1][2])
+    a, b, mats = _operands(4, 4, 4)
+    prog = compile_ptg(ex06_gemm_ptg.SRC, "gemm")
+    d0 = PTDEV_STATS.snapshot()
+    _solve(dctx, prog, mats, 4, 4, 4)
+    dd = PTDEV_STATS.delta(d0)
+    assert dd["donated"] == 0 and dd["region_outputs"] == 16
+    (ent,) = prog._ptexec_cache.values()
+    plan = ent["fusion"]
+    (shape,) = plan["shapes"]
+    (region,) = plan["regions"]
+    assert shape["n_donated"] == 0
+    assert {k for k, _v in region["ext"]} == {"mem"}
+    kind, steps, outs = shape["sig"]
+    assert kind == "dev" and steps == shape["steps"] and outs == ()
+    # the C flow of the last of each chain's four tasks (three flows a task)
+    assert shape["ret"] == ((), tuple(range(11, 192, 12)))
+    (((_shape, fns, written, _scopes), kwargs, program),) = built
+    assert kwargs == {}             # no donation, so nothing to check
+    tile = jax.ShapeDtypeStruct((TS, TS), jnp.float32)
+    ext = (tile,) * len(region["ext"])
+    now = jax.jit(program).lower((), ext).as_text()
+    was = jax.jit(_program_as_it_was(shape, fns, written)).lower(ext).as_text()
+    assert "jit_ptg_region_GEMM" in now and now == was
+    assert "tf.aliasing_output" not in now and "buffer_donor" not in now

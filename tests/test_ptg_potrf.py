@@ -3,8 +3,12 @@ four classes over a triangular task space with ranges in DPLASMA's
 declaration order, bodies calling the program's tile functions by name,
 through ``ptexec`` + region fusion + ``ptdev`` (the device module over a
 host device). Against the plain reference (``np.linalg.cholesky`` in
-float64 on the host) and the DTD twin ``insert_potrf_tasks``. Counts and
-results only: no test here reads a clock."""
+float64 on the host) and the DTD twin ``insert_potrf_tasks``. A fused
+region donates the slot operands it is the last reader of (ISSUE 34): what
+the plan gives away, what it never does, and what a solve leaves behind.
+Counts and results only: no test here reads a clock."""
+
+import warnings
 
 import numpy as np
 import pytest
@@ -15,6 +19,7 @@ from parsec_tpu.data.matrix import TiledMatrix
 from parsec_tpu.device.native import PTDEV_STATS
 from parsec_tpu.dsl.dtd import DTDTaskpool
 from parsec_tpu.dsl.fusion import CAPTURE_CACHE_STATS
+from parsec_tpu.dsl.ptg import compiler as C
 from parsec_tpu.dsl.ptg.compiler import PTEXEC_STATS, compile_ptg
 from parsec_tpu.ops import potrf as ops
 from parsec_tpu.utils import hist as H
@@ -70,6 +75,27 @@ def _factor(ctx, A, prog=None):
     return np.tril(np.asarray(A.to_dense()))
 
 
+def _plan_of(prog, nt):
+    """The fusion plan ``prog`` holds for the pool of ``nt`` x ``nt`` tiles."""
+    plan, = [e["fusion"] for e in prog._ptexec_cache.values()
+             if e["fusion"] is not None and e["fusion"]["n_fused"] == ntasks(nt)]
+    return plan
+
+
+def _donated(plan):
+    """Per region of ``plan``, the operands its program is given for good
+    (a property of the region's shape)."""
+    return [plan["shapes"][r["shape"]]["n_donated"] for r in plan["regions"]]
+
+
+def _donated_and_returned(plan):
+    """(operands the plan's device regions donate, arrays their programs
+    return) a solve."""
+    return (sum(_donated(plan)),
+            sum(len(r["out_slots"]) + len(r["wb_keys"])
+                for r in plan["regions"]))
+
+
 def _reference(a):
     """The plain reference: float64 Cholesky on the host."""
     return np.linalg.cholesky(a.astype(np.float64))
@@ -99,6 +125,12 @@ def test_the_jdf_against_the_reference_and_the_dtd_twin(dctx, nt):
     if nt == 12:
         assert dx["fused_regions"] == dx["mixed_regions"] == 3
     assert counters.read("ptdev.cb_errors") == 0
+    # what the lane gave away and got back is what the plan said it would
+    want = _donated_and_returned(_plan_of(ops.potrf_program(), nt)) \
+        if nt > 1 else (0, 0)
+    assert (dd["donated"], dd["region_outputs"]) == want
+    # one region returns its write-backs alone; three hand 100 slots on
+    assert want == {12: (83, 178)}.get(nt, (0, nt * (nt + 1) // 2 * (nt > 1)))
     _assert_factor(got, a)
     _a, T = _matrix(nt, seed=nt)
     tp = DTDTaskpool(dctx, f"twin{nt}")
@@ -141,10 +173,12 @@ def test_a_second_instantiation_builds_nothing_with_callable_globals(dctx):
     for solve in range(3):
         a, A = _matrix(12, seed=solve)
         x0, c0 = PTEXEC_STATS.snapshot(), CAPTURE_CACHE_STATS.snapshot()
+        d0 = PTDEV_STATS["donated"]
         _assert_factor(_factor(dctx, A, prog), a)
         dx, dc = PTEXEC_STATS.delta(x0), CAPTURE_CACHE_STATS.delta(c0)
         assert dx["fused_regions"] == 3
         assert dx["region_programs"] == (0 if solve else 3)
+        assert PTDEV_STATS["donated"] - d0 == 83    # every solve alike
         assert dc["cache_hits"] == (3 if solve else 0)
         assert dc["cache_evictions"] == 0
     assert len(prog._ptexec_cache) == 1
@@ -197,7 +231,7 @@ def test_a_region_program_names_each_members_class(dctx, monkeypatch):
     plan, = [e["fusion"] for e in prog._ptexec_cache.values()]
     tile = jax.ShapeDtypeStruct((TS, TS), jnp.float32)
     text = jax.jit(built[0]).lower(
-        (tile,) * len(plan["regions"][0]["ext"])).as_text(debug_info=True)
+        (), (tile,) * len(plan["regions"][0]["ext"])).as_text(debug_info=True)
     assert "module @jit_ptg_region_POTRF_TRSM_SYRK_GEMM" in text
     for name in ("POTRF", "TRSM", "SYRK", "GEMM"):
         assert f"ptg_region_POTRF_TRSM_SYRK_GEMM)/{name}/" in text
@@ -249,3 +283,230 @@ def test_the_new_histograms_record_nothing_with_the_spans_off(dctx):
     for key in ("ptdev.inflight", "ptexec.region_tasks",
                 "ptdev.stage_in_ns"):
         assert n1.get(key, 0) == n0.get(key, 0)
+
+
+# ------------------------------------------- donation (ISSUE 34): the plan
+
+class _PlanOnly(Exception):
+    pass
+
+
+def _plan_without_running(monkeypatch, ctx, prog, nt, A):
+    """Instantiate and lower as far as the fusion plan: nothing is bound
+    to a lane, nothing traced, nothing compiled."""
+    def stop(*_a, **_k):
+        raise _PlanOnly
+    monkeypatch.setattr(C.PTGTaskpool, "_ptexec_lane_fused", stop)
+    tp = prog.instantiate(ctx, globals={"NT": nt, **TILE_FNS},
+                          collections={"descA": A})
+    with pytest.raises(_PlanOnly):
+        tp._ptexec_prepare(set())
+    monkeypatch.undo()
+    return _plan_of(prog, nt)
+
+
+def test_the_benchmark_cells_plan_donates_every_update_chain(dctx,
+                                                             monkeypatch):
+    """``ptg_potrf.ts512``'s pool (NT = 32, the plan only): of the 6,562
+    operands its 47 region programs take, 5,158 are slots the region is
+    the last reader of, each the head of an update chain of the JDF (the
+    ``RW C`` of GEMM from the GEMM before it, the ``RW T`` of SYRK, TRSM's
+    ``C``, POTRF's ``T``); 968 of the 6,130 arrays a solve's programs
+    return are then new buffers. The first four regions hold every memory
+    read of the pool's head and donate nothing; a memory operand, a slot
+    with a write-back or with a second reader is never among the donated,
+    and every donated operand has an output of its own to become."""
+    nt = 32
+    A = TiledMatrix("A32", nt * TS, nt * TS, TS, TS)
+    prog = compile_ptg(ops.POTRF_JDF, "potrf")
+    plan = _plan_without_running(monkeypatch, dctx, prog, nt, A)
+    regs = plan["regions"]
+    assert len(regs) == 47 and {r["kind"] for r in regs} == {"dev"}
+    assert sum(len(r["ext"]) for r in regs) == 6562
+    assert sum(len(r["ext_mems"]) for r in regs) == 528
+    assert _donated_and_returned(plan) == (5158, 6130)
+    given = _donated(plan)
+    assert given[:5] == [0, 0, 0, 0, 112]
+    assert all(120 <= nd <= 128 for nd in given[5:42])
+    assert given[42:] == [105, 91, 66, 55, 28]
+    (ent,) = prog._ptexec_cache.values()
+    data = ent["flat"]["data"]
+    written_back = {data["slot_base"][tid] + dj
+                    for tid, dj, _dc, _ix in data["writebacks"]}
+    taken = set()
+    for r, nd in zip(regs, given):
+        shape = plan["shapes"][r["shape"]]
+        lead = r["ext"][:nd]
+        assert all(kind == "slot" for kind, _v in lead)
+        assert not any(kind == "slot" and plan["slot_uses"][v] == 1
+                       and v not in written_back
+                       for kind, v in r["ext"][nd:]), \
+            "an operand with one reader and no write-back was kept"
+        for _kind, v in lead:
+            assert plan["slot_uses"][v] == 1 and v not in written_back
+            assert v not in taken
+            taken.add(v)
+        # each donated operand is paired with an output of its own, and
+        # those lead what the program returns
+        first, rest = shape["ret"]
+        if nd:
+            assert len(first) == len(set(first)) == nd
+        assert len(first) + len(rest) == \
+            len(r["out_slots"]) + len(r["wb_keys"])
+        assert sorted(shape["out_pos"] + shape["wb_pos"]) == \
+            list(range(len(first) + len(rest)))
+        # a shape that donates nothing keeps the key it always had
+        assert len(shape["sig"]) == (4 if nd else 3)
+    assert len(plan["shapes"]) == 47
+
+
+# small JDFs: a chain S(0) .. S(N-1) over one tile, cut into regions of two
+_HEAD = "%global N\n%global descX\n%global descY\n"
+
+
+def _chain(flow="", dep="", body="X = X + 1.0", where=" [type=TPU]",
+           more=""):
+    return (_HEAD + "S(k)\n  k = 0 .. N-1\n  : descX(0, 0)\n" + flow +
+            "  RW X <- (k == 0) ? descX(0, 0) : X S(k-1)\n"
+            "       -> (k < N-1) ? X S(k+1) : descX(0, 0)\n" + dep +
+            f"BODY{where}\n  {body}\nEND\n" + more)
+
+
+_READER = ("R(k)\n  k = 0 .. N-1\n  : descY(0, k)\n  READ V <- X S(k)\n"
+           "  RW W <- descY(0, k)\n       -> descY(0, k)\n"
+           "BODY [type=TPU]\n  W = W + V\nEND\n")
+
+
+def _run_chain(dctx, src, name, n=6):
+    X = TiledMatrix("X", TS, TS, TS, TS)
+    X.fill(lambda m, k: np.zeros((TS, TS), np.float32))
+    Y = TiledMatrix("Y", TS, n * TS, TS, TS)
+    Y.fill(lambda m, k: np.full((TS, TS), float(k), np.float32))
+    mca.set("region_fusion_max", 2)
+    try:
+        prog = compile_ptg(src, name)
+        d0 = PTDEV_STATS.snapshot()
+        with warnings.catch_warnings(record=True) as seen:
+            warnings.simplefilter("always")
+            tp = prog.instantiate(dctx, globals={"N": n},
+                                  collections={"descX": X, "descY": Y})
+            dctx.add_taskpool(tp)
+            dctx.wait(timeout=120)
+        assert tp.completed and counters.read("ptdev.cb_errors") == 0
+        assert not [w for w in seen if "onat" in str(w.message)], \
+            [str(w.message) for w in seen]
+    finally:
+        mca.params.unset("region_fusion_max")
+    (ent,) = prog._ptexec_cache.values()
+    x = np.asarray(X.data_of(0, 0).newest_copy().payload)
+    return ent["fusion"], PTDEV_STATS.delta(d0), x, Y
+
+
+@pytest.mark.parametrize("what", ["chain", "memory", "write-back",
+                                  "second reader", "host body"])
+def test_what_a_region_never_donates(dctx, what):
+    """The chain alone: each region after the first is the one reader of
+    the slot the region before it wrote, and takes it. Then the four
+    refusals: a memory operand beside it is the residency table's; a slot
+    also written back belongs to its ``Data`` as well; a slot with a
+    second reader is still needed; a region on the host donates nothing."""
+    src = {"chain": _chain(),
+           "memory": _chain(flow="  READ M <- descY(0, k)\n",
+                            body="X = X + M"),
+           "write-back": _chain(dep="       -> descY(0, k)\n"),
+           "second reader": _chain(dep="       -> V R(k)\n", more=_READER),
+           "host body": _chain(where="")}[what]
+    plan, dd, x, Y = _run_chain(dctx, src, "s-" + what.replace(" ", "-"))
+    regions = plan["regions"]
+    donates = what in ("chain", "memory")
+    assert _donated(plan)[:3] == ([0, 1, 1] if donates else [0, 0, 0])
+    assert dd["donated"] == (2 if donates else 0)
+    for r, nd in zip(regions, _donated(plan)):
+        assert all(k == "slot" for k, _v in r["ext"][:nd])
+    if what == "memory":
+        assert [len(r["ext_mems"]) for r in regions] == [3, 2, 2]
+        np.testing.assert_array_equal(x, np.full((TS, TS), 15.0))
+    else:
+        np.testing.assert_array_equal(x, np.full((TS, TS), 6.0))
+    if what == "write-back":
+        assert dd["region_outputs"] == 9     # 2 slots + 7 write-backs
+        for k in range(6):
+            np.testing.assert_array_equal(
+                np.asarray(Y.data_of(0, k).newest_copy().payload), k + 1.0)
+    if what == "second reader":
+        assert len(regions) == 6 and dd["region_outputs"] == 13
+        for k in range(6):      # R(k) read S(k)'s X, which S(k+1) read too
+            np.testing.assert_array_equal(
+                np.asarray(Y.data_of(0, k).newest_copy().payload),
+                2.0 * k + 1.0)
+    if what == "host body":
+        assert {r["kind"] for r in regions} == {"cpu"}
+        assert dd["region_outputs"] == 0
+
+
+def test_a_body_that_returns_another_shape_is_run_undonated(dctx):
+    """The plan pairs an operand with the output its chain ends in by
+    structure; the program's own trace sees the shapes. A body that
+    doubles its tile leaves no output a donated operand could become: the
+    shape runs undonated, without JAX's warning, and counts nothing."""
+    plan, dd, x, _Y = _run_chain(
+        dctx, _chain(body="X = jnp.concatenate([X, X + 1.0])"), "s-grows")
+    assert _donated(plan) == [0, 1, 1]          # the plan's offer
+    assert dd["donated"] == 0 and dd["region_outputs"] == 3
+    assert x.shape == (TS * 2 ** 6, TS) and x[-1, 0] == 6.0 and x[0, 0] == 0.0
+
+
+def test_after_a_solve_every_donated_operand_is_gone(dctx, monkeypatch):
+    """NT = 12: the 83 slot values the second and third regions take are
+    deleted arrays once their program has been called, no slot still holds
+    one of them when the pool is finalized, and what the slots do hold is
+    alive."""
+    taken, left = [], []
+    timed = C._timed_region_program
+
+    def spy(fn, n_members):
+        def call(donated, kept):
+            taken.extend(donated)
+            return fn(donated, kept)
+        return timed(call, n_members)
+    monkeypatch.setattr(C, "_timed_region_program", spy)
+    finalize = C.PTGTaskpool._ptexec_finalize
+
+    def snapshot(self, lane):
+        left.extend(v for v in lane["slots"] if v is not None)
+        return finalize(self, lane)
+    monkeypatch.setattr(C.PTGTaskpool, "_ptexec_finalize", snapshot)
+    prog = compile_ptg(ops.POTRF_JDF, "potrf")
+    a, A = _matrix(12, seed=5)
+    with warnings.catch_warnings(record=True) as seen:
+        warnings.simplefilter("always")
+        _assert_factor(_factor(dctx, A, prog), a)
+    assert not [w for w in seen if "onat" in str(w.message)]
+    assert len(taken) == 83 and all(v.is_deleted() for v in taken)
+    gone = {id(v) for v in taken}
+    assert left and not any(id(v) in gone or v.is_deleted() for v in left)
+    # 178 arrays came back, 83 of them in a donated operand's buffer: the
+    # slots hold the other 17 of the 100 handed on, and the last region's
+    assert len(left) == 100 - 83
+
+
+def test_the_benchmarks_reader_gives_the_share_or_nothing(monkeypatch):
+    """``chipbench/layers/donated_share.py`` reads the lane's two counts:
+    the cell's 5,158 of 6,130, 0 where nothing is donated, and nothing to
+    read where no region program ran or the program has no such counters
+    (the parent commit under this benchmark)."""
+    import importlib
+    import os
+    monkeypatch.syspath_prepend(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    reader = importlib.import_module("chipbench.layers.donated_share")
+    monkeypatch.setitem(PTDEV_STATS, "donated", 5158)
+    monkeypatch.setitem(PTDEV_STATS, "region_outputs", 6130)
+    assert reader.read(None) == pytest.approx(84.1436, abs=1e-4)
+    monkeypatch.setitem(PTDEV_STATS, "donated", 0)
+    assert reader.read(None) == 0.0
+    monkeypatch.setitem(PTDEV_STATS, "region_outputs", 0)
+    assert reader.read(None) is None
+    monkeypatch.delitem(PTDEV_STATS, "region_outputs")
+    monkeypatch.delitem(PTDEV_STATS, "donated")
+    assert reader.read(None) is None
