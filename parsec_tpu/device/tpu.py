@@ -31,6 +31,7 @@ XLA/PJRT execution model:
 from __future__ import annotations
 
 import collections
+import itertools
 import os
 import threading
 import weakref
@@ -42,7 +43,8 @@ from ..core.task import (DEV_TPU, FLOW_ACCESS_CTL, FLOW_ACCESS_WRITE,
                          HOOK_ASYNC, HOOK_DONE, Task)
 from ..data.data import COHERENCY_INVALID, COHERENCY_OWNED, COHERENCY_SHARED, Data, DataCopy
 from ..utils import mca, output
-from ..utils.xla_trace import DEV_POLL, DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT
+from ..utils.xla_trace import (DEV_POLL, DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT,
+                               DEV_WRITEBACK)
 from .device import DeviceModule
 
 #: the sizes a multi-task program comes in (capped by
@@ -55,6 +57,11 @@ GROUP_LADDER = (16, 8, 4, 2)
 #: is, about the host's cycle per task, so two in three singles pass and a
 #: longer streak would rarely form; a program behind a backlog never passes
 PACED_STREAK = 4
+#: dirty copies at the LRU's end whose D2H is under way before they are
+#: evicted (:meth:`TPUDevice._fetch_ahead_locked`): a burst of evictions (a
+#: DTD window refilling: ~57 k-chains' C tiles in the out-of-core GEMM) then
+#: finds its write-backs on the host already; 1 GiB of host memory at 16 MiB
+WRITEBACK_AHEAD = 64
 
 mca.register("device_tpu_max_bytes", 0,
              "HBM tile-heap budget in bytes (0 = 75% of the device's "
@@ -122,6 +129,9 @@ class TPUDevice(DeviceModule):
         self._inflight: Deque[List[TPUTask]] = collections.deque()
         self._inflight_tasks = 0
         self._pass = 0              # manager passes, for _observe
+        #: the last pass whose issue phase evicted: no program issued up to
+        #: it is judged complete (:meth:`_observe`)
+        self._evicted_pass = 0
         self._manager_lock = threading.Lock()  # the CAS mutex (device_gpu.c:3408)
         self._fifo_lock = threading.Lock()
         #: task class -> its programs judged complete in a row
@@ -140,6 +150,12 @@ class TPUDevice(DeviceModule):
         self._lru_segs: Dict[Any, Any] = {}    # key -> pt_zone segment
         self._resident_bytes = 0
         self.evictions = 0          # copies evicted (budget pressure stat)
+        #: of those, copies that left in OWNED state: the device had written
+        #: them since they were staged, so each owes the host a write-back
+        self.owned_evictions = 0
+        #: residency key -> id of the device array whose D2H was started
+        #: ahead of its eviction (an id only: no array is kept alive)
+        self._fetching: Dict[Any, int] = {}
         self.pinned_skips = 0       # eviction walks that skipped a pinned copy
         self._budget = mca.get("device_tpu_max_bytes", 0) or \
             int(_device_bytes_limit(jax_device) * 0.75)
@@ -244,8 +260,13 @@ class TPUDevice(DeviceModule):
             seg.free()
         data = copy.original
         wrote = False
+        if copy.coherency_state == COHERENCY_OWNED:
+            self.owned_evictions += 1
+        self._fetching.pop(key, None)
+        self._fetch_ahead_locked()  # before this victim's own fetch blocks
         if data is not None:
-            _evicted, wrote = data.evict_copy(self.device_index)
+            _evicted, wrote = data.evict_copy(self.device_index,
+                                              self._write_back)
         else:
             copy.coherency_state = COHERENCY_INVALID
             copy.payload = None
@@ -257,6 +278,48 @@ class TPUDevice(DeviceModule):
             self._ncoh.drop(key)
         self.evictions += 1
         self._trace_mem(-freed)
+
+    def _fetch_ahead_locked(self) -> None:
+        """Start the D2H of the dirty copies next in line for eviction
+        (heap lock held): where one copy leaves, more follow, and a
+        write-back begun only at its eviction is milliseconds of the
+        manager's own time (2.3 ms for 16 MiB on the v5e) with the chip's
+        queue running dry behind it. The fetch is the array's own
+        (``copy_to_host_async``: it waits for the producing program on the
+        device's transfer engine, not on this thread); the eviction stays
+        the one mechanism and finds the bytes on the host. A copy touched
+        again before it leaves costs one wasted transfer; its array is
+        replaced by the write that touches it, and the fetched bytes go
+        with it."""
+        ahead, fetching = 0, self._fetching
+        for key, copy in itertools.islice(self._lru.items(),
+                                          4 * WRITEBACK_AHEAD):
+            if copy.coherency_state != COHERENCY_OWNED or copy.readers > 0:
+                continue
+            arr = copy.payload
+            if fetching.get(key) != id(arr):
+                fetching[key] = id(arr)
+                start = getattr(arr, "copy_to_host_async", None)
+                if start is not None:
+                    start()
+            ahead += 1
+            if ahead == WRITEBACK_AHEAD:
+                break
+
+    def _write_back(self, payload: Any) -> np.ndarray:
+        """The D2H of an eviction's dirty branch (``Data.evict_copy`` calls
+        it under the data's lock): the fetch blocks until the bytes are on
+        the host (at once, where :meth:`_fetch_ahead_locked` started it in
+        time), so the span holds what the manager waited, one record a
+        tile."""
+        sp = self._spans
+        if sp is not None:
+            tok = sp.begin(DEV_WRITEBACK)
+        try:
+            return np.asarray(payload)
+        finally:
+            if sp is not None:
+                sp.end(tok, sp.writeback)
 
     def coh_stats(self) -> Optional[Dict[str, int]]:
         """The native coherency/residency counters, or None when the
@@ -297,7 +360,15 @@ class TPUDevice(DeviceModule):
         the chip sat idle through the call just made, and a call saved is
         time saved; a streak of those and the class is issued in groups.
         Not complete: the chip has work queued, waiting for companions
-        would only delay it, and the class goes back to a program a task."""
+        would only delay it, and the class goes back to a program a task.
+        A program found complete after a host cycle that evicted (in its
+        own pass or the judging one) is not judged: the cycle was long by
+        the victim walk and a write-back of milliseconds, which no saved
+        call shortens, and a group would pin its members' operands against
+        the evictor all at once; such a cycle also ends every streak (ISSUE
+        35: under memory pressure a class of 0.6-ms dots was taken as paced
+        at every burst of evictions, in four runs of six, and ran slower and
+        less evenly in groups of 16)."""
         gt.issued = 0
         if gt.batch_submit is None:
             return
@@ -350,6 +421,7 @@ class TPUDevice(DeviceModule):
             # kernel_push + kernel_exec phases (device_gpu.c:2746,2874)
             self._pass += 1
             issued = False
+            evicted = self.evictions
             max_inflight = mca.get("device_tpu_max_inflight", 64)
             while self._inflight_tasks < max_inflight:
                 with self._fifo_lock:
@@ -366,6 +438,10 @@ class TPUDevice(DeviceModule):
                     self._inflight_tasks += len(program)
                 self._inflight.extend(programs)
                 issued |= bool(programs)
+            if self.evictions != evicted:
+                # under memory pressure every class goes a program a task
+                self._evicted_pass = self._pass
+                self._paced.clear()
             # event polling + kernel_pop/epilog: poll each program's events
             # independently — inflight programs are mutually independent
             # (their deps only release at epilog), so one slow kernel must
@@ -388,7 +464,12 @@ class TPUDevice(DeviceModule):
                     all(a.is_ready() for a in head.out_arrays)
                 if issued and head.issued and \
                         (done or head.issued != self._pass):
-                    self._observe(head, done)
+                    if head.issued > self._evicted_pass or not done:
+                        self._observe(head, done)
+                    else:
+                        # the host's cycle since the program's call held an
+                        # eviction: complete by now says nothing of calls
+                        head.issued = 0
                 if not done:
                     still.append(program)
                     continue
@@ -846,6 +927,7 @@ class TPUDevice(DeviceModule):
             seg.free()
         self._lru_segs.clear()
         self._resident_bytes = 0
+        self._fetching.clear()
         self._pending.clear()
 
 

@@ -57,8 +57,10 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     # the per-task device path's spans (utils/xla_trace.py Spans), recorded
     # from Python into PyHistograms below
     # ``group_tasks`` is no time: one record per multi-task program, its size
+    # ``writeback_ns`` (ISSUE 35): the dirty branch of an eviction, one
+    # record a tile written back to the host
     "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns",
-               "group_tasks"),
+               "group_tasks", "writeback_ns"),
     "dtd": ("link_ns", "stall_ns"),
     # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
     # lanes, and the ptdev manager's dispatch / stage-in / poll / retire

@@ -14,7 +14,8 @@ Usage::
 or annotate spans manually through :class:`TaskAnnotator` (a PINS module).
 
 :class:`Spans` is the runtime's own instrumentation of the device paths:
-six named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``)
+seven named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``;
+``dev.writeback``, the dirty branch of an eviction, is both managers')
 and four on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
 instantiation, and the ``ptdev`` manager's dispatch, poll and retire),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
@@ -60,6 +61,7 @@ def xla_trace(logdir: Optional[str] = None) -> Iterator[None]:
 DTD_LINK, DTD_STALL = "dtd.link", "dtd.stall"
 DEV_SUBMIT, DEV_STAGE_IN = "dev.submit", "dev.stage_in"
 DEV_POLL, DEV_RETIRE = "dev.poll", "dev.retire"
+DEV_WRITEBACK = "dev.writeback"
 PTG_LOWER = "ptg.lower"
 PTDEV_DISPATCH = "ptdev.dispatch"
 PTDEV_POLL, PTDEV_RETIRE = "ptdev.poll", "ptdev.retire"
@@ -90,6 +92,7 @@ class Spans:
         self.poll = tpudev.cell("poll_ns")
         self.retire = tpudev.cell("retire_ns")
         self.group_tasks = tpudev.cell("group_tasks")
+        self.writeback = tpudev.cell("writeback_ns")
         self.link = dtd.cell("link_ns")
         self.stall = dtd.cell("stall_ns")
         self._ready = ready.cell("ready_wait_ns")

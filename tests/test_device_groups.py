@@ -171,6 +171,33 @@ def test_groups_form_by_observation_alone(dctx, monkeypatch):
     assert stats["batched_dispatches"] == dev.batched_dispatches
 
 
+@pytest.mark.parametrize("tiles_of_room, grouped", [(1, False), (64, True)])
+def test_a_cycle_that_evicts_judges_no_program(dctx, monkeypatch,
+                                               tiles_of_room, grouped):
+    """ISSUE 35: the same 64 tasks, each staging a tile of its own. Under a
+    budget of one tile every stage-in but the first evicts (and writes a
+    scaled tile back), so every program is found complete after a cycle
+    that evicted and none is judged: the class goes a program a task to the
+    end. With room for all 64 nothing evicts and the groups form as above."""
+    dev = _dev(dctx)
+    dev.set_budget(tiles_of_room * TS * TS * 4, unit=1024)
+    issued = _record_programs(dev, monkeypatch)
+    A = _column("EV", 64)
+    tp = DTDTaskpool(dctx, "evicting")
+    for m in range(64):
+        tp.insert_task(scale, (tp.tile_of(A, m, 0), RW))
+    tp.wait(); tp.close(); dctx.wait()
+    for m in range(64):
+        assert np.allclose(_tile(A, m), 3.0 * m)
+    sizes = [len(ids) for _name, ids in issued]
+    assert sum(sizes) == 64 == dev.executed_tasks
+    assert (max(sizes) > 1) is grouped
+    assert (dev.evictions > 0) is not grouped
+    if not grouped:
+        assert dev.batched_tasks == 0 and dev.owned_evictions > 0
+        assert dev.transfer_out_bytes == dev.owned_evictions * TS * TS * 4
+
+
 class _Late:
     """An output whose completion event fires at the ``after``-th poll."""
 
