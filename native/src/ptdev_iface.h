@@ -15,7 +15,9 @@
 //
 //   device -> engine  (PtDevRetireVtbl): the device manager thread
 //     observed a dispatched task's completion events (jax.Array
-//     is_ready, the cudaEventQuery of device_gpu.c:2593) and lands the
+//     is_ready, the cudaEventQuery of device_gpu.c:2593), or dispatched
+//     a task whose successors are all device tasks of this lane (they
+//     queue behind it on the device; device/lane_pool.py), and lands the
 //     completion straight into the engine's release walk — successor
 //     decrements, slot retires and ready pushes all run without the GIL,
 //     exactly like a local CPU retire (the kernel_epilog ->

@@ -1135,7 +1135,8 @@ PyObject *graph_comm_bind(PyObject *obj, PyObject *args) {
 
 // The GIL-free retire entry the ptdev manager thread calls through the
 // PtDevRetireVtbl capsule once a dispatched task's completion events
-// fired (its outputs already landed in the Python-owned slots): run the
+// fired, or at its dispatch where every successor is a device task of the
+// same lane (its outputs already landed in the Python-owned slots): run the
 // release walk — successor decrements (more device tasks surface back
 // onto the lane; CPU successors enter the ready structure/plane), slot
 // retires, completion accounting — exactly the run() sweep, per task.

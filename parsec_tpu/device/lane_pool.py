@@ -19,8 +19,8 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
          written: Sequence[Tuple[int, ...]], names: Sequence[str],
          slots: List[Any], mem_datas: Sequence[Any],
          writebacks: Dict[int, List], dev_mask: Sequence[int],
-         ndev_tasks: int, fusion: Optional[Dict[str, Any]] = None,
-         bucket: int = 0,
+         ndev_tasks: int, early: Optional[Sequence[int]] = None,
+         fusion: Optional[Dict[str, Any]] = None, bucket: int = 0,
          cost_obs: Optional[Dict] = None) -> Tuple[int, Dict[int, List]]:
     """Bind a flattened data pool to ``devlane`` (device/native.py): from
     then on a task of ``engine`` (``dev_bind``, ``dev_retire_capsule``,
@@ -39,6 +39,9 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
     ``cost_obs`` ((name, bucket, dev) -> [count, sum_ns]; None:
     unobserved). ``writebacks[i]``: the (flow position, ``Data``) pairs
     task ``i`` writes to memory. ``ndev_tasks``: the tasks under the mask.
+    ``early[i]`` set (the caller's finding, from the graph's structure
+    alone): node ``i`` has successors and every one of them is under the
+    mask, so it is released when it is dispatched (:func:`_closures`).
 
     ``fusion``, for an engine whose nodes are regions and seams:
     ``orig_of`` maps a node to its task, ``dev_regions`` a region's node
@@ -54,11 +57,11 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
     once and the manager may dispatch them before this returns. Returns
     the lane's pool id (for ``unbind_pool``) and ``held``.
     """
-    dispatch, poll, held = _closures(
+    dispatch, poll, drop, held = _closures(
         devlane, engine, bases, params, slot_base, in_refs, ndflows, cls_of,
         fns, written, names, slots, mem_datas, writebacks, fusion, bucket,
-        cost_obs)
-    pid = devlane.bind_pool(engine, dispatch, poll)
+        cost_obs, early)
+    pid = devlane.bind_pool(engine, dispatch, poll, drop)
     PTDEV_STATS["pools_engaged"] += 1
     PTDEV_STATS["tasks_engaged"] += ndev_tasks
     engine.dev_bind(devlane.submit_capsule(), pid, dev_mask)
@@ -68,7 +71,7 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
 
 def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
               cls_of, fns, written, names, slots, mem_datas, writebacks,
-              fusion, bucket, cost_obs):
+              fusion, bucket, cost_obs, early):
     """The pool's dispatch/poll pair, both run on the lane's manager
     thread with the GIL held:
 
@@ -78,20 +81,35 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
       through the C coherency table; ``device_put`` is asynchronous, so
       the transfers overlap compute already in flight), THEN each
       program dispatches (async) and its future outputs land in the
-      slots at once — safe because no consumer runs before this task
-      RETIRES, which only happens after its completion events fire;
+      slots at once. A node under ``early`` is RELEASED here: its
+      write-backs land (future arrays: ``Data.write_host`` blocks on
+      nothing) and the next ``poll()`` reports its id, so the engine's
+      release walk surfaces its successors, device nodes of this lane
+      all, while it still runs; XLA queues each behind the producers
+      of its operands, donated ones included;
     * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
       inflight program's outputs (cudaEventQuery, device_gpu.c:2593).
       Completed tasks write back to memory, give up their reads and
       return their ids; the C side then calls the engine's ``dev_retire``.
+      A released node stays in flight until it is seen complete and
+      then SETTLES: its reads, ``dev.executed_tasks``, the cost
+      observation and the one ``ptdev.retire`` record. Its witness is
+      one output that is left (a successor may have been given some
+      for good, and a deleted array is never asked), or the order of
+      the queue: one device runs its programs in dispatch order, so a
+      released node is complete once a node dispatched after it is.
 
     Residency is touched once per distinct memory operand of a BATCH:
     the push phase's stage-in takes the operand's one pin (table +
     ``readers``), ``held`` counts the programs in flight that read it,
-    and the pin is given back when the last of them retires (or at the
-    end of ``dispatch``, where no program of the batch reads it).
-    Returns ``(dispatch, poll, held)``; ``held`` is empty whenever
-    nothing is in flight.
+    and the pin is given back when the last of them retires or settles
+    (or at the end of ``dispatch``, where no program of the batch reads
+    it). Returns ``(dispatch, poll, drop, held)``; ``held`` is empty
+    whenever nothing is in flight: a node that is no sink of the device
+    part of the graph retires only when seen complete, every released
+    one has such a node dispatched after it, and the pass that retires
+    it settles what precedes it. ``drop()`` gives up what an aborted
+    pool leaves in flight (``unbind_pool``).
 
     Ownership: a table copy is the residency table's and a written-back
     array its ``Data``'s; a slot value is the pool's until its last
@@ -102,7 +120,12 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     arrays returned).
     """
     dev = devlane.device
+    # (id, events, write-backs, values, reads, weight, cost key, released),
+    # in dispatch order
     inflight: "collections.deque" = collections.deque()
+    # released at dispatch, for the next poll() to report to the engine
+    released: List[int] = []
+    early = frozenset(i for i, e in enumerate(early or ()) if e)
     # device-side cost observation (ISSUE 18): each inflight entry is
     # stamped at dispatch and observed at retire — the elapsed window
     # covers the async compute, the output-ready wait, AND the lane's
@@ -186,6 +209,22 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     else:
         _forig = _dregs = _graph = None
 
+    def _fly(i, events, wbs, vals, reads, w, ckey2):
+        # one more program is on the device. Released at dispatch, it owes
+        # no write-back later: they land now, BEFORE the engine hears of
+        # it, so that a successor reading the ``Data`` from memory stages
+        # (adopts) the new version
+        PTDEV_STATS["programs"] += 1
+        rel = i in early
+        if rel:
+            if wbs:
+                for dj, dref in wbs:
+                    dref.write_host(vals[dj])
+                wbs = None
+            released.append(i)
+            PTDEV_STATS["released_early"] += 1
+        inflight.append((i, events, wbs, vals, reads, w, ckey2, rel))
+
     def dispatch(ids):
         # PUSH phase: issue every memory-endpoint stage-in for the
         # whole batch before any compute dispatch, each distinct
@@ -246,11 +285,10 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                     PTDEV_STATS["region_outputs"] += len(vals)
                     events = tuple(v for v in vals
                                    if hasattr(v, "is_ready"))
-                    inflight.append((
-                        i, events, r["wb_pairs"], vals,
-                        r["ext_mems"], r["ntasks"],
-                        None if (_obs is None or r.get("cold")) else
-                        (names[r["cls"]], bucket, "tpu_fused")))
+                    _fly(i, events, r["wb_pairs"], vals,
+                         r["ext_mems"], r["ntasks"],
+                         None if (_obs is None or r.get("cold")) else
+                         (names[r["cls"]], bucket, "tpu_fused"))
                     continue
                 oi = _forig[i]
             k = cls_of[oi]
@@ -279,40 +317,68 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                                if hasattr(v, "is_ready"))
             for dj in range(nd):
                 slots[base + dj] = vals[dj]
-            inflight.append((i, events, writebacks.get(oi), vals, reads,
-                             1,
-                             None if _obs is None else
-                             (names[k], bucket, "tpu")))
+            _fly(i, events, writebacks.get(oi), vals, reads, 1,
+                 None if _obs is None else (names[k], bucket, "tpu"))
         for mi, h in staged.items():
             if not h[1]:            # staged, and no program reads it
                 _release(mi, h)
         return len(ids)
 
+    def _complete(events, was_released):
+        if not was_released:
+            return not events or all(a.is_ready() for a in events)
+        # settling gates the accounting only, so one witness will do: every
+        # output of a program is a buffer that program wrote, and they
+        # turn ready together. A successor in flight may have been given
+        # some for good: a deleted array tells nothing and is never asked
+        for a in reversed(events):
+            if not a.is_deleted():
+                return a.is_ready()
+        return not events
+
     def poll():
-        done: List[int] = []
+        done = released[:]
+        del released[:]
+        if not inflight:
+            return done
+        # newest first: a released program is complete once one dispatched
+        # after it is (one device, in dispatch order), so the pass that
+        # sees a completion settles every released one before it
+        complete: List[Tuple] = []
+        flying: List[Tuple] = []
+        later = False
+        for ent in reversed(inflight):
+            if (later and ent[7]) or _complete(ent[1], ent[7]):
+                later = True
+                complete.append(ent)
+            else:
+                flying.append(ent)
+        if not complete:
+            return done
+        inflight.clear()
+        inflight.extend(reversed(flying))
         retired: List[Tuple] = []
-        for _ in range(len(inflight)):
-            ent = inflight.popleft()
-            i, events, wbs, vals, reads, w, ckey2 = ent
-            if events and not all(a.is_ready() for a in events):
-                inflight.append(ent)
-                continue
+        for i, _events, wbs, vals, reads, w, ckey2, was_released in \
+                reversed(complete):
             if sp is not None:
                 tok = sp.begin(PTDEV_RETIRE)
             if wbs:
                 for dj, dref in wbs:
                     dref.write_host(vals[dj])
             for mi in reads:
-                h = held[mi]
+                h = held.get(mi)
+                if h is None:       # dropped with an aborted pool
+                    continue
                 h[1] -= 1
                 if not h[1]:        # its last reader in flight retired
                     _release(mi, h)
             dev.executed_tasks += w
             retired.append((ckey2, w))
-            done.append(i)
+            if not was_released:    # the engine heard of it at dispatch
+                done.append(i)
             if sp is not None:
                 retired_ns[0] += sp.end(tok, sp.pt_retire)
-        if retired and _obs is not None:
+        if _obs is not None:
             # batch amortization, the SAME semantics as the C lane's
             # exec bump: the wall window since the last retire sweep
             # (or the idle->active mark) divides across every task
@@ -333,8 +399,17 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             dev_clock[0] = now
         return done
 
+    def drop():
+        # what an aborted pool leaves: nobody will ask after it again
+        inflight.clear()
+        del released[:]
+        while held:
+            _mi, h = held.popitem()
+            for _ in range(h[2]):
+                dev.unpin_copy(h[0])
+
     if sp is None:
-        return dispatch, poll, held
+        return dispatch, poll, drop, held
     retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
 
     def traced_dispatch(ids):
@@ -358,4 +433,4 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         finally:
             sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
 
-    return traced_dispatch, traced_poll, held
+    return traced_dispatch, traced_poll, drop, held

@@ -58,10 +58,13 @@ mca.register("device_native_poll_us", 100,
 #: ``donated`` / ``region_outputs`` (ISSUE 34): slot operands fused
 #: region programs were given for good, and the arrays those programs
 #: returned (write-backs included): what of a solve's output buffers
-#: reuses an input's.
+#: reuses an input's. ``programs`` / ``released_early`` (ISSUE 36): device
+#: programs the lane's closures dispatched, and those released to the
+#: engine at dispatch (every successor a device node of the same lane).
 PTDEV_STATS = LaneStats(lanes_up=0, pools_engaged=0, tasks_engaged=0,
                         pools_fallback=0, pools_ineligible=0,
-                        donated=0, region_outputs=0)
+                        donated=0, region_outputs=0,
+                        programs=0, released_early=0)
 
 #: live lanes, for the process-wide ``ptdev.*`` counter samplers
 _lanes: "weakref.WeakSet[NativeDeviceLane]" = weakref.WeakSet()
@@ -194,8 +197,8 @@ class NativeDeviceLane:
         self.device = device          # the TPUDevice whose chip we drive
         self._mod = load_ptdev()
         self.clane = self._mod.Lane()
-        #: pool id -> its (dispatch, poll) closures (device/lane_pool.py)
-        self._pools: Dict[int, Tuple[Callable, Callable]] = {}
+        #: pool id -> its (dispatch, poll, drop) closures (device/lane_pool.py)
+        self._pools: Dict[int, Tuple[Callable, Callable, Callable]] = {}
         self._next_pool = 1
         self._stats_cache: Tuple[float, Optional[dict]] = (0.0, None)
         self._coh_cache: Tuple[float, Optional[dict]] = (0.0, None)
@@ -211,20 +214,26 @@ class NativeDeviceLane:
                              f"native device lane up on {device.name}")
 
     # --------------------------------------------------------- pool routing
-    def bind_pool(self, engine, dispatch: Callable, poll: Callable) -> int:
+    def bind_pool(self, engine, dispatch: Callable, poll: Callable,
+                  drop: Callable) -> int:
         """Route a pool's device tasks: ``engine`` provides the GIL-free
         retire entry (dev_retire_capsule); ``dispatch(ids)`` issues the
-        async device work; ``poll()`` returns completed tids whose
-        outputs have landed. Returns the lane-local pool id to pass to
-        the engine's ``dev_bind``."""
+        async device work; ``poll()`` returns the tids the engine may
+        retire (seen complete, or released at dispatch); ``drop()`` gives
+        up what the pool still has in flight when it is unbound. Returns
+        the lane-local pool id to pass to the engine's ``dev_bind``."""
         pid = self._next_pool
         self._next_pool += 1
         self.clane.bind_pool(pid, engine.dev_retire_capsule(), engine)
-        self._pools[pid] = (dispatch, poll)
+        self._pools[pid] = (dispatch, poll, drop)
         return pid
 
     def unbind_pool(self, pool_id: int) -> None:
-        self._pools.pop(pool_id, None)
+        closures = self._pools.pop(pool_id, None)
+        if closures is not None:
+            # a pool that ran to its end has nothing left; an aborted one
+            # may: programs released at dispatch and not yet seen complete
+            closures[2]()
         try:
             self.clane.unbind_pool(pool_id)
         except Exception:  # noqa: BLE001 — teardown races are benign
@@ -249,7 +258,7 @@ class NativeDeviceLane:
 
     def _poll(self):
         done = []
-        for pid, (_dispatch, poll) in list(self._pools.items()):
+        for pid, (_dispatch, poll, _drop) in list(self._pools.items()):
             for tid in poll():
                 done.append((pid, tid))
         return done

@@ -285,6 +285,22 @@ def _mk_region_program(rp: Dict[str, Any], fns, written_by_class, scopes,
     return region_program
 
 
+def _released_at_dispatch(dev_mask: Sequence[int], off: Sequence[int],
+                          succs: Sequence[int]) -> List[int]:
+    """Per node of a graph (the CSR ``off`` / ``succs``), 1 where the device
+    lane may release it the moment it is dispatched (ISSUE 36): a device
+    node (``dev_mask``) with at least one successor, every one of them a
+    device node of the same mask. Its successors are then programs of the
+    one device behind it, which XLA's runtime queues behind the producers
+    of their operands; a sink, and a node with one host-bodied or CTL
+    successor, retires when the host has seen it complete, as ever.
+    Structure alone: it is no part of a region's shape nor of any
+    executable's key."""
+    return [1 if d and off[i] < off[i + 1]
+            and all(dev_mask[t] for t in succs[off[i]:off[i + 1]]) else 0
+            for i, d in enumerate(dev_mask)]
+
+
 def _region_shape(kind: str, steps, out_slots, reads, class_names,
                   donated=()):
     """The canonical plan of a region (ISSUE 29): its steps with slot
@@ -2034,6 +2050,8 @@ class PTGTaskpool(Taskpool):
             if ndev_tasks == 0:
                 dev_mask2 = None
         n_fused = sum(len(m) for m in regions)
+        dev_early2 = None if dev_mask2 is None else \
+            _released_at_dispatch(dev_mask2, off2, succs2)
         return {"node": node, "goals": goals2, "off": off2,
                 "succs": succs2, "prio": prio2, "in_off": in_off2,
                 "in_slots": in_slots2, "slot_uses": slot_uses2,
@@ -2045,6 +2063,7 @@ class PTGTaskpool(Taskpool):
                 "writebacks": [w for w in data["writebacks"]
                                if reg_of[w[0]] < 0],
                 "dev_mask": dev_mask2, "ndev_tasks": ndev_tasks,
+                "dev_early": dev_early2,
                 "n_seam": n - n_fused, "n_fused": n_fused,
                 "n_packed": n_packed,
                 "n_mixed": sum(
@@ -2356,12 +2375,21 @@ class PTGTaskpool(Taskpool):
                             for d, nd in zip(place_dev, data["ndflows"])]
             if not any(dev_of_class):
                 return
-            dev_mask: List[int] = []
-            for ci, insts in enumerate(flat["params"]):
-                dev_mask.extend([1 if dev_of_class[ci] else 0] * len(insts))
-            ndev = sum(dev_mask)
+            # the mask and what it allows ride the flatten cache (the
+            # placement is part of its key)
+            found = flat.get("dev")
+            if found is None:
+                dev_mask: List[int] = []
+                for ci, insts in enumerate(flat["params"]):
+                    dev_mask.extend(
+                        [1 if dev_of_class[ci] else 0] * len(insts))
+                found = flat["dev"] = (
+                    dev_mask, sum(dev_mask), _released_at_dispatch(
+                        dev_mask, flat["off"], flat["succs"]))
+            dev_mask, ndev, early = found
         else:
             dev_mask, ndev = plan["dev_mask"], plan["ndev_tasks"]
+            early = plan["dev_early"]
         from ...core import costmodel as _cm
         from ...device import lane_pool
         PTEXEC_STATS["pools_device"] += 1
@@ -2374,7 +2402,7 @@ class PTGTaskpool(Taskpool):
             cls_of=data["cls_of"], fns=class_fns[0], written=class_fns[1],
             names=names, slots=slots, mem_datas=mem_datas,
             writebacks=writebacks, dev_mask=dev_mask, ndev_tasks=ndev,
-            fusion=fusion, bucket=bucket,
+            early=early, fusion=fusion, bucket=bucket,
             # the lane's observations, folded into the cost model at
             # detach (Context._cost_fold)
             cost_obs=lane.setdefault("cost_dev", {}) if _cm.enabled()

@@ -712,7 +712,7 @@ def _spy_closures(tp, monkeypatch, on_dispatch=None, on_poll=None):
     box = {}
 
     def spied(*args, **kw):
-        dispatch, poll, held = make(*args, **kw)
+        dispatch, poll, drop, held = make(*args, **kw)
         box["held"] = held
 
         def spy_dispatch(ids):
@@ -726,7 +726,7 @@ def _spy_closures(tp, monkeypatch, on_dispatch=None, on_poll=None):
             if on_poll is not None:
                 on_poll(done, held)
             return done
-        return spy_dispatch, spy_poll, held
+        return spy_dispatch, spy_poll, drop, held
     monkeypatch.setattr(lane_pool, "_closures", spied)
     return box
 
@@ -1104,3 +1104,85 @@ def test_lane_pool_binds_plain_data(dctx):
         assert all(c.readers == 0 for c in d.copies.values())
     delta = PTDEV_STATS.delta(before)
     assert (delta["pools_engaged"], delta["tasks_engaged"]) == (1, 3)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: release at dispatch
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("bound", [128, 0], ids=["packs", "per-task"])
+def test_a_k_chain_gemm_pool_releases_what_its_structure_allows(dctx, bound):
+    """A pool of packed k-chains is regions with no successor: none is
+    released at dispatch and every one retires when seen complete. Run a
+    task a program, each GEMM(m, n, k) but a chain's last has the next as
+    its one successor, a device task: three of every four are released."""
+    from parsec_tpu.device.native import PTDEV_STATS
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    a, b, mats = _gemm_operands(f"er{bound}", 36)
+    mca.set("region_fusion", bool(bound))
+    try:
+        prog = compile_ptg(_GEMM_SRC, f"er-gemm-{bound}")
+        tp = _gemm_pool(dctx, prog, mats)
+        before = PTDEV_STATS.snapshot()
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=90)
+        delta = PTDEV_STATS.delta(before)
+    finally:
+        mca.params.unset("region_fusion")
+    assert dctx._ptdev.failed() is None
+    assert np.array_equal(mats[2].to_dense(), a @ b)
+    (ent,) = prog._ptexec_cache.values()
+    if bound:
+        assert ent["fusion"]["dev_early"] == [0] * len(
+            ent["fusion"]["dev_mask"])
+        assert delta["programs"] >= 1 and delta["released_early"] == 0
+    else:
+        assert delta["programs"] == _NT ** 3
+        assert delta["released_early"] == _NT * _NT * (_NT - 1) \
+            == sum(ent["flat"]["dev"][2])
+    assert tp._ptexec_state["dev_held"] == {}
+    _assert_unpinned(dev, mats)
+
+
+def test_a_program_that_fails_on_the_device_still_poisons_the_lane(
+        dctx, monkeypatch):
+    """A chain of regions released at dispatch, the first of which fails
+    on the device (its outputs raise when asked whether they are ready):
+    the failure surfaces when the lane looks for a completion, poisons the
+    lane and ends the wait as the pool's error, and tearing the context
+    down leaves no pin behind an unbound pool."""
+    import jax
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    _need_lane(dctx)
+    dev = _tpu_dev(dctx)
+    kind = type(jax.device_put(np.zeros(1, np.float32), dev.jax_device))
+
+    def is_ready(array):
+        raise RuntimeError("INTERNAL: the device gave up on this program")
+    src = ("%global NT\n%global descA\n"
+           "T(k)\n  k = 0 .. NT-1\n"
+           "  RW X <- (k == 0) ? descA(0, 0) : X T(k-1)\n"
+           "       -> (k < NT-1) ? X T(k+1) : descA(0, 0)\n"
+           "  READ M <- descA(0, k+1)\n"
+           "BODY [type=TPU]\n  X = X + M\nEND\n")
+    A = TiledMatrix("failA", 4, 4 * 7, 4, 4)
+    A.fill(lambda m, k: np.ones((4, 4), np.float32))
+    mca.set("region_fusion_max", 2)
+    try:
+        prog = compile_ptg(src, "dev-fail-early")
+        tp = prog.instantiate(dctx, globals={"NT": 6},
+                              collections={"descA": A})
+        monkeypatch.setattr(kind, "is_ready", is_ready)
+        dctx.add_taskpool(tp)
+        with pytest.raises(BaseException):
+            dctx.wait(timeout=30)
+    finally:
+        mca.params.unset("region_fusion_max")
+        monkeypatch.undo()
+    assert "gave up on this program" in (dctx._ptdev.failed() or "")
+    (ent,) = prog._ptexec_cache.values()
+    assert ent["fusion"]["dev_early"] == [1, 1, 0]
+    dctx._ptdev.fini()
+    _assert_unpinned(dev, [A])

@@ -510,3 +510,317 @@ def test_the_benchmarks_reader_gives_the_share_or_nothing(monkeypatch):
     monkeypatch.delitem(PTDEV_STATS, "region_outputs")
     monkeypatch.delitem(PTDEV_STATS, "donated")
     assert reader.read(None) is None
+
+
+# --------------------------------- release at dispatch (ISSUE 36): the plan
+
+@pytest.mark.parametrize("nt, nodes, programs", [(12, 3, 3), (32, 47, 47)])
+def test_the_plan_releases_every_region_with_device_successors_only(
+        dctx, monkeypatch, nt, nodes, programs):
+    """The POTRF JDF's plan (the plan only): a region qualifies for
+    release at dispatch when it has successors and every one of them is a
+    device region of the pool; the sink does not. At NT = 32 that is 46
+    of the 47, node 46 the one sink, 1 to 5 distinct successors a node.
+    The finding is no part of a region's shape: the executables are the
+    parent's 47 (3 at NT = 12), and no shape's key knows of it."""
+    A = TiledMatrix(f"A{nt}", nt * TS, nt * TS, TS, TS)
+    prog = compile_ptg(ops.POTRF_JDF, "potrf")
+    plan = _plan_without_running(monkeypatch, dctx, prog, nt, A)
+    early, mask = plan["dev_early"], plan["dev_mask"]
+    off, succs = plan["off"], plan["succs"]
+    assert len(early) == len(mask) == nodes and all(mask)
+    for i in range(nodes):
+        after = set(succs[off[i]:off[i + 1]])
+        assert early[i] == (1 if after and all(mask[t] for t in after)
+                            else 0)
+        assert len(after) <= 5 and i not in after
+    sinks = [i for i in range(nodes) if off[i] == off[i + 1]]
+    assert sinks == [nodes - 1] and not early[nodes - 1]
+    assert sum(early) == nodes - 1
+    assert len(plan["shapes"]) == programs
+    assert C._released_at_dispatch(mask, off, succs) == early
+    # a host-bodied or CTL successor (a node outside the mask) refuses it
+    host = [1] * nodes
+    host[nodes - 1] = 0
+    refused = C._released_at_dispatch(host, off, succs)
+    assert all(not refused[i] for i in range(nodes)
+               if nodes - 1 in succs[off[i]:off[i + 1]])
+    assert not refused[nodes - 1]
+
+
+def _tpu_dev(ctx):
+    from parsec_tpu.device.tpu import TPUDevice
+    return [d for d in ctx.devices.devices if isinstance(d, TPUDevice)][0]
+
+
+def _spy_lane(monkeypatch, before_dispatch=None, after_poll=None):
+    """Wrap the pool's closures as the lane receives them."""
+    from parsec_tpu.device import lane_pool
+    make = lane_pool._closures
+
+    def spied(*args, **kw):
+        dispatch, poll, drop, held = make(*args, **kw)
+
+        def spy_dispatch(ids):
+            if before_dispatch is not None:
+                before_dispatch(list(ids))
+            return dispatch(ids)
+
+        def spy_poll():
+            done = poll()
+            if after_poll is not None:
+                after_poll(list(done), held)
+            return done
+        return spy_dispatch, spy_poll, drop, held
+    monkeypatch.setattr(lane_pool, "_closures", spied)
+
+
+def _late_is_ready(monkeypatch, dctx, asks=3):
+    """Every array reads complete only from its ``asks``-th ask on, so a
+    program stays in flight over several polls; returns the arrays asked
+    about after they were deleted (there must be none) and the ids of
+    those that have read complete."""
+    import jax
+    dev = _tpu_dev(dctx)
+    kind = type(jax.device_put(np.zeros(1, np.float32), dev.jax_device))
+    real = kind.is_ready
+    left, deleted, seen = {}, [], set()
+
+    def is_ready(array):
+        if array.is_deleted():
+            deleted.append(array)
+            return real(array)
+        ent = left.setdefault(id(array), [array, asks])
+        ent[1] -= 1
+        if ent[1] < 0 and real(array):
+            seen.add(id(array))
+            return True
+        return False
+    monkeypatch.setattr(kind, "is_ready", is_ready)
+    return deleted, seen
+
+
+def _assert_pins_given_back(dctx, mats):
+    dev = _tpu_dev(dctx)
+    for M in mats:
+        for m in range(M.mt):
+            for n in range(M.nt):
+                data = M.data_of(m, n)
+                if dev._ncoh is not None:
+                    st = dev._ncoh.state(dev.res_key(data))
+                    assert st is None or st[3] == 0, (M.name, m, n, st)
+                assert all(c.readers == 0 for c in data.copies.values())
+
+
+@pytest.mark.parametrize("path", ["regions", "per-task"])
+def test_a_factorization_released_at_dispatch(dctx, monkeypatch, path):
+    """The Cholesky with its regions (12 of <= 16 tasks at NT = 8) or its
+    tasks (35 at NT = 5) released at dispatch, each program held in flight
+    over several polls: the factor is the reference's and the DTD twin's,
+    the lane released what the plan said it would, and after ``ctx.wait()``
+    nothing is in flight: ``dev_held`` empty, every table pin given back."""
+    nt = {"regions": 8, "per-task": 5}[path]
+    knob, value = {"regions": ("region_fusion_max", 16),
+                   "per-task": ("region_fusion", False)}[path]
+    deleted, _seen = _late_is_ready(monkeypatch, dctx)
+    flying = []
+    _spy_lane(monkeypatch, after_poll=lambda done, held: flying.append(
+        len(held)))
+    a, A = _matrix(nt, seed=36)
+    prog = compile_ptg(ops.POTRF_JDF, f"potrf-early-{path}")
+    mca.set(knob, value)
+    try:
+        d0 = PTDEV_STATS.snapshot()
+        tp = prog.instantiate(dctx, globals={"NT": nt, **TILE_FNS},
+                              collections={"descA": A})
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=300)
+        dd = PTDEV_STATS.delta(d0)
+    finally:
+        mca.params.unset(knob)
+    assert tp.completed and counters.read("ptdev.cb_errors") == 0
+    assert dctx._ptdev.failed() is None and not deleted
+    (ent,) = prog._ptexec_cache.values()
+    if path == "regions":
+        early = ent["fusion"]["dev_early"]
+        assert len(early) >= 8
+    else:
+        assert ent["fusion"] is None
+        mask, ndev, early = ent["flat"]["dev"]
+        assert ndev == sum(mask) == ntasks(nt)
+    assert dd["programs"] == len(early)
+    assert dd["released_early"] == sum(early) == len(early) - 1
+    assert tp._ptexec_state["dev_held"] == {} and max(flying) > 0
+    _assert_pins_given_back(dctx, [A])
+    got = np.tril(np.asarray(A.to_dense()))
+    _assert_factor(got, a)
+    _a, T = _matrix(nt, seed=36)
+    twin = DTDTaskpool(dctx, f"twin-early-{path}")
+    assert ops.insert_potrf_tasks(twin, T) == ntasks(nt)
+    assert twin.wait(timeout=300)
+    twin.close()
+    dctx.wait(timeout=300)
+    np.testing.assert_allclose(got, np.tril(np.asarray(T.to_dense())),
+                               rtol=0, atol=2e-5)
+
+
+def _run_early_chain(dctx, src, name, n=6, x0=0.0, y0=float, fused=True):
+    X = TiledMatrix("X", TS, TS, TS, TS)
+    X.fill(lambda m, k: np.full((TS, TS), x0, np.float32))
+    Y = TiledMatrix("Y", TS, n * TS, TS, TS)
+    Y.fill(lambda m, k: np.full((TS, TS), y0(k), np.float32))
+    knob, value = ("region_fusion_max", 2) if fused \
+        else ("region_fusion", False)
+    mca.set(knob, value)
+    try:
+        prog = compile_ptg(src, name)
+        d0 = PTDEV_STATS.snapshot()
+        tp = prog.instantiate(dctx, globals={"N": n},
+                              collections={"descX": X, "descY": Y})
+        dctx.add_taskpool(tp)
+        dctx.wait(timeout=120)
+        dd = PTDEV_STATS.delta(d0)
+    finally:
+        mca.params.unset(knob)
+    assert tp.completed and counters.read("ptdev.cb_errors") == 0
+    assert tp._ptexec_state["dev_held"] == {}
+    _assert_pins_given_back(dctx, [X, Y])
+    (ent,) = prog._ptexec_cache.values()
+    return ent, dd, np.asarray(X.data_of(0, 0).newest_copy().payload), Y
+
+
+_HOST_READER = _READER.replace("BODY [type=TPU]", "BODY")
+
+
+@pytest.mark.parametrize("reader", ["host", "device"])
+def test_a_producer_with_a_host_bodied_reader_retires_when_complete(
+        dctx, monkeypatch, reader):
+    """``R(k)`` reads ``S(k)``'s result beside ``S(k+1)``. On the host it
+    keeps every ``S`` from being released at dispatch, and each ``R`` is
+    handed a value that has read complete; on the device every ``S`` but
+    the last is released early (its readers are device tasks all)."""
+    _deleted, seen = _late_is_ready(monkeypatch, dctx)
+    handed = []
+    make = C.PTGTaskpool._mk_ptexec_data_callback
+
+    def spied(self, flat, classes, slots, *args, **kw):
+        run_batch = make(self, flat, classes, slots, *args, **kw)
+        data = flat["data"]
+
+        def spy_batch(ids, retired):
+            for i in ids:       # host tasks: R(k), V its first flow
+                v = slots[data["in_refs"][data["slot_base"][i]]]
+                handed.append(id(v) in seen)
+            return run_batch(ids, retired)
+        return spy_batch
+    monkeypatch.setattr(C.PTGTaskpool, "_mk_ptexec_data_callback", spied)
+    src = _chain(dep="       -> V R(k)\n",
+                 more=_HOST_READER if reader == "host" else _READER)
+    ent, dd, x, Y = _run_early_chain(dctx, src, f"early-{reader}-reader",
+                                     fused=False)
+    mask, ndev, early = ent["flat"]["dev"]
+    if reader == "host":
+        assert ndev == 6 and sum(early) == 0 == dd["released_early"]
+        assert dd["programs"] == 6
+        assert handed == [True] * 6
+    else:
+        # S(k) -> S(k+1), R(k): released; S(5) -> R(5): released too; the
+        # six R are sinks
+        assert ndev == 12 and sum(early) == 6 == dd["released_early"]
+        assert dd["programs"] == 12 and not handed
+    np.testing.assert_array_equal(x, np.full((TS, TS), 6.0))
+    for k in range(6):
+        np.testing.assert_array_equal(
+            np.asarray(Y.data_of(0, k).newest_copy().payload), 2.0 * k + 1.0)
+
+
+def test_a_region_whose_outputs_are_all_donated_on_settles_by_the_queue(
+        dctx, monkeypatch):
+    """A chain of regions each of which hands its one output to the next
+    for good: a released region's only completion witness is deleted by its
+    successor's call. It settles when a later program is seen complete,
+    and no deleted array is ever asked ``is_ready``."""
+    deleted, _seen = _late_is_ready(monkeypatch, dctx, asks=5)
+    passes = []
+    _spy_lane(monkeypatch,
+              after_poll=lambda done, held: passes.append(list(done)))
+    ent, dd, x, _Y = _run_early_chain(dctx, _chain(), "early-donated-on")
+    plan = ent["fusion"]
+    assert _donated(plan) == [0, 1, 1] and plan["dev_early"] == [1, 1, 0]
+    assert dd["donated"] == 2 and dd["released_early"] == 2
+    assert dd["programs"] == 3 and not deleted
+    # each released region was reported at the poll after its dispatch
+    assert [p for p in passes if p] == [[0], [1], [2]]
+    np.testing.assert_array_equal(x, np.full((TS, TS), 6.0))
+
+
+def test_a_released_regions_write_back_is_what_its_successor_stages_in(
+        dctx, monkeypatch):
+    """``S(k)`` writes ``descY(0, k)`` back and ``S(k+1)`` reads it from
+    memory. Released at dispatch, a region's write-backs land before the
+    engine hears of it: when the next region is dispatched (its
+    predecessor still in flight) every tile the predecessor wrote is at
+    its new version, and the stage-in adopts the array."""
+    _deleted, seen = _late_is_ready(monkeypatch, dctx, asks=5)
+    versions, box = [], {}
+
+    def before(ids):
+        versions.append((ids, [box["Y"].data_of(0, k).version
+                               for k in range(6)]))
+    _spy_lane(monkeypatch, before_dispatch=before)
+    real_fill = TiledMatrix.fill
+
+    def fill(self, fn):
+        if self.name == "Y":
+            box["Y"] = self
+        return real_fill(self, fn)
+    monkeypatch.setattr(TiledMatrix, "fill", fill)
+    dev = _tpu_dev(dctx)
+    adopted = dev.adopted
+    src = _chain(flow="  READ M <- (k == 0) ? descY(0, 0) : descY(0, k-1)\n",
+                 dep="       -> descY(0, k)\n", body="X = X + M")
+    ent, dd, x, Y = _run_early_chain(dctx, src, "early-write-back", x0=1.0,
+                                     y0=lambda k: 10.0 * (k + 1))
+    assert ent["fusion"]["dev_early"] == [1, 1, 0]
+    assert dd["released_early"] == 2
+    v0 = versions[0][1]
+    assert [ids for ids, _v in versions] == [[0], [1], [2]]
+    # region i wrote tiles 2i and 2i + 1 (the last X also goes to descX)
+    assert [[b - a for a, b in zip(v0, v)] for _ids, v in versions] == [
+        [0] * 6, [1, 1, 0, 0, 0, 0], [1, 1, 1, 1, 0, 0]]
+    assert dev.adopted - adopted >= 2
+    # 1 + 10 = 11, then doubled by each S(k) that reads what S(k-1) wrote
+    np.testing.assert_array_equal(x, np.full((TS, TS), 352.0))
+    for k in range(6):
+        np.testing.assert_array_equal(
+            np.asarray(Y.data_of(0, k).newest_copy().payload),
+            11.0 * 2 ** k)
+
+
+def test_the_benchmarks_reader_gives_the_early_share_or_nothing(monkeypatch):
+    """``chipbench/layers/early_release_share.py``: the cell's 46 of 47, 0
+    where no program has a successor, and nothing to read where no program
+    ran or the program has no such counters (the parent commit under this
+    benchmark)."""
+    import importlib
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    reader = importlib.import_module("chipbench.layers.early_release_share")
+    monkeypatch.setitem(PTDEV_STATS, "programs", 47)
+    monkeypatch.setitem(PTDEV_STATS, "released_early", 46)
+    assert reader.read(None) == pytest.approx(97.8723, abs=1e-4)
+    monkeypatch.setitem(PTDEV_STATS, "released_early", 0)
+    assert reader.read(None) == 0.0
+    monkeypatch.setitem(PTDEV_STATS, "programs", 0)
+    assert reader.read(None) is None
+    monkeypatch.delitem(PTDEV_STATS, "programs")
+    monkeypatch.delitem(PTDEV_STATS, "released_early")
+    assert reader.read(None) is None
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = json.load(f)["per_layer"][-1]
+    assert entry == {"name": "early_release_share", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "device issue", "moves": "tasks_per_s",
+                     "workloads": ["ptg_gemm.ts512", "ptg_potrf.ts512"]}
