@@ -10,7 +10,8 @@ import collections
 import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-from ..utils.xla_trace import PTDEV_DISPATCH, PTDEV_POLL, PTDEV_RETIRE
+from ..utils.xla_trace import (PTDEV_CALL, PTDEV_DISPATCH, PTDEV_POLL,
+                               PTDEV_PUSH, PTDEV_RETIRE, file_pool_account)
 from .native import PTDEV_STATS
 
 
@@ -60,7 +61,7 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
     dispatch, poll, drop, held = _closures(
         devlane, engine, bases, params, slot_base, in_refs, ndflows, cls_of,
         fns, written, names, slots, mem_datas, writebacks, fusion, bucket,
-        cost_obs, early)
+        cost_obs, early, ndev_tasks)
     pid = devlane.bind_pool(engine, dispatch, poll, drop)
     PTDEV_STATS["pools_engaged"] += 1
     PTDEV_STATS["tasks_engaged"] += ndev_tasks
@@ -71,7 +72,7 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
 
 def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
               cls_of, fns, written, names, slots, mem_datas, writebacks,
-              fusion, bucket, cost_obs, early):
+              fusion, bucket, cost_obs, early, ndev_tasks):
     """The pool's dispatch/poll pair, both run on the lane's manager
     thread with the GIL held:
 
@@ -118,6 +119,12 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     jitted with them donated, so ``dispatch`` clears those slots after
     the call (``PTDEV_STATS["donated"]``, of ``["region_outputs"]``
     arrays returned).
+
+    With the context's spans on, the pair comes back wrapped: the
+    ``ptdev.*`` spans, and the pool's account of the manager thread's
+    time, filed once, in the pass that retires or settles the last of
+    the ``ndev_tasks`` task weights, or at ``drop()``
+    (``utils/xla_trace.py file_pool_account``; docs/observability.md).
     """
     dev = devlane.device
     # (id, events, write-backs, values, reads, weight, cost key, released),
@@ -161,6 +168,16 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     sp = devlane.ctx._spans
     if sp is not None:
         pinned = [0]     # table pins taken so far, for ptdev.pins
+        # the pool's account: the clock now and at the first ptdev.call
+        # entered, what its spans add up to, and whether it is filed
+        bound, first_call, filed = _pc(), [0], []
+        acct = {"dispatch_ns": 0, "push_ns": 0, "call_ns": 0, "poll_ns": 0,
+                "programs": 0, "callbacks": 0, "passes": 0, "tasks": 0}
+
+        def _called(tok):
+            if not first_call[0]:
+                first_call[0] = tok[1]
+            acct["call_ns"] += sp.end(tok, sp.pt_call)
 
         # ptdev.stage_in: the push phase's misses only (a hit moves
         # no bytes); on the timeline a miss is the dev.stage_in
@@ -225,7 +242,7 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             PTDEV_STATS["released_early"] += 1
         inflight.append((i, events, wbs, vals, reads, w, ckey2, rel))
 
-    def dispatch(ids):
+    def push(ids):
         # PUSH phase: issue every memory-endpoint stage-in for the
         # whole batch before any compute dispatch, each distinct
         # operand once, pinned THE MOMENT it stages (_hold)
@@ -248,6 +265,9 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 r = in_refs[base + dj]
                 if r < -1 and (-2 - r) not in staged:
                     _hold(-2 - r, staged)
+        return staged
+
+    def issue(ids, staged):
         # EXEC phase: dispatch each ready device task asynchronously
         for i in ids:
             oi = i
@@ -271,8 +291,16 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                     given = r["given"]
                     nd = len(given)
                     _graph.trace_mark(_evr, i, _fs)
-                    first, rest = r["jitted"](tuple(ev[:nd]),
-                                              tuple(ev[nd:]))
+                    if sp is None:
+                        first, rest = r["jitted"](tuple(ev[:nd]),
+                                                  tuple(ev[nd:]))
+                    else:
+                        tok = sp.begin(PTDEV_CALL)
+                        try:
+                            first, rest = r["jitted"](tuple(ev[:nd]),
+                                                      tuple(ev[nd:]))
+                        finally:
+                            _called(tok)
                     _graph.trace_mark(_evr, i, _fe)
                     vals = first + rest
                     for s, p in r["outs"]:
@@ -310,7 +338,14 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             fn = fns[k]
             events = ()
             if fn is not None:
-                outs = fn(*params[k][oi - bases[k]], *vals)
+                if sp is None:
+                    outs = fn(*params[k][oi - bases[k]], *vals)
+                else:
+                    tok = sp.begin(PTDEV_CALL)
+                    try:
+                        outs = fn(*params[k][oi - bases[k]], *vals)
+                    finally:
+                        _called(tok)
                 for oj, dj in enumerate(written[k]):
                     vals[dj] = outs[oj]
                 events = tuple(v for v in outs
@@ -323,6 +358,9 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             if not h[1]:            # staged, and no program reads it
                 _release(mi, h)
         return len(ids)
+
+    def dispatch(ids):
+        return issue(ids, push(ids))
 
     def _complete(events, was_released):
         if not was_released:
@@ -378,6 +416,7 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 done.append(i)
             if sp is not None:
                 retired_ns[0] += sp.end(tok, sp.pt_retire)
+                acct["tasks"] += w
         if _obs is not None:
             # batch amortization, the SAME semantics as the C lane's
             # exec bump: the wall window since the last retire sweep
@@ -412,25 +451,47 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         return dispatch, poll, drop, held
     retired_ns = [0]     # ptdev.retire total, for ptdev.poll to subtract
 
+    def _file(end):
+        if not filed:
+            filed.append(file_pool_account(
+                bound, first_call[0], end, retire_ns=retired_ns[0], **acct))
+
     def traced_dispatch(ids):
-        # one span a callback, recorded once per device program; the
-        # table pins the callback took and the programs it found in
-        # flight (the depth of the device's queue as the host left it),
-        # one record each
+        # one span a callback, recorded once per device program, its push
+        # phase a span inside it; the table pins the callback took and
+        # the programs it found in flight (the depth of the device's
+        # queue as the host left it), one record each
         sp.pt_inflight.record(len(inflight))
         tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
         try:
-            return dispatch(ids)
+            sub = sp.begin(PTDEV_PUSH)
+            try:
+                staged = push(ids)
+            finally:
+                acct["push_ns"] += sp.end(sub, sp.pt_push)
+            return issue(ids, staged)
         finally:
-            sp.end(tok, sp.pt_dispatch, n=len(ids))
+            acct["dispatch_ns"] += sp.end(tok, sp.pt_dispatch, n=len(ids))
             sp.pt_pins.record(pinned[0] - before)
+            acct["programs"] += len(ids)
+            acct["callbacks"] += 1
 
     def traced_poll():
-        # one record a pass, the retirements' own spans subtracted
+        # one record a pass, the retirements' own spans subtracted; the
+        # pool's end is the end of the pass that retires its last task
         tok, before = sp.begin(PTDEV_POLL), retired_ns[0]
         try:
             return poll()
         finally:
-            sp.end(tok, sp.pt_poll, less=retired_ns[0] - before)
+            less = retired_ns[0] - before
+            whole = sp.end(tok, sp.pt_poll, less=less)
+            acct["poll_ns"] += whole - less
+            acct["passes"] += 1
+            if acct["tasks"] >= ndev_tasks:
+                _file(tok[1] + whole)
 
-    return traced_dispatch, traced_poll, drop, held
+    def traced_drop():
+        drop()
+        _file(_pc())
+
+    return traced_dispatch, traced_poll, traced_drop, held

@@ -43,7 +43,8 @@ from ..core.task import (DEV_TPU, FLOW_ACCESS_CTL, FLOW_ACCESS_WRITE,
                          HOOK_ASYNC, HOOK_DONE, Task)
 from ..data.data import COHERENCY_INVALID, COHERENCY_OWNED, COHERENCY_SHARED, Data, DataCopy
 from ..utils import mca, output
-from ..utils.xla_trace import (DEV_POLL, DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT,
+from ..utils.xla_trace import (DEV_CALL, DEV_GATHER, DEV_POLL, DEV_RETIRE,
+                               DEV_STAGE_IN, DEV_SUBMIT,
                                DEV_WRITEBACK)
 from .device import DeviceModule
 
@@ -628,13 +629,19 @@ class TPUDevice(DeviceModule):
                      task.taskpool.taskpool_id, EVENT_FLAG_START)
         sp = self._spans
         if sp is not None:
-            tok = sp.begin(DEV_SUBMIT)
+            # dev.submit is dev.gather, then dev.call
+            tok, sub, cell = sp.begin(DEV_SUBMIT), sp.begin(DEV_GATHER), \
+                sp.gather
         try:
             inputs = self._gather_inputs(gt)
+            if sp is not None:
+                sp.end(sub, cell)
+                sub, cell = sp.begin(DEV_CALL), sp.call
             outs = gt.submit(self, task, inputs)
         finally:
             if sp is not None:
-                sp.end(tok, sp.submit)      # a failed attempt's cost too
+                sp.end(sub, cell)           # a failed attempt's cost too
+                sp.end(tok, sp.submit)
         if sp is not None:
             sp.ready_wait(task)     # issued: ready-wait ends, once per task
         if outs is None:
@@ -722,14 +729,18 @@ class TPUDevice(DeviceModule):
         dispatched, each as the tasks it carries."""
         sp = self._spans
         if sp is not None:
-            tok = sp.begin(DEV_SUBMIT)
+            tok, sub = sp.begin(DEV_SUBMIT), sp.begin(DEV_GATHER)
         try:
             inputs_list = [self._gather_inputs(g) for g in group]
+            if sp is not None:
+                gather_ns = sp.end(sub, None)
+                sub = sp.begin(DEV_CALL)
             outs_list = group[0].batch_submit(self, [g.task for g in group],
                                               inputs_list)
         except Exception as e:  # noqa: BLE001 - ragged shapes, stage-in OOM
             if sp is not None:
-                sp.end(tok, None)   # the per-task retries record their own
+                sp.end(sub, None)   # the per-task retries record their own
+                sp.end(tok, None)
             output.debug_verbose(2, "device",
                                  f"group of {len(group)} fell back: {e}")
             # unpin EVERY member (a stage-in failure mid-gather leaves
@@ -737,14 +748,17 @@ class TPUDevice(DeviceModule):
             for g in group:
                 self._unpin(g)
             return [[g] for g in group if self._submit_one_retry(g)]
-        self.batched_dispatches += 1
-        self.batched_tasks += len(group)
         if sp is not None:
             # one dispatch, recorded once per member at its share
-            sp.end(tok, sp.submit, n=len(group))
-            sp.group_tasks.record(len(group))
+            n = len(group)
+            sp.end(sub, sp.call, n=n)
+            sp.gather.record(gather_ns // n, n)
+            sp.end(tok, sp.submit, n=n)
+            sp.group_tasks.record(n)
             for g in group:
                 sp.ready_wait(g.task)
+        self.batched_dispatches += 1
+        self.batched_tasks += len(group)
         for g, outs in zip(group, outs_list):
             if outs is None:
                 outs = ()

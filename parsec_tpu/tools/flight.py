@@ -121,7 +121,7 @@ def _snapshot_trace(ctx, path: str, max_events: int) -> int:
         n += len(events)
     if n == 0:
         return 0
-    snap.dump(path, backend="pbp")
+    snap.dump(path)
     return n
 
 

@@ -1,7 +1,7 @@
 """Memory-over-time from a trace: the dbp2mem role.
 
 Re-design of the reference's dbp2mem (tools/profiling/dbp2mem.c): read a
-PBP/PTF2 trace, extract the ``*::mem`` residency POINT events the device
+PBP trace, extract the ``*::mem`` residency POINT events the device
 LRU emits (``resident{q};delta{q}`` — post-change occupancy in bytes), and
 render memory occupancy over time — as rows, CSV (the reference emits a
 gnuplot-ready table), or a standalone step-line SVG per device stream.
@@ -18,7 +18,7 @@ from __future__ import annotations
 import struct
 from typing import Any, Dict, List, Optional
 
-from .trace_reader import TraceData, read_trace
+from .trace_reader import TraceData, read_pbp
 
 
 def memory_timeline(trace: TraceData) -> List[Dict[str, Any]]:
@@ -123,13 +123,13 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap = argparse.ArgumentParser(
         description="Render device-memory occupancy over time from a trace "
                     "(the dbp2mem role)")
-    ap.add_argument("trace", help="PBP file or PTF2 archive directory")
+    ap.add_argument("trace", help="PBP file")
     ap.add_argument("--csv", metavar="PATH",
                     help="write a gnuplot/pandas-ready CSV")
     ap.add_argument("--svg", metavar="PATH", help="write a step-line SVG")
     args = ap.parse_args(argv)
 
-    trace = read_trace(args.trace)
+    trace = read_pbp(args.trace)
     if args.csv:
         with open(args.csv, "w") as f:
             f.write(to_csv(trace))

@@ -193,26 +193,6 @@ def to_animated_svg(trace: TraceData, playback_s: float = 5.0) -> str:
     return "\n".join(out)
 
 
-def read_ptf2(path: str) -> TraceData:
-    """Read a PTF2 archive (the OTF2-class backend) into the same model as
-    PBP files, so the whole analysis pipeline is format-agnostic."""
-    from ..utils.trace_ptf2 import read_archive
-    d = read_archive(path)
-    dictionary = []
-    for e in d["dictionary"]:
-        fields, fmt = parse_info_desc(e["info_desc"])
-        dictionary.append({**e, "fields": fields, "fmt": fmt})
-    return TraceData(d["t0"], dictionary, d["streams"])
-
-
-def read_trace(path: str) -> TraceData:
-    """Format dispatch: PTF2 archives are directories, PBP traces files."""
-    import os
-    if os.path.isdir(path):
-        return read_ptf2(path)
-    return read_pbp(path)
-
-
 # ------------------------------------------------- multi-rank trace merge
 
 #: the per-rank clock metadata keyword (stamped by
@@ -261,7 +241,7 @@ def merge_traces(paths: List[str], rebase: bool = True) -> TraceData:
     dictionaries are unified by keyword name, so the merged trace flows
     through the whole existing pipeline (dataframe, chrome JSON, SVG)
     unchanged."""
-    traces = [read_trace(p) for p in paths]
+    traces = [read_pbp(p) for p in paths]
     merged_dict: List[Dict[str, Any]] = []
     by_name: Dict[str, int] = {}
     streams: List[Dict[str, Any]] = []
@@ -407,7 +387,7 @@ def check_comms(paths: List[str]) -> Dict[str, Any]:
     """
     pairs = [("activate_snd", "activate_rcv"), ("get_snd", "get_rcv"),
              ("put_snd", "put_rcv")]
-    per_rank = [comm_events(read_trace(p)) for p in paths]
+    per_rank = [comm_events(read_pbp(p)) for p in paths]
     errors: List[str] = []
     counts: Dict[str, int] = {}
     for snd_kind, rcv_kind in pairs:
@@ -447,7 +427,7 @@ def check_comms(paths: List[str]) -> Dict[str, Any]:
 def main(argv: Optional[List[str]] = None) -> int:
     argv = argv if argv is not None else sys.argv[1:]
     if not argv:
-        print("usage: trace_reader <trace.pbp|archive.ptf2> "
+        print("usage: trace_reader <trace.pbp> "
               "[--ctf out.json] [--csv out.csv] [--svg out.svg]\n"
               "       trace_reader --check-comms <rank0.pbp> <rank1.pbp> ...\n"
               "       trace_reader --merge out.json <rank0.pbp> "
@@ -469,7 +449,7 @@ def main(argv: Optional[List[str]] = None) -> int:
               f"{len(flows['unmatched_tx'])} unmatched tx, "
               f"{len(flows['unmatched_rx'])} unmatched rx")
         return 1 if flows["unmatched_tx"] or flows["unmatched_rx"] else 0
-    trace = read_trace(argv[0])
+    trace = read_pbp(argv[0])
     print(f"trace: {len(trace.dictionary)} keywords, "
           f"{len(trace.streams)} streams, "
           f"{sum(len(s['events']) for s in trace.streams)} events")

@@ -123,6 +123,7 @@ def install_native_counters() -> None:
     from ..serving import reconcile as _rec
     from ..tools import flight as _fl
     from . import native_trace as _nt
+    from . import xla_trace as _xt
     from .hist import install_hist_counters
 
     def _sampler(stats, key):
@@ -167,6 +168,12 @@ def install_native_counters() -> None:
     for key in _dnative.COH_COUNTER_KEYS:
         counters.register(f"ptdev.{key}",
                           sampler=_dnative.coh_counter_sampler(key))
+    # the account of the newest pool that ended on a device lane with the
+    # spans on (ISSUE 37): where the lane's manager thread spent its life
+    for key in _xt.POOL_ACCOUNT_FIELDS:
+        counters.register(
+            f"ptdev.pool.{key}", sampler=lambda key=key: (
+                _xt.POOL_ACCOUNTS[-1][key] if _xt.POOL_ACCOUNTS else 0))
     # the scheduler plane's C-side counters (summed across live planes):
     # steals, spills, served, queued, admission stalls — ISSUE 9
     for key in _sp.PLANE_COUNTER_KEYS:

@@ -59,17 +59,21 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     # ``group_tasks`` is no time: one record per multi-task program, its size
     # ``writeback_ns`` (ISSUE 35): the dirty branch of an eviction, one
     # record a tile written back to the host
+    # ``gather_ns``, ``call_ns`` (ISSUE 37): the two halves of ``submit_ns``,
+    # the inputs made resident and pinned, then the program's call
     "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns",
-               "group_tasks", "writeback_ns"),
+               "group_tasks", "writeback_ns", "gather_ns", "call_ns"),
     "dtd": ("link_ns", "stall_ns"),
     # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
     # lanes, and the ptdev manager's dispatch / stage-in / poll / retire
     # ``pins`` and ``inflight`` are no times: one record each per dispatch
     # callback, the table pins it took and the programs already in flight
     # when it was called
+    # ``push_ns`` (one record a dispatch callback) and ``call_ns`` (one a
+    # device program) are the two named parts of ``dispatch_ns`` (ISSUE 37)
     "ptg": ("lower_ns",),
     "ptdev": ("dispatch_ns", "stage_in_ns", "poll_ns", "retire_ns", "pins",
-              "inflight"),
+              "inflight", "push_ns", "call_ns"),
 }
 
 

@@ -14,8 +14,8 @@ This module drains those rings and lands the events into the existing
   the low bit distinguishing START/END exactly like every other keyword;
 * each (lane, ring) pair becomes a per-worker **profiling stream**
   (``ptexec-w0`` …), so :mod:`parsec_tpu.tools.trace_reader` (summary,
-  CSV, chrome://tracing/Perfetto JSON) and the PTF2 backend consume
-  native-lane runs unchanged;
+  CSV, chrome://tracing/Perfetto JSON) consumes native-lane runs
+  unchanged;
 * each drain that landed events fires coarse ``SCHEDULE_BEGIN/END``
   PINS batch markers (a :class:`NativeDrainMarker`, NOT per-task events)
   so existing ``pins_modules`` consumers observe lane activity — exact

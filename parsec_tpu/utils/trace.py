@@ -32,12 +32,6 @@ from . import mca, output
 
 mca.register("profile_enabled", False, "Record runtime events", type=bool)
 mca.register("profile_filename", "parsec_tpu.pbp", "Trace output path")
-mca.register("profile_backend", "pbp",
-             "Trace output format: 'pbp' (flat binary file) or 'ptf2' "
-             "(archive directory following OTF2's architecture: anchor + "
-             "global defs + per-location event files — a PRIVATE format, "
-             "not OTF2 interchange; the profiling_otf2.c role). 'otf2' is "
-             "accepted as a deprecated alias and warns.", type=str)
 
 MAGIC = b"PTPBP001"
 
@@ -137,25 +131,10 @@ class Profiling:
         return struct.pack(e.fmt, *[kw.get(n, 0) for n, _ in e.fields])
 
     # -- output ------------------------------------------------------------------
-    def dump(self, path: Optional[str] = None,
-             backend: Optional[str] = None) -> str:
-        """Write the trace (ref: dbp file writing at parsec_fini). The
-        backend — flat PBP file or PTF2 archive (OTF2-architecture,
-        private format) — is chosen by
-        ``backend`` / ``--mca profile_backend`` (profiling_otf2.c role)."""
+    def dump(self, path: Optional[str] = None) -> str:
+        """Write the trace as a flat PBP file (ref: dbp file writing at
+        parsec_fini)."""
         path = path or mca.get("profile_filename", "parsec_tpu.pbp")
-        backend = backend or mca.get("profile_backend", "pbp")
-        if backend == "otf2":
-            output.warning(
-                "profile_backend 'otf2' is a deprecated alias for 'ptf2' — "
-                "the archive follows OTF2's architecture but is NOT "
-                "readable by OTF2 tools (use tools/trace_reader)")
-            backend = "ptf2"
-        if backend == "ptf2":
-            from .trace_ptf2 import write_archive
-            return write_archive(self, path)
-        if backend != "pbp":
-            raise ValueError(f"unknown profile_backend {backend!r}")
         with self._lock:
             buf = io.BytesIO()
             buf.write(MAGIC)

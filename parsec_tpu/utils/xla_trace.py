@@ -14,10 +14,12 @@ Usage::
 or annotate spans manually through :class:`TaskAnnotator` (a PINS module).
 
 :class:`Spans` is the runtime's own instrumentation of the device paths:
-seven named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``;
+nine named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``;
+``dev.gather`` and ``dev.call`` are the two halves of ``dev.submit``;
 ``dev.writeback``, the dirty branch of an eviction, is both managers')
-and four on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
-instantiation, and the ``ptdev`` manager's dispatch, poll and retire),
+and six on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
+instantiation; ``device/lane_pool.py``: the ``ptdev`` manager's dispatch,
+with its push phase and each program's call inside it, poll and retire),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
 alone: the ready-wait, and ``ptdev.stage_in_ns`` (a miss of the lane's
@@ -26,13 +28,19 @@ counts filed the same way: ``tpudev.group_tasks``, ``ptdev.pins``,
 ``ptdev.inflight`` and ``ptexec.region_tasks``.
 One object per ``Context``, ``None`` when off, so a site is
 ``sp = self._spans`` / ``if sp is not None:``.
+
+With the spans on, a pool of the ``ptdev`` lane also keeps an account of
+the manager thread's time and files it at its end
+(:func:`file_pool_account`, :data:`POOL_ACCOUNTS`; docs/observability.md,
+"A pool's account").
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 from time import perf_counter_ns
-from typing import Iterator, List, Optional, Tuple
+from typing import Deque, Dict, Iterator, List, Optional, Tuple
 
 from ..core import pins as P
 from . import mca, output
@@ -62,9 +70,51 @@ DTD_LINK, DTD_STALL = "dtd.link", "dtd.stall"
 DEV_SUBMIT, DEV_STAGE_IN = "dev.submit", "dev.stage_in"
 DEV_POLL, DEV_RETIRE = "dev.poll", "dev.retire"
 DEV_WRITEBACK = "dev.writeback"
+DEV_GATHER, DEV_CALL = "dev.gather", "dev.call"     # inside dev.submit
 PTG_LOWER = "ptg.lower"
 PTDEV_DISPATCH = "ptdev.dispatch"
+PTDEV_PUSH, PTDEV_CALL = "ptdev.push", "ptdev.call"  # inside ptdev.dispatch
 PTDEV_POLL, PTDEV_RETIRE = "ptdev.poll", "ptdev.retire"
+
+#: the fields of a pool's account, in the order they are told: nanoseconds
+#: of the lane's manager thread that add up to ``life_ns`` (``head_ns`` is
+#: the part of ``life_ns`` before the first program's call), then counts
+POOL_ACCOUNT_FIELDS = ("life_ns", "head_ns", "push_ns", "call_ns", "own_ns",
+                       "poll_ns", "retire_ns", "away_ns", "programs",
+                       "callbacks", "passes", "tasks")
+#: the accounts of the last pools that ended on a ``ptdev`` lane with the
+#: spans on, oldest first. Process-wide, so it outlives ``ctx.fini()`` as
+#: the folded histograms do; appended on a lane's manager thread, read by
+#: anyone (the registry serves the newest as ``ptdev.pool.<field>``)
+POOL_ACCOUNTS: Deque[Dict[str, int]] = collections.deque(maxlen=64)
+
+
+def file_pool_account(bound: int, first_call: int, end: int, *,
+                      dispatch_ns: int, push_ns: int, call_ns: int,
+                      poll_ns: int, retire_ns: int, programs: int,
+                      callbacks: int, passes: int, tasks: int
+                      ) -> Dict[str, int]:
+    """File the account of a pool that was bound at the clock ``bound``,
+    entered its first ``ptdev.call`` at ``first_call`` (0: it never did)
+    and ended at ``end``, from the nanoseconds of its callbacks' spans
+    (``poll_ns`` less the retirements, as ``ptdev.poll_ns`` records it).
+    ``own_ns`` is what a ``dispatch`` callback spends outside its push
+    phase and its calls; ``away_ns`` the manager thread outside this
+    pool's callbacks: the engine's release walk, the C lane's wake-ups and
+    waits, other pools. ``push + call + own + poll + retire + away`` is
+    ``life`` to the nanosecond."""
+    life = end - bound
+    account = {
+        "life_ns": life,
+        "head_ns": first_call - bound if first_call else life,
+        "push_ns": push_ns, "call_ns": call_ns,
+        "own_ns": dispatch_ns - push_ns - call_ns,
+        "poll_ns": poll_ns, "retire_ns": retire_ns,
+        "away_ns": life - dispatch_ns - poll_ns - retire_ns,
+        "programs": programs, "callbacks": callbacks, "passes": passes,
+        "tasks": tasks}
+    POOL_ACCOUNTS.append(account)
+    return account
 
 
 class Spans:
@@ -93,6 +143,8 @@ class Spans:
         self.retire = tpudev.cell("retire_ns")
         self.group_tasks = tpudev.cell("group_tasks")
         self.writeback = tpudev.cell("writeback_ns")
+        self.gather = tpudev.cell("gather_ns")
+        self.call = tpudev.cell("call_ns")
         self.link = dtd.cell("link_ns")
         self.stall = dtd.cell("stall_ns")
         self._ready = ready.cell("ready_wait_ns")
@@ -105,6 +157,8 @@ class Spans:
         self.pt_retire = ptdev.cell("retire_ns")
         self.pt_pins = ptdev.cell("pins")
         self.pt_inflight = ptdev.cell("inflight")
+        self.pt_push = ptdev.cell("push_ns")
+        self.pt_call = ptdev.cell("call_ns")
         # a fused region's members, one record a region a pool binds:
         # filed beside the ptexec lane's own histograms, as the ready-wait
         # is beside ptdtd's

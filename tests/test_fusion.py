@@ -666,7 +666,7 @@ def test_region_trace_intervals_land_in_pbp(tmp_path):
         ctx.fini()
         mca.params.unset("profile_enabled")
         mca.params.unset("profile_filename")
-    df = trace_reader.to_dataframe(trace_reader.read_trace(pbp))
+    df = trace_reader.to_dataframe(trace_reader.read_pbp(pbp))
     assert int((df["name"] == "ptexec::region").sum()) == nregions
     assert int((df["name"] == "ptexec::task").sum()) >= 1   # seams too
 

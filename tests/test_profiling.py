@@ -215,100 +215,6 @@ def test_comm_trace_2rank_check_comms(tmp_path):
     assert trace_reader.main(["--check-comms", *paths]) == 0
 
 
-# ------------------------------------------------- OTF2-class backend
-
-def test_otf2_archive_roundtrip(ctx, tmp_path):
-    """The second trace backend (profiling_otf2.c role): same tracer state
-    written as a PTF2 archive (anchor + global defs + per-location event
-    files, varint/delta encoded) reads back IDENTICAL to the PBP file
-    through the shared analysis pipeline."""
-    import os
-
-    from parsec_tpu.tools.trace_reader import (read_pbp, read_trace,
-                                               to_chrome_trace, to_dataframe)
-
-    prof = Profiling()
-    TaskProfiler(prof).enable(ctx)
-    _run_chain(ctx, 8)
-
-    pbp = prof.dump(str(tmp_path / "t.pbp"))
-    arch = prof.dump(str(tmp_path / "t"), backend="otf2")
-    assert os.path.isdir(arch) and arch.endswith(".ptf2")
-    assert os.path.exists(os.path.join(arch, "anchor.json"))
-    assert os.path.exists(os.path.join(arch, "global.defs"))
-    assert any(f.startswith("loc_") for f in os.listdir(arch))
-
-    a = read_pbp(pbp)
-    b = read_trace(arch)
-    assert [d["name"] for d in a.dictionary] == [d["name"] for d in b.dictionary]
-    assert [s["name"] for s in a.streams] == [s["name"] for s in b.streams]
-    dfa, dfb = to_dataframe(a), to_dataframe(b)
-    assert len(dfa) == len(dfb) == 8
-    # timestamps survive the ns-tick delta encoding to <1us
-    assert (abs(dfa["duration"] - dfb["duration"]) < 1e-6).all()
-    assert list(dfa["name"]) == list(dfb["name"])
-    ctf = to_chrome_trace(b)
-    assert len([e for e in ctf["traceEvents"] if e["ph"] == "X"]) == 8
-
-
-def test_otf2_backend_via_mca(ctx, tmp_path):
-    """--mca profile_backend otf2 flips the default dump format."""
-    from parsec_tpu.utils import mca
-
-    prof = Profiling()
-    TaskProfiler(prof).enable(ctx)
-    _run_chain(ctx, 4)
-    mca.set("profile_backend", "otf2")
-    try:
-        out = prof.dump(str(tmp_path / "m"))
-    finally:
-        mca.params.unset("profile_backend")
-    import os
-    assert os.path.isdir(out)
-    with pytest.raises(ValueError):
-        prof.dump(str(tmp_path / "x"), backend="hdf5")
-
-
-def test_check_comms_reads_otf2_archives(tmp_path):
-    """check-comms is format-agnostic: rank traces written as PTF2 archives
-    validate the same as PBP files."""
-    import numpy as np
-
-    from parsec_tpu.comm.remote_dep import RemoteDepEngine
-    from parsec_tpu.comm.threads import ThreadsCE, run_distributed
-    from parsec_tpu.core.context import Context
-    from parsec_tpu.data.matrix import TwoDimBlockCyclic
-    from parsec_tpu.ops.gemm import insert_gemm_tasks
-    from parsec_tpu.tools.trace_reader import check_comms
-
-    N, TS = 32, 16
-
-    def program(rank, fabric):
-        ctx = Context(nb_cores=1, my_rank=rank, nb_ranks=2)
-        ctx.profiling = Profiling()
-        RemoteDepEngine(ctx, ThreadsCE(fabric, rank))
-        kw = dict(nodes=2, myrank=rank, P=2, Q=1)
-        A = TwoDimBlockCyclic("o2A", N, N, TS, TS, **kw)
-        B = TwoDimBlockCyclic("o2B", N, N, TS, TS, **kw)
-        C = TwoDimBlockCyclic("o2C", N, N, TS, TS, **kw)
-        rng = np.random.default_rng(1)
-        A.fill(lambda m, n: rng.standard_normal((TS, TS)).astype(np.float32))
-        B.fill(lambda m, n: rng.standard_normal((TS, TS)).astype(np.float32))
-        C.fill(lambda m, n: np.zeros((TS, TS), np.float32))
-        tp = DTDTaskpool(ctx, "otf2comm")
-        insert_gemm_tasks(tp, A, B, C)
-        tp.wait(timeout=60)
-        tp.close()
-        ctx.wait(timeout=30)
-        ctx.fini()
-        return ctx.profiling.dump(str(tmp_path / f"r{rank}"), backend="otf2")
-
-    paths = run_distributed(2, program, timeout=120)
-    summary = check_comms(paths)
-    assert summary["errors"] == [], summary
-    assert summary["counts"]["activate_snd"] > 0
-
-
 def test_dag_svg_render(ctx, tmp_path):
     """The dbp-dot2png role without graphviz: the executed DAG renders to a
     self-contained SVG with layered nodes and dependency arrows."""
@@ -325,15 +231,15 @@ def test_dag_svg_render(ctx, tmp_path):
 
 def test_animated_gantt_svg(ctx, tmp_path):
     """The trace-animation role (tools/profiling/animation.c): a
-    self-drawing Gantt SVG with SMIL timing, from either trace format."""
+    self-drawing Gantt SVG with SMIL timing."""
     from parsec_tpu.tools import trace_reader
-    from parsec_tpu.tools.trace_reader import read_trace, to_animated_svg
+    from parsec_tpu.tools.trace_reader import read_pbp, to_animated_svg
 
     prof = Profiling()
     TaskProfiler(prof).enable(ctx)
     _run_chain(ctx, 6)
     path = prof.dump(str(tmp_path / "anim.pbp"))
-    svg = to_animated_svg(read_trace(path))
+    svg = to_animated_svg(read_pbp(path))
     assert svg.count("<rect") == 6
     assert svg.count("<set attributeName=") == 6       # SMIL playback
     out = str(tmp_path / "anim.svg")
@@ -440,51 +346,6 @@ def test_trace_perf_bench_runs():
     assert got["value"] > 10_000                      # trivially exceeded
     assert got["n_events"] == 2000 + 2 * (2000 // 2) + 2000 + 2000 // 10
     assert got["dump_events_per_sec"] > 0 and got["read_events_per_sec"] > 0
-
-
-def test_mem_view_reads_ptf2_archive(ctx, tmp_path):
-    """mem_view consumes the OTF2-class backend identically to PBP."""
-    from parsec_tpu.device.tpu import TPUDevice
-    from parsec_tpu.tools import mem_view
-    from parsec_tpu.tools.trace_reader import read_trace
-    from parsec_tpu.utils import mca
-
-    # reuse the tracer state by emitting synthetic ::mem events
-    prof = Profiling()
-    key, _ = prof.add_dictionary_keyword("dev0::mem",
-                                         info_desc="resident{q};delta{q}")
-    s = prof.stream("dev0")
-    from parsec_tpu.utils.trace import EVENT_FLAG_POINT
-    run = 0
-    for i, d in enumerate([1024, 2048, -1024, 512]):
-        run += d
-        s.trace(key, i, 0, EVENT_FLAG_POINT,
-                prof.pack_info("dev0::mem", resident=run, delta=d))
-
-    pbp = prof.dump(str(tmp_path / "m.pbp"))
-    arch = prof.dump(str(tmp_path / "m"), backend="otf2")
-    rows_pbp = mem_view.memory_timeline(read_trace(pbp))
-    rows_otf = mem_view.memory_timeline(read_trace(arch))
-    assert [(r["resident"], r["delta"]) for r in rows_pbp] == \
-        [(r["resident"], r["delta"]) for r in rows_otf] == \
-        [(1024, 1024), (3072, 2048), (2048, -1024), (2560, 512)]
-    assert mem_view.summarize(read_trace(arch))["dev0"]["peak"] == 3072
-
-
-def test_ptf2_is_the_backend_name_and_otf2_warns(ctx, tmp_path):
-    """The second backend is named for what it is (a private
-    OTF2-architecture format): 'ptf2' selects it; 'otf2' still works as a
-    deprecated alias."""
-    from parsec_tpu.utils.trace import Profiling
-    prof = Profiling()
-    TaskProfiler(prof).enable(ctx)
-    _run_chain(ctx, 3)
-    arch = prof.dump(str(tmp_path / "p"), backend="ptf2")
-    assert arch.endswith(".ptf2")
-    import os
-    assert os.path.isdir(arch)
-    arch2 = prof.dump(str(tmp_path / "q"), backend="otf2")   # alias
-    assert os.path.isdir(arch2)
 
 
 def test_hw_counters_pins_module(ctx):

@@ -819,7 +819,8 @@ def test_the_benchmarks_reader_gives_the_early_share_or_nothing(monkeypatch):
     monkeypatch.delitem(PTDEV_STATS, "released_early")
     assert reader.read(None) is None
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        entry = json.load(f)["per_layer"][-1]
+        entry, = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == "early_release_share"]
     assert entry == {"name": "early_release_share", "unit": "%",
                      "better": "higher", "source": "program_counter",
                      "layer": "device issue", "moves": "tasks_per_s",

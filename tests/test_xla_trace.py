@@ -4,6 +4,7 @@ import glob
 import os
 
 import numpy as np
+import pytest
 
 from parsec_tpu.core.context import Context
 from parsec_tpu.dsl.dtd import DTDTaskpool, RW
@@ -69,47 +70,18 @@ def test_task_annotator_fires_once_per_task_on_the_native_per_task_lane():
     finally:
         mca.params.unset("device_tpu_over_cpu")
     if not native:
-        import pytest
         pytest.skip("native _ptdtd unavailable")
     assert not batched
     assert Counting.begun == Counting.ended == 12 and not ann._open
 
 
-def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path):
-    """A jax.profiler trace of a small DTD pool with the spans on: the
-    span names are TraceMe events of a host plane, and on each thread any
-    two of them are disjoint or one inside the other."""
+def _host_plane_spans(logdir, names):
+    """The events named in ``names`` of the trace under ``logdir``: how
+    often each was seen and the names each stood directly inside; any two
+    of one thread are disjoint or nested, and all are on a host plane."""
     from jax.profiler import ProfileData
 
-    from parsec_tpu.data.matrix import TiledMatrix
-    from parsec_tpu.utils import mca
-    from parsec_tpu.utils import xla_trace as X
-
-    params = {"device_tpu_over_cpu": True, "hist_enabled": True,
-              "dtd_window_size": 8, "dtd_threshold_size": 4}
-    for k, v in params.items():
-        mca.set(k, v)
-    try:
-        ctx = Context(nb_cores=1)
-        A = TiledMatrix("XS", 64, 16, 16, 16)
-        A.fill(lambda m, n: np.ones((16, 16), np.float32))
-        with xla_trace(str(tmp_path)):
-            tp = DTDTaskpool(ctx, "xt-spans")
-
-            def body(x):
-                return x + 1.0
-
-            for i in range(32):
-                tp.insert_task(body, (tp.tile_of(A, i % 4, 0), RW))
-            tp.wait(); tp.close(); ctx.wait()
-        dev = next(d for d in ctx.devices.devices if d.name.startswith("tpu"))
-        ctx.fini()
-    finally:
-        for k in params:
-            mca.params.unset(k)
-    names = {X.DTD_LINK, X.DTD_STALL, X.DEV_SUBMIT, X.DEV_STAGE_IN,
-             X.DEV_POLL, X.DEV_RETIRE}
-    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+    path = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
                      recursive=True)[0]
     seen, parents = {}, {}
     for plane in ProfileData.from_file(path).planes:
@@ -127,12 +99,102 @@ def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path):
                     assert e <= stack[-1][1], (name, stack[-1][2])
                     parents.setdefault(name, set()).add(stack[-1][2])
                 stack.append((s, e, name))
+    return seen, parents
+
+
+def _traced_dtd_pool(ctx, logdir):
+    from parsec_tpu.data.matrix import TiledMatrix
+
+    A = TiledMatrix("XS", 64, 16, 16, 16)
+    A.fill(lambda m, n: np.ones((16, 16), np.float32))
+    with xla_trace(logdir):
+        tp = DTDTaskpool(ctx, "xt-spans")
+
+        def body(x):
+            return x + 1.0
+
+        for i in range(32):
+            tp.insert_task(body, (tp.tile_of(A, i % 4, 0), RW))
+        tp.wait(); tp.close(); ctx.wait()
+
+
+def _traced_ptg_pool(ctx, logdir):
+    """``ex06``'s GEMM, 2 x 2 x 2 tiles, as one fused region on ``ptdev``."""
+    import sys
+
+    from parsec_tpu.data.matrix import TiledMatrix
+    from parsec_tpu.dsl.ptg.compiler import compile_ptg
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "examples"))
+    import ex06_gemm_ptg
+
+    mats = {}
+    for name in "ABC":
+        M = TiledMatrix(f"xp{name}", 32, 32, 16, 16)
+        M.fill(lambda m, n: np.ones((16, 16), np.float32))
+        mats["desc" + name] = M
+    prog = compile_ptg(ex06_gemm_ptg.SRC, "xt-ptg")
+    with xla_trace(logdir):
+        tp = prog.instantiate(ctx, globals={"MT": 2, "NT": 2, "KT": 2},
+                              collections=mats)
+        ctx.add_taskpool(tp)
+        ctx.wait(timeout=120)
+    assert tp.completed and ctx._ptdev.failed() is None
+
+
+@pytest.mark.parametrize("path", ["dtd", "ptg"])
+def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path, path):
+    """A jax.profiler trace of a small pool with the spans on, through the
+    per-task manager and through the ``ptdev`` lane: the span names are
+    TraceMe events of a host plane, on each thread any two of them are
+    disjoint or one inside the other, and the sub-spans of ISSUE 37 stand
+    where they should: ``dev.gather`` / ``dev.call`` inside
+    ``dev.submit``, ``ptdev.push`` / ``ptdev.call`` inside
+    ``ptdev.dispatch``, a stage-in miss inside the gather or the push."""
+    from parsec_tpu import native as native_mod
+    from parsec_tpu.utils import mca
+    from parsec_tpu.utils import xla_trace as X
+
+    if path == "ptg" and (native_mod.load_ptexec() is None
+                          or native_mod.load_ptdev() is None):
+        pytest.skip("native _ptexec/_ptdev unavailable")
+    params = {"device_tpu_over_cpu": True, "hist_enabled": True,
+              "dtd_window_size": 8, "dtd_threshold_size": 4}
+    for k, v in params.items():
+        mca.set(k, v)
+    try:
+        ctx = Context(nb_cores=1)
+        (_traced_dtd_pool if path == "dtd" else _traced_ptg_pool)(
+            ctx, str(tmp_path))
+        dev = next(d for d in ctx.devices.devices if d.name.startswith("tpu"))
+        ctx.fini()
+    finally:
+        for k in params:
+            mca.params.unset(k)
+    if path == "ptg":
+        names = {X.PTG_LOWER, X.PTDEV_DISPATCH, X.PTDEV_PUSH, X.PTDEV_CALL,
+                 X.PTDEV_POLL, X.PTDEV_RETIRE, X.DEV_STAGE_IN}
+        seen, parents = _host_plane_spans(str(tmp_path), names)
+        assert set(seen) == names
+        assert seen[X.PTDEV_PUSH] == seen[X.PTDEV_DISPATCH]  # one a callback
+        assert seen[X.PTDEV_CALL] == seen[X.PTDEV_RETIRE] == 1  # one region
+        assert seen[X.DEV_STAGE_IN] == 12       # A, B, C: every tile a miss
+        assert parents[X.PTDEV_PUSH] == parents[X.PTDEV_CALL] \
+            == {X.PTDEV_DISPATCH}
+        assert parents[X.DEV_STAGE_IN] == {X.PTDEV_PUSH}
+        assert parents[X.PTDEV_RETIRE] == {X.PTDEV_POLL}
+        assert X.PTDEV_DISPATCH not in parents
+        return
+    names = {X.DTD_LINK, X.DTD_STALL, X.DEV_SUBMIT, X.DEV_STAGE_IN,
+             X.DEV_POLL, X.DEV_RETIRE, X.DEV_GATHER, X.DEV_CALL}
+    seen, parents = _host_plane_spans(str(tmp_path), names)
     assert set(seen) == names
     assert seen[X.DTD_LINK] == seen[X.DEV_RETIRE] == 32
     # one dev.submit span a program: a group of tasks is issued under one
-    assert seen[X.DEV_SUBMIT] == \
+    assert seen[X.DEV_SUBMIT] == seen[X.DEV_GATHER] == seen[X.DEV_CALL] == \
         32 - dev.batched_tasks + dev.batched_dispatches
     assert seen[X.DEV_STAGE_IN] == 4
-    assert parents[X.DEV_STAGE_IN] == {X.DEV_SUBMIT}
+    assert parents[X.DEV_GATHER] == parents[X.DEV_CALL] == {X.DEV_SUBMIT}
+    assert parents[X.DEV_STAGE_IN] == {X.DEV_GATHER}
     assert parents[X.DEV_RETIRE] == {X.DEV_POLL}
     assert X.DTD_STALL in parents[X.DEV_SUBMIT]
