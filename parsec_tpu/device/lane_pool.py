@@ -78,9 +78,10 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
 
     * ``dispatch(ids)`` — the push+exec phases of the reference's stream
       pipeline (device_gpu.c:3438) on XLA's async runtime: FIRST every
-      memory operand of the whole batch stages in (version-checked
-      through the C coherency table; ``device_put`` is asynchronous, so
-      the transfers overlap compute already in flight), THEN each
+      memory operand of the whole batch stages in (version-checked key
+      by key through the C coherency table, the misses moved by ONE
+      ``device_put`` of the list, which is asynchronous, so the transfer
+      overlaps compute already in flight), THEN each
       program dispatches (async) and its future outputs land in the
       slots at once. A node under ``early`` is RELEASED here: its
       write-backs land (future arrays: ``Data.write_host`` blocks on
@@ -101,8 +102,10 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
       released node is complete once a node dispatched after it is.
 
     Residency is touched once per distinct memory operand of a BATCH:
-    the push phase's stage-in takes the operand's one pin (table +
-    ``readers``), ``held`` counts the programs in flight that read it,
+    the push phase's one ``lane_stage_in_batch`` takes the operand's one
+    pin (table + ``readers``; all given back if it raises, always-on
+    counts ``PTDEV_STATS["staged_tiles"]`` moved by ``["stage_in_puts"]``
+    calls), ``held`` counts the programs in flight that read it,
     and the pin is given back when the last of them retires or settles
     (or at the end of ``dispatch``, where no program of the batch reads
     it). Returns ``(dispatch, poll, drop, held)``; ``held`` is empty
@@ -153,18 +156,8 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             else:
                 e[0] += w
                 e[1] += ns
-
-        def _stage(mi):
-            t0 = _pc()
-            copy = dev.lane_stage_in(mem_datas[mi], pin=True)
-            nb = getattr(getattr(copy, "payload", None), "nbytes", 0)
-            _obs((_STG, shape_bucket(nb), "tpu"), 1, _pc() - t0)
-            return copy
     else:
         _obs = None
-
-        def _stage(mi):
-            return dev.lane_stage_in(mem_datas[mi], pin=True)
     sp = devlane.ctx._spans
     if sp is not None:
         pinned = [0]     # table pins taken so far, for ptdev.pins
@@ -178,17 +171,6 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             if not first_call[0]:
                 first_call[0] = tok[1]
             acct["call_ns"] += sp.end(tok, sp.pt_call)
-
-        # ptdev.stage_in: the push phase's misses only (a hit moves
-        # no bytes); on the timeline a miss is the dev.stage_in
-        # annotation of TPUDevice._stage_in_copy, inside ptdev.dispatch
-        def _stage(mi, _inner=_stage):
-            moved, t0 = dev.transfer_in_bytes, _pc()
-            copy = _inner(mi)
-            pinned[0] += 1      # every pin of the closure is a stage-in's
-            if dev.transfer_in_bytes != moved:
-                sp.pt_stage_in.record(_pc() - t0)
-            return copy
     # mi -> [device copy, programs in flight that read it, pins held]:
     # owned by the manager thread (dispatch and poll both run there
     # with the GIL, as _obs relies on), so no lock and no table call
@@ -196,19 +178,6 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     # while an earlier reader still flies joins the same entry; its
     # pins nest in the table as they always did.
     held: Dict[int, List[Any]] = {}
-
-    def _hold(mi, staged):
-        # pin=True: the eviction pin is taken inside the table's
-        # reserve critical section, so no peer thread's stage-in can
-        # evict this entry first, and staging tile k+1 of this very
-        # batch cannot evict tile k before the exec phase reads it
-        # (found by the verify drive: "dot got NoneType")
-        copy = _stage(mi)
-        h = held.get(mi)
-        if h is None:
-            h = held[mi] = [copy, 0, 0]
-        h[2] += 1
-        staged[mi] = h
 
     def _release(mi, h):
         del held[mi]
@@ -243,10 +212,15 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         inflight.append((i, events, wbs, vals, reads, w, ckey2, rel))
 
     def push(ids):
-        # PUSH phase: issue every memory-endpoint stage-in for the
-        # whole batch before any compute dispatch, each distinct
-        # operand once, pinned THE MOMENT it stages (_hold)
-        staged: Dict[int, List[Any]] = {}
+        # PUSH phase: every distinct memory operand of the whole batch is
+        # asked for at once, before any compute dispatch. The device
+        # decides key by key, pinning each operand INSIDE the table's
+        # reserve critical section (no peer thread's stage-in can evict it
+        # first, and staging tile k+1 of this very batch cannot evict tile
+        # k before the exec phase reads it: "dot got NoneType", found by
+        # the verify drive), and moves the misses in one device_put. If it
+        # raises it has given its pins back, and nothing is held here yet
+        staged: Dict[int, Any] = {}
         if _obs is not None and not inflight:
             # idle -> active: restart the amortization clock so idle
             # gaps between batches never land in any task's cost
@@ -256,15 +230,42 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 r = _dregs.get(i)
                 if r is not None:
                     for mi in r["ext_mems"]:
-                        if mi not in staged:
-                            _hold(mi, staged)
+                        staged[mi] = None
                     continue
                 i = _forig[i]
             base = slot_base[i]
             for dj in range(ndflows[cls_of[i]]):
                 r = in_refs[base + dj]
-                if r < -1 and (-2 - r) not in staged:
-                    _hold(-2 - r, staged)
+                if r < -1:
+                    staged[-2 - r] = None
+        if not staged:
+            return staged
+        t0 = _pc()
+        copies, moved, put_ns = dev.lane_stage_in_batch(
+            [mem_datas[mi] for mi in staged])
+        if moved:
+            PTDEV_STATS["staged_tiles"] += moved
+            PTDEV_STATS["stage_in_puts"] += 1
+        if sp is not None:
+            pinned[0] += len(copies)    # every pin here is a stage-in's
+            if moved:
+                # ptdev.stage_in: the misses only (a hit moves no bytes),
+                # each at its share of the one put; on the timeline the
+                # dev.stage_in annotation of TPUDevice._transfer
+                sp.pt_stage_in.record(put_ns // moved, moved)
+        if _obs is not None:
+            # one observation an operand, hit or miss, at its share
+            share = (_pc() - t0) / len(copies)
+            sizes = collections.Counter(
+                getattr(c.payload, "nbytes", 0) for c in copies)
+            for nb, n in sizes.items():
+                _obs((_STG, shape_bucket(nb), "tpu"), n, share * n)
+        for mi, copy in zip(staged, copies):
+            h = held.get(mi)
+            if h is None:
+                h = held[mi] = [copy, 0, 0]
+            h[2] += 1           # one table pin an operand a batch
+            staged[mi] = h
         return staged
 
     def issue(ids, staged):

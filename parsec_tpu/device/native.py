@@ -61,10 +61,15 @@ mca.register("device_native_poll_us", 100,
 #: reuses an input's. ``programs`` / ``released_early`` (ISSUE 36): device
 #: programs the lane's closures dispatched, and those released to the
 #: engine at dispatch (every successor a device node of the same lane).
+#: ``staged_tiles`` / ``stage_in_puts`` (ISSUE 38): the tiles the push
+#: phases' stage-ins moved onto the device (misses; a hit or an adoption
+#: moves nothing), and the ``device_put`` calls that moved them: one a
+#: batch that had a miss.
 PTDEV_STATS = LaneStats(lanes_up=0, pools_engaged=0, tasks_engaged=0,
                         pools_fallback=0, pools_ineligible=0,
                         donated=0, region_outputs=0,
-                        programs=0, released_early=0)
+                        programs=0, released_early=0,
+                        staged_tiles=0, stage_in_puts=0)
 
 #: live lanes, for the process-wide ``ptdev.*`` counter samplers
 _lanes: "weakref.WeakSet[NativeDeviceLane]" = weakref.WeakSet()

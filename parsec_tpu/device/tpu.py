@@ -488,49 +488,56 @@ class TPUDevice(DeviceModule):
     # ------------------------------------------------------------- internals
     def _stage_in_copy(self, data: Data, access: int,
                        pin: bool = False) -> DataCopy:
-        """Version-checked stage-in (ref: parsec_device_data_stage_in
-        device_gpu.c:1800). Returns the device-resident copy.
+        """Version-checked stage-in of one datum (ref:
+        parsec_device_data_stage_in device_gpu.c:1800): the decision
+        (:meth:`_stage_in_decide`), then the transfer if it said so
+        (:meth:`_transfer`, a list of one). Returns the device-resident
+        copy, pinned when ``pin`` — release with :meth:`unpin_copy`."""
+        copy, src = self._stage_in_decide(data, pin)
+        if src is None:
+            return copy
+        return self._transfer([(data, copy, src)], pin)[0][0]
 
-        With the native table up, the residency decision — is a copy of
-        exactly this version resident, and which victims must leave to
-        make room — is C's (CohTable.stage_in issues the early reserve of
-        the push stage); this method stays the transfer MECHANISM.
-        ``pin=True`` takes the eviction pin INSIDE the table's reserve
-        critical section (a concurrent stage-in on another thread could
-        otherwise evict this entry between the reserve and the caller's
-        pin) and bumps the Python reader count to match — release with
-        :meth:`unpin_copy`."""
-        dev_idx = self.device_index
-        copy = data.get_copy(dev_idx)
+    def _stage_in_decide(self, data: Data, pin: bool
+                         ) -> Tuple[Optional[DataCopy], Optional[DataCopy]]:
+        """One operand's residency decision, and everything about it that
+        moves no byte. With the native table up the decision is C's — is a
+        copy of exactly this version resident, and which victims must
+        leave to make room (CohTable.stage_in issues the early reserve of
+        the push stage) — and the victims are applied here, a dirty one
+        written back, before the caller transfers anything. ``pin=True``
+        takes the eviction pin INSIDE the table's reserve critical section
+        (a concurrent stage-in on another thread could otherwise evict
+        this entry between the reserve and the caller's pin).
+
+        Returns ``(copy, None)`` where the operand is resident as it
+        stands, the Python reader count bumped to match the table's pin: a
+        hit (LRU touched), or newest bytes that already live on this
+        device, adopted. Else ``(copy, newest)``: the device copy to
+        refill (or None) and the copy that holds the bytes to transfer,
+        for :meth:`_install` to finish."""
+        copy = data.get_copy(self.device_index)
         newest = data.newest_copy()
-        if self._ncoh is not None and newest is not None:
-            nbytes = _nbytes(newest.payload)
+        if newest is None:
+            raise RuntimeError(f"no valid copy to stage in for {data!r}")
+        hit = copy is not None and copy.version == newest.version and \
+            copy.coherency_state != COHERENCY_INVALID
+        if self._ncoh is not None:
             need, victims = self._ncoh.stage_in(
-                self.res_key(data), nbytes,
+                self.res_key(data), _nbytes(newest.payload),
                 newest.version & 0xFFFFFFFF, 0, 1 if pin else 0)
             if victims:
                 self._apply_victims(victims)
-            if not need and copy is not None and \
-                    copy.version == newest.version and \
-                    copy.coherency_state != COHERENCY_INVALID:
-                self._lru_touch(self.res_key(data), copy)
-                if pin:
-                    with self._heap_lock:
-                        copy.readers += 1     # table half pinned above
-                return copy
-            # table said transfer (or the mirror lost the payload: the
+            # the table said transfer, or the mirror lost the payload (the
             # stale table entry was already replaced by stage_in)
-        elif copy is not None and newest is not None and \
-                copy.version == newest.version and \
-                copy.coherency_state != COHERENCY_INVALID:
+            hit = hit and not need
+        if hit:
             self._lru_touch(self.res_key(data), copy)
             if pin:
-                self.pin_copy(copy)
-            return copy
-        src = newest
-        if src is None:
-            raise RuntimeError(f"no valid copy to stage in for {data!r}")
-        arr = src.payload
+                with self._heap_lock:
+                    copy.readers += 1     # the table half was pinned above
+            return copy, None
+        arr = newest.payload
         if isinstance(arr, self._jax.Array) and arr.committed and \
                 arr.devices() == {self.jax_device}:
             # adoption: the newest bytes already live on this device (a
@@ -544,18 +551,45 @@ class TPUDevice(DeviceModule):
             # region donates only slot values its own pool wrote and
             # nothing else reads (docs/device_lane.md, "Who owns what")
             self.adopted += 1
-            return self._install(data, copy, arr, src.version, pin,
-                                 moved=False)
+            return self._install(data, copy, arr, newest.version, pin,
+                                 moved=False), None
+        return copy, newest
+
+    def _transfer(self, misses: List[Tuple[Data, Optional[DataCopy],
+                                           DataCopy]], pin: bool
+                  ) -> Tuple[List[DataCopy], int]:
+        """Move the bytes of operands :meth:`_stage_in_decide` said to
+        transfer, each a ``(data, device copy or None, source copy)``, in
+        ONE ``device_put`` of the list (the TPU client's price is per
+        call: 254 us a 1-MiB tile alone, 183 in a list) and install the
+        arrays under one hold of the heap lock. Returns the device copies
+        in order and the nanoseconds of the put and its installs (0 with
+        the spans off), recorded as one observation a tile. If it raises,
+        the pins these operands took at their decision are given back."""
+        done: List[DataCopy] = []
+        put_ns = 0
         sp = self._spans
         if sp is not None:
-            tok = sp.begin(DEV_STAGE_IN)    # a miss: the host cost of one H2D
+            tok = sp.begin(DEV_STAGE_IN)    # the host cost of one H2D call
         try:
-            arr = self._jax.device_put(arr, self.jax_device)  # async H2D/D2D
-            return self._install(data, copy, arr, src.version, pin,
-                                 moved=True)
+            arrs = self._jax.device_put(    # async H2D/D2D
+                [src.payload for _data, _copy, src in misses],
+                self.jax_device)
+            with self._heap_lock:
+                for (data, copy, src), arr in zip(misses, arrs):
+                    done.append(self._install(data, copy, arr, src.version,
+                                              pin, moved=True))
+        except BaseException:
+            if pin:
+                for copy in done:
+                    self.unpin_copy(copy)
+                for data, _copy, _src in misses[len(done):]:
+                    self._coh_unpin(data)   # decided, never installed
+            raise
         finally:
             if sp is not None:
-                sp.end(tok, sp.stage_in)
+                put_ns = sp.end(tok, sp.stage_in, n=len(misses))
+        return done, put_ns
 
     def _install(self, data: Data, copy: Optional[DataCopy], arr: Any,
                  version: int, pin: bool, moved: bool) -> DataCopy:
@@ -575,19 +609,61 @@ class TPUDevice(DeviceModule):
             self.transfer_in_bytes += nbytes
         self._lru_touch(self.res_key(data), copy)
         if pin:
-            if self._ncoh is not None:
-                with self._heap_lock:
-                    copy.readers += 1     # table half pinned in stage_in
-            else:
-                self.pin_copy(copy)
+            with self._heap_lock:
+                copy.readers += 1     # the table half: pinned in stage_in
         return copy
 
     def lane_stage_in(self, data: Data, pin: bool = False) -> DataCopy:
-        """Stage-in entry for the native device lane's dispatch callback
-        (the push phase of ptdev): version-checked through the C table,
-        asynchronous, returns the device copy — pinned atomically with
-        the reserve when ``pin``."""
+        """One datum staged in through the lane's entry: version-checked
+        through the C table, asynchronous, returns the device copy —
+        pinned atomically with the reserve when ``pin``."""
         return self._stage_in_copy(data, 0, pin=pin)
+
+    def lane_stage_in_batch(self, datas: Sequence[Data]
+                            ) -> Tuple[List[DataCopy], int, int]:
+        """Stage-in entry for the native device lane's dispatch callback
+        (the push phase of ptdev): the distinct memory operands of a whole
+        batch at once. The decision is taken key by key and in order
+        (:meth:`_stage_in_decide`), each operand pinned atomically with
+        its reserve and its victims applied before a byte moves, so a
+        batch never evicts a tile of its own; the misses among them then
+        move in one transfer (:meth:`_transfer`).
+
+        Returns the copies in order, each pinned once, the tiles that
+        moved, and the nanoseconds of the put and its installs (0 with the
+        spans off). When anything raises, every pin the batch took is
+        given back."""
+        copies: List[Optional[DataCopy]] = []
+        misses, at = [], []
+        try:
+            with self._heap_lock:
+                for data in datas:
+                    copy, src = self._stage_in_decide(data, True)
+                    if src is not None:
+                        at.append(len(copies))
+                        misses.append((data, copy, src))
+                        copy = None
+                    copies.append(copy)
+        except BaseException:
+            for data, _copy, _src in misses:
+                self._coh_unpin(data)
+            self._unpin_all(copies)
+            raise
+        if not misses:
+            return copies, 0, 0
+        try:
+            moved, put_ns = self._transfer(misses, True)
+        except BaseException:
+            self._unpin_all(copies)     # the hits and the adoptions
+            raise
+        for i, copy in zip(at, moved):
+            copies[i] = copy
+        return copies, len(misses), put_ns
+
+    def _unpin_all(self, copies: Sequence[Optional[DataCopy]]) -> None:
+        for copy in copies:
+            if copy is not None:
+                self.unpin_copy(copy)
 
     def _prof(self):
         """Per-device profiling stream (ref: per-GPU-stream profiling

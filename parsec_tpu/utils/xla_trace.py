@@ -22,8 +22,9 @@ instantiation; ``device/lane_pool.py``: the ``ptdev`` manager's dispatch,
 with its push phase and each program's call inside it, poll and retire),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
-alone: the ready-wait, and ``ptdev.stage_in_ns`` (a miss of the lane's
-push phase, whose annotation is the ``dev.stage_in`` it nests), and four
+alone: the ready-wait, and ``ptdev.stage_in_ns`` (the misses of the
+lane's push phase, each at its share of the batch's one ``device_put``,
+whose annotation is the ``dev.stage_in`` the push nests), and four
 counts filed the same way: ``tpudev.group_tasks``, ``ptdev.pins``,
 ``ptdev.inflight`` and ``ptexec.region_tasks``.
 One object per ``Context``, ``None`` when off, so a site is

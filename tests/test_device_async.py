@@ -758,6 +758,7 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
     a, b, mats = _gemm_operands(f"po{bound}", 30)
     log, readers = [], {}
     monkeypatch.setattr(dev, "_ncoh", _TableSpy(dev._ncoh, log))
+    puts = _count_puts(dev, monkeypatch)
 
     def on_dispatch(ids, held):
         log.append(("batch", len(ids)))
@@ -796,6 +797,9 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
         assert len(keys) <= min(n * tiles, 3 * _NT * _NT)
     pins = sum(len(keys) for _, keys in batches)
     assert pins >= 3 * _NT * _NT
+    # the 48 host tiles move once each, a batch's misses in one put (ISSUE 38)
+    assert sum(puts.calls) == 3 * _NT * _NT
+    assert len(puts.calls) <= sum(1 for _, keys in batches if keys)
     assert not [e for e in log if e[0] == "pin"], "a pin per program operand"
     assert sum(1 for e in log if e[0] == "unpin") == pins
     # readers in flight count PROGRAMS: the A tiles of a row are read by its
@@ -867,11 +871,22 @@ def test_operand_in_flight_is_no_victim_after_a_reader_retired(dctx,
     _assert_unpinned(dev, mats)
 
 
+class _Puts(list):
+    """The arrays handed to ``device_put``, a list's members one by one;
+    ``calls`` holds how many each call carried."""
+
+    def __init__(self):
+        super().__init__()
+        self.calls = []
+
+
 def _count_puts(dev, monkeypatch):
-    puts, real = [], dev._jax.device_put
+    puts, real = _Puts(), dev._jax.device_put
 
     def device_put(x, *args, **kw):
-        puts.append(x)
+        members = x if isinstance(x, list) else [x]
+        puts.extend(members)
+        puts.calls.append(len(members))
         return real(x, *args, **kw)
     monkeypatch.setattr(dev._jax, "device_put", device_put)
     return puts
@@ -1046,6 +1061,269 @@ def test_pins_per_program_reader(monkeypatch, snapshot, want):
                      "better": "lower", "source": "program_counter",
                      "layer": "device issue", "moves": "tasks_per_s",
                      "workloads": ["ptg_gemm.ts512", "ptg_potrf.ts512"]}
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 38: the push phase asks for a batch's operands at once, and the
+# misses among them move in one device_put
+# ---------------------------------------------------------------------------
+
+_TILE = _TS * _TS * 4
+
+
+def _tiles(n, base=0.0):
+    """``n`` data, each with one host copy: a numpy tile of its own value."""
+    from parsec_tpu.data.data import data_from_array
+    return [data_from_array(np.full((_TS, _TS), base + i, np.float32))
+            for i in range(n)]
+
+
+def _hand_pool(devlane, reads, mem_datas):
+    """The lane's closures over independent tasks, for the test to drive by
+    hand on its own thread: task ``i`` adds the memory operands ``reads[i]
+    = (mi, mj)`` into its one written flow. A batch is what ``dispatch`` is
+    given. Returns ``(dispatch, drain, held, slots)``; ``drain()`` polls
+    until every dispatched task has come back."""
+    import time as _t
+    import jax
+    from parsec_tpu.device import lane_pool
+    n = len(reads)
+    in_refs = [r for a, b in reads for r in (-1, -2 - a, -2 - b)]
+    slots = [None] * (3 * n)
+    dispatch, poll, _drop, held = lane_pool._closures(
+        devlane, None, [0], [[()] * n], list(range(0, 3 * n, 3)), in_refs,
+        [3], [0] * n, [jax.jit(lambda o, a, b: (a + b,))], [(0,)],
+        ["hand.add"], slots, mem_datas, {}, None, 0, None, None, n)
+    sent = []
+
+    def send(ids):
+        n = dispatch(ids)
+        sent.extend(ids)
+        return n
+
+    def drain():
+        deadline = _t.monotonic() + 30
+        while sent and _t.monotonic() < deadline:
+            for i in poll():
+                sent.remove(i)
+        assert not sent
+    return send, drain, held, slots
+
+
+def _pins(dev, data):
+    """(table pins, ``readers`` of the device copy) of ``data``."""
+    st = dev._ncoh.state(dev.res_key(data))
+    copy = data.get_copy(dev.device_index)
+    return (0 if st is None else st[3], 0 if copy is None else copy.readers)
+
+
+def _batch_of_misses(dctx, monkeypatch):
+    """k misses: one ``device_put`` of the k tiles, k copies at the newest
+    versions, each pinned once in the table and in ``readers``, the bytes
+    and the table's misses counted, the lane's two counters up."""
+    from parsec_tpu.device.native import PTDEV_STATS
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    datas = _tiles(5)
+    datas[3].bump_version(0)
+    send, drain, held, slots = _hand_pool(
+        devlane, [(0, 1), (2, 3), (4, 0)], datas)
+    puts = _count_puts(dev, monkeypatch)
+    moved, misses, stats = dev.transfer_in_bytes, \
+        dev.coh_stats()["coh_misses"], PTDEV_STATS.snapshot()
+    assert send([0, 1, 2]) == 3
+    assert puts.calls == [5]
+    assert all(a is d.get_copy(0).payload for a, d in zip(puts, datas))
+    assert dev.transfer_in_bytes == moved + 5 * _TILE
+    assert dev.coh_stats()["coh_misses"] == misses + 5
+    for mi, d in enumerate(datas):
+        copy = d.get_copy(dev.device_index)
+        assert copy.version == d.version and held[mi][0] is copy
+        assert held[mi][2] == 1 and _pins(dev, d) == (1, 1)
+    assert [h[1] for h in held.values()] == [2, 1, 1, 1, 1]
+    delta = PTDEV_STATS.delta(stats)
+    assert (delta["staged_tiles"], delta["stage_in_puts"]) == (5, 1)
+    drain()
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3, 6)] == \
+        [1.0, 5.0, 4.0]
+    # nothing left to move: a second batch over the same operands puts none
+    send([0, 1, 2])
+    drain()
+    assert puts.calls == [5] and PTDEV_STATS.delta(stats)["stage_in_puts"] == 1
+
+
+def _mixed_batch(dctx, monkeypatch):
+    """Hits, an adoption, misses, and an operand that two programs (and one
+    of them twice) name: only the misses are in the put, the adoption is
+    counted, a hit's copy is the object it was, each operand pinned once."""
+    import jax
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    hit0, hit1, here, miss0, miss1, miss2 = datas = _tiles(6)
+    was = [dev.lane_stage_in(d) for d in (hit0, hit1)]
+    arr = jax.device_put(np.full((_TS, _TS), 7.0, np.float32), dev.jax_device)
+    here.get_copy(0).payload = arr
+    here.bump_version(0)
+    send, drain, held, slots = _hand_pool(
+        devlane, [(3, 3), (3, 0), (2, 4), (1, 5)], datas)
+    puts = _count_puts(dev, monkeypatch)
+    moved, adopted = dev.transfer_in_bytes, dev.adopted
+    send([0, 1, 2, 3])
+    assert puts.calls == [3] and len(puts) == 3
+    assert all(any(a is d.get_copy(0).payload for a in puts)
+               for d in (miss0, miss1, miss2))
+    assert dev.adopted == adopted + 1
+    assert dev.transfer_in_bytes == moved + 3 * _TILE
+    assert held[0][0] is was[0] and held[1][0] is was[1]
+    assert held[2][0].payload is arr
+    assert sorted(held) == list(range(6))
+    assert all(h[2] == 1 for h in held.values())
+    assert all(_pins(dev, d) == (1, 1) for d in datas)
+    assert held[3][1] == 3              # two programs read it, one twice
+    drain()
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3, 6, 9)] == \
+        [6.0, 3.0, 11.0, 6.0]
+
+
+def _batch_under_a_budget(dctx, monkeypatch):
+    """Four tiles of room. A batch of four new tiles evicts the four LRU
+    unpinned ones and none of its own, and the dirty one among them is on
+    the host, at its version, before the put is called. A batch of six
+    pins all six, skips what it cannot evict and leaves the rest to XLA."""
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    dev.set_budget(4 * _TILE, unit=1024)
+    old, new, more = _tiles(4), _tiles(4, 10.0), _tiles(6, 20.0)
+    datas = old + new + more
+    send, drain, held, slots = _hand_pool(
+        devlane, [(0, 1), (2, 3), (4, 5), (6, 7), (8, 9), (10, 11),
+                  (12, 13)], datas)
+    send([0, 1])
+    drain()
+    dirty = old[0].get_copy(dev.device_index)
+    dirty.payload = dirty.payload + 100.0       # a write on the device
+    old[0].bump_version(dev.device_index)
+    dev._coh_mark_owned(old[0], dirty)
+    assert dev._resident_bytes == 4 * _TILE and dev.evictions == 0
+    seen, real = [], dev._jax.device_put
+
+    def device_put(x, *args, **kw):
+        host = old[0].get_copy(0)
+        seen.append((len(x), dev.evictions, dev.owned_evictions,
+                     type(host.payload), float(host.payload[0, 0]),
+                     host.version == old[0].version))
+        return real(x, *args, **kw)
+    monkeypatch.setattr(dev._jax, "device_put", device_put)
+    out = dev.transfer_out_bytes
+    send([2, 3])
+    assert seen == [(4, 4, 1, np.ndarray, 100.0, True)]
+    assert dev.transfer_out_bytes == out + _TILE
+    assert all(d.get_copy(dev.device_index).payload is None for d in old)
+    assert all(_pins(dev, d) == (1, 1) and
+               d.get_copy(dev.device_index).payload is not None for d in new)
+    assert dev._resident_bytes == 4 * _TILE
+    drain()
+    skips = dev.coh_stats()["pinned_skips"]
+    send([4, 5, 6])
+    assert seen[-1][:2] == (6, 8)       # the four before it, none of its own
+    assert dev.coh_stats()["pinned_skips"] > skips
+    assert dev._resident_bytes == 6 * _TILE     # over: XLA's to carry
+    assert all(_pins(dev, d) == (1, 1) and
+               d.get_copy(dev.device_index).payload is not None for d in more)
+    drain()
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(slots[s])[0, 0]) for s in range(6, 21, 3)] == \
+        [21.0, 25.0, 41.0, 45.0, 49.0]
+
+
+def _a_put_that_raises(dctx, monkeypatch):
+    """The put fails: every pin the batch took, a hit's, an adoption's and
+    the misses' alike, is given back before the error surfaces, nothing is
+    held, and the same batch then stages in and runs."""
+    import jax
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    hit, here, miss0, miss1 = datas = _tiles(4)
+    dev.lane_stage_in(hit)
+    here.get_copy(0).payload = jax.device_put(
+        np.full((_TS, _TS), 7.0, np.float32), dev.jax_device)
+    here.bump_version(0)
+    send, drain, held, slots = _hand_pool(devlane, [(0, 2), (1, 3)], datas)
+
+    def device_put(x, *args, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: no room for this transfer")
+    with monkeypatch.context() as patch:
+        patch.setattr(dev._jax, "device_put", device_put)
+        with pytest.raises(RuntimeError, match="no room for this transfer"):
+            send([0, 1])
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert miss0.get_copy(dev.device_index) is None
+    puts = _count_puts(dev, monkeypatch)
+    send([0, 1])
+    assert puts.calls == [2] and all(_pins(dev, d) == (1, 1) for d in datas)
+    drain()
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3)] == [2.0, 10.0]
+
+
+def _batch_with_the_spans_on(dctx, monkeypatch):
+    """``ptdev.stage_in_ns`` and ``tpudev.stage_in_ns`` count the tiles that
+    moved, not the puts, ``ptdev.pins`` one a distinct operand, and the
+    pool's six parts add up to its life."""
+    from parsec_tpu.utils import xla_trace as X
+    from parsec_tpu.utils.hist import histograms
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    assert dctx._spans is not None
+    datas = _tiles(6)
+    dev.lane_stage_in(datas[5])
+    kept = list(X.POOL_ACCOUNTS)
+    try:
+        send, drain, held, _slots = _hand_pool(
+            devlane, [(0, 1), (2, 3), (4, 5), (5, 0)], datas)
+        puts = _count_puts(dev, monkeypatch)
+        s0 = histograms.snapshot()
+        send([0, 1, 2, 3])
+        drain()
+        s1 = histograms.snapshot()
+        acct = X.POOL_ACCOUNTS[-1]
+    finally:
+        X.POOL_ACCOUNTS.clear()
+        X.POOL_ACCOUNTS.extend(kept)
+
+    def grew(name, field):
+        return s1[name][field] - s0.get(name, {field: 0})[field]
+    assert puts.calls == [5]
+    assert grew("ptdev.stage_in_ns", "count") == 5
+    assert grew("tpudev.stage_in_ns", "count") == 5
+    # n equal shares of one put: the two histograms hold the same time
+    assert abs(grew("ptdev.stage_in_ns", "sum_ns")
+               - grew("tpudev.stage_in_ns", "sum_ns")) < 5
+    assert (grew("ptdev.pins", "count"), grew("ptdev.pins", "sum_ns")) == \
+        (1, 6)
+    assert grew("ptdev.push_ns", "sum_ns") >= \
+        grew("ptdev.stage_in_ns", "sum_ns") > 0
+    assert (acct["programs"], acct["callbacks"], acct["tasks"]) == (4, 1, 4)
+    assert acct["push_ns"] == grew("ptdev.push_ns", "sum_ns")
+    assert acct["life_ns"] == sum(acct[k] for k in (
+        "push_ns", "call_ns", "own_ns", "poll_ns", "retire_ns", "away_ns"))
+    assert held == {}
+
+
+@pytest.mark.parametrize("case, spans", [
+    (_batch_of_misses, False), (_mixed_batch, False),
+    (_batch_under_a_budget, False), (_a_put_that_raises, False),
+    (_batch_with_the_spans_on, True)],
+    ids=["misses", "mixed", "budget", "put-raises", "spans"])
+def test_the_push_phase_stages_a_batch_at_once(monkeypatch, case, spans):
+    mca.set("device_tpu_over_cpu", True)
+    if spans:
+        mca.set("hist_enabled", True)
+    ctx = Context(nb_cores=1)
+    try:
+        case(ctx, monkeypatch)
+    finally:
+        monkeypatch.undo()
+        ctx.fini()
+        mca.params.unset("hist_enabled")
+        mca.params.unset("device_tpu_over_cpu")
 
 
 # ---------------------------------------------------------------------------

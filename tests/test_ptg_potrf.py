@@ -825,3 +825,46 @@ def test_the_benchmarks_reader_gives_the_early_share_or_nothing(monkeypatch):
                      "better": "higher", "source": "program_counter",
                      "layer": "device issue", "moves": "tasks_per_s",
                      "workloads": ["ptg_gemm.ts512", "ptg_potrf.ts512"]}
+
+
+@pytest.mark.parametrize("path", ["regions", "per-task"])
+def test_the_lane_counts_the_tiles_it_moves_and_the_puts_that_move_them(
+        dctx, monkeypatch, path):
+    """ISSUE 38: the push phase of a ``dispatch`` callback moves its
+    batch's misses in one ``device_put``. Over a factorization the lane
+    moves the lower triangle's tiles, each once, in no more puts than the
+    callbacks that had a miss, and the factor is the reference's."""
+    from parsec_tpu.device import lane_pool
+    nt = {"regions": 8, "per-task": 5}[path]
+    knob, value = {"regions": ("region_fusion_max", 16),
+                   "per-task": ("region_fusion", False)}[path]
+    dev = _tpu_dev(dctx)
+    make, moved = lane_pool._closures, []
+
+    def spied(*args, **kw):
+        dispatch, poll, drop, held = make(*args, **kw)
+
+        def spy_dispatch(ids):
+            before = dev.transfer_in_bytes
+            try:
+                return dispatch(ids)
+            finally:
+                moved.append(dev.transfer_in_bytes - before)
+        return spy_dispatch, poll, drop, held
+    monkeypatch.setattr(lane_pool, "_closures", spied)
+    a, A = _matrix(nt, seed=38)
+    prog = compile_ptg(ops.POTRF_JDF, f"potrf-puts-{path}")
+    mca.set(knob, value)
+    try:
+        d0 = PTDEV_STATS.snapshot()
+        got = _factor(dctx, A, prog)
+        dd = PTDEV_STATS.delta(d0)
+    finally:
+        mca.params.unset(knob)
+    assert counters.read("ptdev.cb_errors") == 0
+    tiles = nt * (nt + 1) // 2
+    assert dd["staged_tiles"] == tiles
+    assert sum(moved) == tiles * TS * TS * 4
+    assert 1 <= dd["stage_in_puts"] == sum(1 for b in moved if b) < tiles
+    _assert_pins_given_back(dctx, [A])
+    _assert_factor(got, a)

@@ -178,7 +178,9 @@ def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path, path):
         assert set(seen) == names
         assert seen[X.PTDEV_PUSH] == seen[X.PTDEV_DISPATCH]  # one a callback
         assert seen[X.PTDEV_CALL] == seen[X.PTDEV_RETIRE] == 1  # one region
-        assert seen[X.DEV_STAGE_IN] == 12       # A, B, C: every tile a miss
+        # A, B, C: 12 tiles, every one a miss, in one put of the one callback
+        assert seen[X.DEV_STAGE_IN] == seen[X.PTDEV_PUSH] == 1
+        assert dev.transfer_in_bytes == 12 * 16 * 16 * 4
         assert parents[X.PTDEV_PUSH] == parents[X.PTDEV_CALL] \
             == {X.PTDEV_DISPATCH}
         assert parents[X.DEV_STAGE_IN] == {X.PTDEV_PUSH}
