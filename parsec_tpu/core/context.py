@@ -402,7 +402,13 @@ class Context:
         if tp.on_enqueue is not None:
             tp.on_enqueue(tp)
         if tp.startup_hook is not None:
-            startup = tp.startup_hook(self.streams[0], tp)
+            try:
+                startup = tp.startup_hook(self.streams[0], tp)
+            except BaseException:
+                # a pool that cannot start (a PTG region no device budget
+                # can hold) is the caller's error: wait() owes it nothing
+                self._taskpool_completed(tp)
+                raise
             if startup:
                 self.schedule(startup, self.streams[0])
         tp.termdet.taskpool_ready(tp)

@@ -13,6 +13,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 from ..utils.xla_trace import (PTDEV_CALL, PTDEV_DISPATCH, PTDEV_POLL,
                                PTDEV_PUSH, PTDEV_RETIRE, file_pool_account)
 from .native import PTDEV_STATS
+from .tpu import NoRoom
 
 
 def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
@@ -22,7 +23,8 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
          writebacks: Dict[int, List], dev_mask: Sequence[int],
          ndev_tasks: int, early: Optional[Sequence[int]] = None,
          fusion: Optional[Dict[str, Any]] = None, bucket: int = 0,
-         cost_obs: Optional[Dict] = None) -> Tuple[int, Dict[int, List]]:
+         cost_obs: Optional[Dict] = None,
+         tile: int = 0) -> Tuple[int, Dict[int, List]]:
     """Bind a flattened data pool to ``devlane`` (device/native.py): from
     then on a task of ``engine`` (``dev_bind``, ``dev_retire_capsule``,
     ``trace_mark``: the ``ptexec`` ``Graph``) whose ``dev_mask`` entry is
@@ -43,6 +45,7 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
     ``early[i]`` set (the caller's finding, from the graph's structure
     alone): node ``i`` has successors and every one of them is under the
     mask, so it is released when it is dispatched (:func:`_closures`).
+    ``tile``: no memory operand or write-back is larger (0: not known).
 
     ``fusion``, for an engine whose nodes are regions and seams:
     ``orig_of`` maps a node to its task, ``dev_regions`` a region's node
@@ -61,7 +64,7 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
     dispatch, poll, drop, held = _closures(
         devlane, engine, bases, params, slot_base, in_refs, ndflows, cls_of,
         fns, written, names, slots, mem_datas, writebacks, fusion, bucket,
-        cost_obs, early, ndev_tasks)
+        cost_obs, early, ndev_tasks, tile)
     pid = devlane.bind_pool(engine, dispatch, poll, drop)
     PTDEV_STATS["pools_engaged"] += 1
     PTDEV_STATS["tasks_engaged"] += ndev_tasks
@@ -72,23 +75,36 @@ def bind(devlane, engine, *, bases: Sequence[int], params, slot_base,
 
 def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
               cls_of, fns, written, names, slots, mem_datas, writebacks,
-              fusion, bucket, cost_obs, early, ndev_tasks):
+              fusion, bucket, cost_obs, early, ndev_tasks, tile=0):
     """The pool's dispatch/poll pair, both run on the lane's manager
     thread with the GIL held:
 
     * ``dispatch(ids)`` — the push+exec phases of the reference's stream
-      pipeline (device_gpu.c:3438) on XLA's async runtime: FIRST every
-      memory operand of the whole batch stages in (version-checked key
-      by key through the C coherency table, the misses moved by ONE
-      ``device_put`` of the list, which is asynchronous, so the transfer
-      overlaps compute already in flight), THEN each
+      pipeline (device_gpu.c:3438) on XLA's async runtime: FIRST, in a
+      pool UNDER PRESSURE (its memory and write-backs could pass the
+      room the residency budget has, ``TPUDevice.lane_room``, when it is
+      bound), the batch is ADMITTED by bytes: a program whose memory
+      operands beyond those pinned already, and whose outputs, do not fit
+      waits in the pool's BACKLOG, ahead of every program that surfaces
+      after it, until retirements give pins back; an admitted program
+      holds the room of its outputs in the table until it ends
+      (``lane_reserve``). Then every memory operand of the
+      admitted programs stages in (version-checked key by key through
+      the C coherency table, the misses moved by ONE ``device_put`` of
+      the list, which is asynchronous, so the transfer overlaps compute
+      already in flight), THEN each
       program dispatches (async) and its future outputs land in the
       slots at once. A node under ``early`` is RELEASED here: its
       write-backs land (future arrays: ``Data.write_host`` blocks on
-      nothing) and the next ``poll()`` reports its id, so the engine's
+      nothing; under pressure ``TPUDevice.lane_write_back``, the array
+      the data's newest copy, an OWNED resident of the table, which
+      blocks on nothing but an eviction it causes) and the next ``poll()``
+      reports its id, so the engine's
       release walk surfaces its successors, device nodes of this lane
       all, while it still runs; XLA queues each behind the producers
-      of its operands, donated ones included;
+      of its operands, donated ones included. It returns the number of
+      ids it was given: the C lane counts a waiting program in flight,
+      so it keeps polling;
     * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
       inflight program's outputs (cudaEventQuery, device_gpu.c:2593).
       Completed tasks write back to memory, give up their reads and
@@ -100,6 +116,8 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
       for good, and a deleted array is never asked), or the order of
       the queue: one device runs its programs in dispatch order, so a
       released node is complete once a node dispatched after it is.
+      A pass that gave pins back admits the backlog as ``dispatch``
+      would.
 
     Residency is touched once per distinct memory operand of a BATCH:
     the push phase's one ``lane_stage_in_batch`` takes the operand's one
@@ -108,7 +126,13 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     calls), ``held`` counts the programs in flight that read it,
     and the pin is given back when the last of them retires or settles
     (or at the end of ``dispatch``, where no program of the batch reads
-    it). Returns ``(dispatch, poll, drop, held)``; ``held`` is empty
+    it). The pins of a batch never pass the budget: what the admission
+    let through fits beside what is pinned, the one exception a program
+    admitted while the pool holds no pin (nothing of its own to wait
+    for; a region no budget can hold is refused when the plan is built),
+    and a stage-in that finds no room short of a pinned victim says so
+    (``NoRoom``) and sends the batch back to the backlog while the pool
+    holds pins. Returns ``(dispatch, poll, drop, held)``; ``held`` is empty
     whenever nothing is in flight: a node that is no sink of the device
     part of the graph retires only when seen complete, every released
     one has such a node dispatched after it, and the pass that retires
@@ -116,7 +140,8 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     pool leaves in flight (``unbind_pool``).
 
     Ownership: a table copy is the residency table's and a written-back
-    array its ``Data``'s; a slot value is the pool's until its last
+    array its ``Data``'s (under pressure the table's too: the ``Data``'s
+    newest copy on the device); a slot value is the pool's until its last
     reader takes it. A fused region's leading operands, ``given``, are
     slots it is the one reader of (the plan's finding): its program is
     jitted with them donated, so ``dispatch`` clears those slots after
@@ -135,6 +160,20 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     inflight: "collections.deque" = collections.deque()
     # released at dispatch, for the next poll() to report to the engine
     released: List[int] = []
+    # ready programs the budget could not pin yet, in the order they came,
+    # and every id that ever waited there (PTDEV_STATS["held_back"] counts
+    # a program once)
+    backlog: List[int] = []
+    waited: set = set()
+    # admitted program -> (table key, bytes) of the room its outputs hold
+    # until it retires or settles (TPUDevice.lane_reserve)
+    reserved: Dict[int, Tuple[int, int]] = {}
+    # set where the pool's memory and write-backs could pass the room the
+    # budget has when it is bound, or at a stage-in that found no room:
+    # from then on the pool admits by bytes, reserves its outputs' room
+    # and writes back into the table. A pool that fits does what it did
+    # before
+    pressed = [False]
     early = frozenset(i for i, e in enumerate(early or ()) if e)
     # device-side cost observation (ISSUE 18): each inflight entry is
     # stamped at dispatch and observed at retire — the elapsed window
@@ -164,8 +203,9 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         # the pool's account: the clock now and at the first ptdev.call
         # entered, what its spans add up to, and whether it is filed
         bound, first_call, filed = _pc(), [0], []
-        acct = {"dispatch_ns": 0, "push_ns": 0, "call_ns": 0, "poll_ns": 0,
-                "programs": 0, "callbacks": 0, "passes": 0, "tasks": 0}
+        acct = {"dispatch_ns": 0, "push_ns": 0, "room_ns": 0, "call_ns": 0,
+                "poll_ns": 0, "programs": 0, "callbacks": 0, "passes": 0,
+                "tasks": 0}
 
         def _called(tok):
             if not first_call[0]:
@@ -194,6 +234,12 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         _evr, _fs, _fe = fusion["marks"]
     else:
         _forig = _dregs = _graph = None
+    outputs = [d for w in writebacks.values() for _dj, d in w] + [
+        d for r in (_dregs or {}).values() for _p, d in r["wb_pairs"]]
+    if mem_datas or outputs:
+        need = (len(mem_datas) + len(outputs)) * tile if tile else sum(
+            dev.lane_bytes(d) for d in (*mem_datas, *outputs))
+        pressed[0] = need > dev.lane_room()
 
     def _fly(i, events, wbs, vals, reads, w, ckey2):
         # one more program is on the device. Released at dispatch, it owes
@@ -204,50 +250,128 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         rel = i in early
         if rel:
             if wbs:
-                for dj, dref in wbs:
-                    dref.write_host(vals[dj])
+                _write_back(wbs, vals)
                 wbs = None
             released.append(i)
             PTDEV_STATS["released_early"] += 1
         inflight.append((i, events, wbs, vals, reads, w, ckey2, rel))
 
+    def _write_back(wbs, vals):
+        # under pressure the newest copy is the device's, a resident of the
+        # table; else the host copy's payload is the device array
+        if pressed[0]:
+            for dj, dref in wbs:
+                dev.lane_write_back(dref, vals[dj])
+        else:
+            for dj, dref in wbs:
+                dref.write_host(vals[dj])
+
+    def _reads(i):
+        # the memory operands of program i, as indices into mem_datas
+        if _dregs is not None:
+            r = _dregs.get(i)
+            if r is not None:
+                return r["ext_mems"]
+            i = _forig[i]
+        base = slot_base[i]
+        return [-2 - r for r in in_refs[base:base + ndflows[cls_of[i]]]
+                if r < -1]
+
+    def _writes(i):
+        # the (position, Data) pairs program i writes back
+        if _dregs is not None:
+            r = _dregs.get(i)
+            if r is not None:
+                return r["wb_pairs"]
+            i = _forig[i]
+        return writebacks.get(i, ())
+
+    def _wait(ids, first=False):
+        # these programs wait in the backlog (ahead of it: ``first``), each
+        # counted once however often it waits
+        if first:
+            backlog[:0] = ids
+        else:
+            backlog.extend(ids)
+        for i in ids:
+            if i not in waited:
+                waited.add(i)
+                PTDEV_STATS["held_back"] += 1
+
+    def _admit(ids, sizes, outs):
+        # the programs, in order, whose operands beyond those pinned and
+        # those of the programs admitted before them, and whose outputs,
+        # fit in the room left; the others wait. With nothing of the
+        # pool's pinned there is no pin to wait for: the first is admitted
+        # whatever it needs
+        room = dev.lane_room()
+        admitted: List[int] = []
+        staged: Dict[int, Any] = {}
+        for i in ids:
+            fresh = [mi for mi in dict.fromkeys(_reads(i))
+                     if mi not in staged]
+            need = sum(sizes[mi] for mi in fresh) + outs.get(i, 0)
+            if need > room and (admitted or held or reserved):
+                _wait([i])
+                continue
+            room -= need
+            for mi in fresh:
+                staged[mi] = None
+            admitted.append(i)
+        return admitted, staged
+
     def push(ids):
-        # PUSH phase: every distinct memory operand of the whole batch is
-        # asked for at once, before any compute dispatch. The device
-        # decides key by key, pinning each operand INSIDE the table's
-        # reserve critical section (no peer thread's stage-in can evict it
-        # first, and staging tile k+1 of this very batch cannot evict tile
-        # k before the exec phase reads it: "dot got NoneType", found by
-        # the verify drive), and moves the misses in one device_put. If it
-        # raises it has given its pins back, and nothing is held here yet
+        # PUSH phase: admission by bytes, then every distinct memory
+        # operand of the admitted programs asked for at once, before any
+        # compute dispatch. The device decides key by key, pinning each
+        # operand INSIDE the table's reserve critical section (no peer
+        # thread's stage-in can evict it first, and staging tile k+1 of
+        # this very batch cannot evict tile k before the exec phase reads
+        # it: "dot got NoneType", found by the verify drive), and moves the
+        # misses in one device_put. If it raises it has given its pins
+        # back, and nothing is held here yet. Returns the admitted ids
         staged: Dict[int, Any] = {}
         if _obs is not None and not inflight:
             # idle -> active: restart the amortization clock so idle
             # gaps between batches never land in any task's cost
             dev_clock[0] = _pc()
         for i in ids:
-            if _dregs is not None:
-                r = _dregs.get(i)
-                if r is not None:
-                    for mi in r["ext_mems"]:
-                        staged[mi] = None
-                    continue
-                i = _forig[i]
-            base = slot_base[i]
-            for dj in range(ndflows[cls_of[i]]):
-                r = in_refs[base + dj]
-                if r < -1:
-                    staged[-2 - r] = None
+            for mi in _reads(i):
+                staged[mi] = None
+        if pressed[0]:
+            outs: Dict[int, int] = {}
+            for i in ids:
+                out = sum(dev.lane_bytes(d) for _p, d in _writes(i))
+                if out:
+                    outs[i] = out
+            sizes = {mi: 0 if mi in held else
+                     dev.lane_pin_bytes(mem_datas[mi]) for mi in staged}
+            if sum(sizes.values()) + sum(outs.values()) > dev.lane_room():
+                ids, staged = _admit(ids, sizes, outs)
+            for i in ids:
+                if i in outs:
+                    reserved[i] = (dev.lane_reserve(outs[i]), outs[i])
         if not staged:
-            return staged
+            return ids, staged
         t0 = _pc()
-        copies, moved, put_ns = dev.lane_stage_in_batch(
-            [mem_datas[mi] for mi in staged])
+        try:
+            copies, moved, put_ns, room_ns = dev.lane_stage_in_batch(
+                [mem_datas[mi] for mi in staged])
+        except NoRoom:
+            for i in ids:
+                if i in reserved:
+                    dev.lane_release(*reserved.pop(i))
+            if not held:        # no pin of the pool's to wait for
+                raise
+            pressed[0] = True
+            _wait(ids, first=True)
+            return [], {}
         if moved:
             PTDEV_STATS["staged_tiles"] += moved
             PTDEV_STATS["stage_in_puts"] += 1
         if sp is not None:
             pinned[0] += len(copies)    # every pin here is a stage-in's
+            acct["room_ns"] += room_ns
             if moved:
                 # ptdev.stage_in: the misses only (a hit moves no bytes),
                 # each at its share of the one put; on the timeline the
@@ -266,7 +390,7 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 h = held[mi] = [copy, 0, 0]
             h[2] += 1           # one table pin an operand a batch
             staged[mi] = h
-        return staged
+        return ids, staged
 
     def issue(ids, staged):
         # EXEC phase: dispatch each ready device task asynchronously
@@ -360,8 +484,22 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 _release(mi, h)
         return len(ids)
 
+    def _waiting_first(ids):
+        # the programs that waited go ahead of those that surface now
+        if not backlog:
+            return ids
+        ids = backlog + list(ids)
+        del backlog[:]
+        return ids
+
+    def _run(ids, _callback):
+        issue(*push(ids))
+
+    run = [_run]        # one admission round: the traced one with spans on
+
     def dispatch(ids):
-        return issue(ids, push(ids))
+        run[0](_waiting_first(ids), 1)
+        return len(ids)
 
     def _complete(events, was_released):
         if not was_released:
@@ -402,8 +540,9 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             if sp is not None:
                 tok = sp.begin(PTDEV_RETIRE)
             if wbs:
-                for dj, dref in wbs:
-                    dref.write_host(vals[dj])
+                _write_back(wbs, vals)
+            if i in reserved:
+                dev.lane_release(*reserved.pop(i))
             for mi in reads:
                 h = held.get(mi)
                 if h is None:       # dropped with an aborted pool
@@ -437,12 +576,17 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 if ckey2 is not None:
                     _obs(ckey2, w, per * w)
             dev_clock[0] = now
+        if backlog:             # pins came back: what waited may fit now
+            run[0](_waiting_first(()), 0)
         return done
 
     def drop():
         # what an aborted pool leaves: nobody will ask after it again
         inflight.clear()
         del released[:]
+        del backlog[:]
+        while reserved:
+            dev.lane_release(*reserved.popitem()[1])
         while held:
             _mi, h = held.popitem()
             for _ in range(h[2]):
@@ -457,34 +601,45 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
             filed.append(file_pool_account(
                 bound, first_call[0], end, retire_ns=retired_ns[0], **acct))
 
-    def traced_dispatch(ids):
-        # one span a callback, recorded once per device program, its push
-        # phase a span inside it; the table pins the callback took and
-        # the programs it found in flight (the depth of the device's
-        # queue as the host left it), one record each
+    def traced_run(ids, callback):
+        # one ptdev.dispatch span a round (a dispatch callback, or the
+        # backlog admitted in a poll pass), recorded once per device
+        # program it called, its push phase a span inside it; the table
+        # pins the round took and the programs it found in flight (the
+        # depth of the device's queue as the host left it), one record each
         sp.pt_inflight.record(len(inflight))
         tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
+        called: Sequence[int] = ()
         try:
             sub = sp.begin(PTDEV_PUSH)
             try:
-                staged = push(ids)
+                called, staged = push(ids)
             finally:
                 acct["push_ns"] += sp.end(sub, sp.pt_push)
-            return issue(ids, staged)
+            issue(called, staged)
         finally:
-            acct["dispatch_ns"] += sp.end(tok, sp.pt_dispatch, n=len(ids))
+            acct["dispatch_ns"] += sp.end(
+                tok, sp.pt_dispatch if called else None, n=len(called) or 1)
             sp.pt_pins.record(pinned[0] - before)
-            acct["programs"] += len(ids)
-            acct["callbacks"] += 1
+            acct["programs"] += len(called)
+            acct["callbacks"] += callback
+
+    run[0] = traced_run
+
+    def traced_dispatch(ids):
+        traced_run(_waiting_first(ids), 1)
+        return len(ids)
 
     def traced_poll():
-        # one record a pass, the retirements' own spans subtracted; the
-        # pool's end is the end of the pass that retires its last task
+        # one record a pass, the retirements' and admissions' own spans
+        # subtracted; the pool's end is the end of the pass that retires
+        # its last task
         tok, before = sp.begin(PTDEV_POLL), retired_ns[0]
+        admitted = acct["dispatch_ns"]
         try:
             return poll()
         finally:
-            less = retired_ns[0] - before
+            less = retired_ns[0] - before + acct["dispatch_ns"] - admitted
             whole = sp.end(tok, sp.pt_poll, less=less)
             acct["poll_ns"] += whole - less
             acct["passes"] += 1

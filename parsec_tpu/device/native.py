@@ -64,12 +64,14 @@ mca.register("device_native_poll_us", 100,
 #: ``staged_tiles`` / ``stage_in_puts`` (ISSUE 38): the tiles the push
 #: phases' stage-ins moved onto the device (misses; a hit or an adoption
 #: moves nothing), and the ``device_put`` calls that moved them: one a
-#: batch that had a miss.
+#: batch that had a miss. ``held_back``: device programs that surfaced
+#: ready and waited in their pool's backlog until the residency budget
+#: could pin their operands (``device/lane_pool.py``), each counted once.
 PTDEV_STATS = LaneStats(lanes_up=0, pools_engaged=0, tasks_engaged=0,
                         pools_fallback=0, pools_ineligible=0,
                         donated=0, region_outputs=0,
                         programs=0, released_early=0,
-                        staged_tiles=0, stage_in_puts=0)
+                        staged_tiles=0, stage_in_puts=0, held_back=0)
 
 #: live lanes, for the process-wide ``ptdev.*`` counter samplers
 _lanes: "weakref.WeakSet[NativeDeviceLane]" = weakref.WeakSet()

@@ -45,7 +45,7 @@ from ..data.data import COHERENCY_INVALID, COHERENCY_OWNED, COHERENCY_SHARED, Da
 from ..utils import mca, output
 from ..utils.xla_trace import (DEV_CALL, DEV_GATHER, DEV_POLL, DEV_RETIRE,
                                DEV_STAGE_IN, DEV_SUBMIT,
-                               DEV_WRITEBACK)
+                               DEV_WRITEBACK, PTDEV_ROOM)
 from .device import DeviceModule
 
 #: the sizes a multi-task program comes in (capped by
@@ -63,6 +63,10 @@ PACED_STREAK = 4
 #: DTD window refilling: ~57 k-chains' C tiles in the out-of-core GEMM) then
 #: finds its write-backs on the host already; 1 GiB of host memory at 16 MiB
 WRITEBACK_AHEAD = 64
+#: the table keys of :meth:`TPUDevice.lane_reserve`: the top bit set, which
+#: no residency key (an object's address) has, and a count of their own
+_RESERVED = 1 << 63
+_reservations = itertools.count(1)
 
 mca.register("device_tpu_max_bytes", 0,
              "HBM tile-heap budget in bytes (0 = 75% of the device's "
@@ -77,6 +81,12 @@ mca.register("device_tpu_over_cpu", False,
 mca.register("device_tpu_over_cpu_index", 0,
              "TEST MODE: which host jax device to register over (lets each "
              "in-process rank bind a distinct virtual device)", type=int)
+
+
+class NoRoom(RuntimeError):
+    """A lane stage-in for which the budget has no room without evicting a
+    pinned copy: what the copies pinned now hold and the operand's bytes
+    pass the budget (:meth:`TPUDevice._stage_in_decide`)."""
 
 
 class TPUTask:
@@ -150,6 +160,10 @@ class TPUDevice(DeviceModule):
         self._lru_sizes: Dict[Any, int] = {}   # accounted bytes per key
         self._lru_segs: Dict[Any, Any] = {}    # key -> pt_zone segment
         self._resident_bytes = 0
+        #: bytes of the resident copies that some reader pins (``readers``
+        #: above 0): what no victim walk may free, so the budget less this
+        #: is the room a new pin can still have (:meth:`lane_room`)
+        self._pinned_bytes = 0
         self.evictions = 0          # copies evicted (budget pressure stat)
         #: of those, copies that left in OWNED state: the device had written
         #: them since they were staged, so each owes the host a write-back
@@ -498,7 +512,8 @@ class TPUDevice(DeviceModule):
             return copy
         return self._transfer([(data, copy, src)], pin)[0][0]
 
-    def _stage_in_decide(self, data: Data, pin: bool
+    def _stage_in_decide(self, data: Data, pin: bool,
+                         room: Optional[List[int]] = None
                          ) -> Tuple[Optional[DataCopy], Optional[DataCopy]]:
         """One operand's residency decision, and everything about it that
         moves no byte. With the native table up the decision is C's — is a
@@ -509,6 +524,15 @@ class TPUDevice(DeviceModule):
         takes the eviction pin INSIDE the table's reserve critical section
         (a concurrent stage-in on another thread could otherwise evict
         this entry between the reserve and the caller's pin).
+
+        ``room`` is the lane's (:meth:`lane_stage_in_batch`): a miss whose
+        bytes, beside what is pinned now, pass the budget has no room
+        short of a pinned victim, and the decision says so (:class:`NoRoom`,
+        before the table is asked) where the DTD manager's would run the
+        table over its budget; with the spans on, a miss's reservation (the
+        table's victim choice, the victims applied, a dirty one's
+        write-back) is the ``ptdev.room`` span, its nanoseconds added to
+        ``room[0]``.
 
         Returns ``(copy, None)`` where the operand is resident as it
         stands, the Python reader count bumped to match the table's pin: a
@@ -523,11 +547,26 @@ class TPUDevice(DeviceModule):
         hit = copy is not None and copy.version == newest.version and \
             copy.coherency_state != COHERENCY_INVALID
         if self._ncoh is not None:
-            need, victims = self._ncoh.stage_in(
-                self.res_key(data), _nbytes(newest.payload),
-                newest.version & 0xFFFFFFFF, 0, 1 if pin else 0)
-            if victims:
-                self._apply_victims(victims)
+            nbytes, tok = _nbytes(newest.payload), None
+            if room is not None and not hit:
+                if (copy is None or not copy.readers) and \
+                        self._pinned_bytes + nbytes > self._budget:
+                    raise NoRoom(
+                        f"no room for {data!r} ({nbytes} bytes) beside the "
+                        f"{self._pinned_bytes} pinned: the budget is "
+                        f"{self._budget}")
+                sp = self._spans
+                if sp is not None:
+                    tok = sp.begin(PTDEV_ROOM)
+            try:
+                need, victims = self._ncoh.stage_in(
+                    self.res_key(data), nbytes,
+                    newest.version & 0xFFFFFFFF, 0, 1 if pin else 0)
+                if victims:
+                    self._apply_victims(victims)
+            finally:
+                if tok is not None:
+                    room[0] += sp.end(tok, None)
             # the table said transfer, or the mirror lost the payload (the
             # stale table entry was already replaced by stage_in)
             hit = hit and not need
@@ -535,7 +574,7 @@ class TPUDevice(DeviceModule):
             self._lru_touch(self.res_key(data), copy)
             if pin:
                 with self._heap_lock:
-                    copy.readers += 1     # the table half was pinned above
+                    self._pin_locked(copy)  # the table half was pinned above
             return copy, None
         arr = newest.payload
         if isinstance(arr, self._jax.Array) and arr.committed and \
@@ -610,8 +649,77 @@ class TPUDevice(DeviceModule):
         self._lru_touch(self.res_key(data), copy)
         if pin:
             with self._heap_lock:
-                copy.readers += 1     # the table half: pinned in stage_in
+                self._pin_locked(copy)  # the table half: pinned in stage_in
         return copy
+
+    def lane_room(self) -> int:
+        """Bytes a new pin can still have: the budget less what pinned
+        copies hold. Where the operands a program would pin beyond those
+        fit in it, every one of them has room short of a pinned victim."""
+        return self._budget - self._pinned_bytes
+
+    def lane_bytes(self, data: Data) -> int:
+        """The bytes ``data`` takes here: its resident copy's, or its newest
+        copy's where none is resident."""
+        nbytes = self._lru_sizes.get(id(data))
+        if nbytes is None:
+            newest = data.newest_copy()
+            nbytes = 0 if newest is None else _nbytes(newest.payload)
+        return nbytes
+
+    def lane_pin_bytes(self, data: Data) -> int:
+        """What pinning ``data`` here adds to the pinned bytes: 0 where its
+        copy is pinned already, else :meth:`lane_bytes`."""
+        copy = data.copies.get(self.device_index)
+        return 0 if copy is not None and copy.readers else \
+            self.lane_bytes(data)
+
+    def lane_reserve(self, nbytes: int) -> int:
+        """Room in the table for the outputs of a lane program from its
+        admission to its end: the buffers it allocates when it is called,
+        beside the copies it reads, until its write-backs replace them. A
+        pinned entry of its own (a key no datum has), victims applied as
+        a stage-in's; among the pinned bytes. Returns the key for
+        :meth:`lane_release`."""
+        key = _RESERVED | next(_reservations)
+        with self._heap_lock:
+            if self._ncoh is not None:
+                _need, victims = self._ncoh.stage_in(key, nbytes, 0, 0, 1)
+                if victims:
+                    self._apply_victims_locked(victims)
+            self._pinned_bytes += nbytes
+        return key
+
+    def lane_release(self, key: int, nbytes: int) -> None:
+        """The program of :meth:`lane_reserve`'s ``key`` has ended: its
+        room goes back."""
+        with self._heap_lock:
+            if self._ncoh is not None:
+                self._ncoh.unpin(key)
+                self._ncoh.drop(key)
+            self._pinned_bytes -= nbytes
+
+    def lane_write_back(self, data: Data, arr: Any) -> None:
+        """A lane program's output ``arr`` becomes ``data``'s newest
+        version as this device's copy: OWNED, tracked in the table at its
+        bytes, the room reserved as a DTD epilog's output is
+        (:meth:`_coh_mark_owned`, which may evict). An eviction then writes
+        it back to the host at its version, as it does a DTD output. A
+        value that is no array of the device's client goes to the host copy
+        (:meth:`Data.write_host`)."""
+        if not isinstance(arr, self._jax.Array):
+            data.write_host(arr)
+            return
+        with self._heap_lock:
+            copy = data.get_copy(self.device_index)
+            if copy is None:
+                copy = data.create_copy(self.device_index, arr,
+                                        COHERENCY_OWNED)
+            else:
+                copy.payload = arr
+            data.bump_version(self.device_index)
+            self._lru_touch_locked(self.res_key(data), copy)
+            self._coh_mark_owned(data, copy)
 
     def lane_stage_in(self, data: Data, pin: bool = False) -> DataCopy:
         """One datum staged in through the lane's entry: version-checked
@@ -620,25 +728,27 @@ class TPUDevice(DeviceModule):
         return self._stage_in_copy(data, 0, pin=pin)
 
     def lane_stage_in_batch(self, datas: Sequence[Data]
-                            ) -> Tuple[List[DataCopy], int, int]:
+                            ) -> Tuple[List[DataCopy], int, int, int]:
         """Stage-in entry for the native device lane's dispatch callback
         (the push phase of ptdev): the distinct memory operands of a whole
         batch at once. The decision is taken key by key and in order
         (:meth:`_stage_in_decide`), each operand pinned atomically with
         its reserve and its victims applied before a byte moves, so a
-        batch never evicts a tile of its own; the misses among them then
-        move in one transfer (:meth:`_transfer`).
+        batch never evicts a tile of its own; a miss with no room short of
+        a pinned victim raises :class:`NoRoom`. The misses then move in
+        one transfer (:meth:`_transfer`).
 
         Returns the copies in order, each pinned once, the tiles that
-        moved, and the nanoseconds of the put and its installs (0 with the
-        spans off). When anything raises, every pin the batch took is
-        given back."""
+        moved, the nanoseconds of the put and its installs and those of
+        making room (both 0 with the spans off). When anything raises,
+        every pin the batch took is given back."""
         copies: List[Optional[DataCopy]] = []
         misses, at = [], []
+        room = [0]
         try:
             with self._heap_lock:
                 for data in datas:
-                    copy, src = self._stage_in_decide(data, True)
+                    copy, src = self._stage_in_decide(data, True, room)
                     if src is not None:
                         at.append(len(copies))
                         misses.append((data, copy, src))
@@ -650,7 +760,7 @@ class TPUDevice(DeviceModule):
             self._unpin_all(copies)
             raise
         if not misses:
-            return copies, 0, 0
+            return copies, 0, 0, room[0]
         try:
             moved, put_ns = self._transfer(misses, True)
         except BaseException:
@@ -658,7 +768,7 @@ class TPUDevice(DeviceModule):
             raise
         for i, copy in zip(at, moved):
             copies[i] = copy
-        return copies, len(misses), put_ns
+        return copies, len(misses), put_ns, room[0]
 
     def _unpin_all(self, copies: Sequence[Optional[DataCopy]]) -> None:
         for copy in copies:
@@ -916,6 +1026,8 @@ class TPUDevice(DeviceModule):
         new_size = _nbytes(copy.payload)
         old_size = self._lru_sizes.get(key, 0)
         self._resident_bytes += new_size - old_size
+        if copy.readers:
+            self._pinned_bytes += new_size - old_size
         self._lru_sizes[key] = new_size
         self._lru[key] = copy
         self._trace_mem(new_size - old_size)
@@ -974,13 +1086,23 @@ class TPUDevice(DeviceModule):
         interpreted-path workers AND the ptdev manager thread — the
         non-atomic ``+=`` goes under the heap lock so no update is lost."""
         with self._heap_lock:
-            copy.readers += 1
+            self._pin_locked(copy)
         self._coh_pin(copy.original)
 
     def unpin_copy(self, copy: DataCopy) -> None:
         with self._heap_lock:
             copy.readers -= 1
+            if not copy.readers:
+                self._pinned_bytes -= self._lru_sizes.get(
+                    id(copy.original), 0)
         self._coh_unpin(copy.original)
+
+    def _pin_locked(self, copy: DataCopy) -> None:
+        """One more reader of ``copy`` (heap lock held); the first puts its
+        resident bytes among the pinned ones."""
+        if not copy.readers:
+            self._pinned_bytes += self._lru_sizes.get(id(copy.original), 0)
+        copy.readers += 1
 
     def _reserve(self, nbytes: int) -> None:
         """Evict LRU copies until ``nbytes`` fits the budget
@@ -1017,6 +1139,7 @@ class TPUDevice(DeviceModule):
             seg.free()
         self._lru_segs.clear()
         self._resident_bytes = 0
+        self._pinned_bytes = 0
         self._fetching.clear()
         self._pending.clear()
 

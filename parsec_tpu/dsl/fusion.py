@@ -340,7 +340,8 @@ def pack_source_regions(sizes: Sequence[int],
                         ext_in: Sequence[int], ext_out: Sequence[int],
                         reads: Sequence[Sequence[Hashable]],
                         writes: Sequence[Sequence[Hashable]],
-                        max_size: int = 128) -> List[List[int]]:
+                        max_size: int = 128,
+                        max_held: Optional[int] = None) -> List[List[int]]:
     """Pack sibling device regions that share memory operands (ISSUE 32):
     a region program then takes each shared operand once, where its
     members' programs each took it again (four k-chains of one row of a
@@ -369,7 +370,11 @@ def pack_source_regions(sizes: Sequence[int],
     other reads or writes: the pack's trace-time memory env would
     otherwise give an order to what the graph left unordered. A pack
     holds at most ``max_size`` tasks, the fusion pass's own hard bound on
-    XLA program size.
+    XLA program size, and reads and writes at most ``max_held``
+    distinct locations, a location both read and written counting twice
+    (None: no bound): the most a device can hold of them at once, each
+    location it reads pinned while the pack runs beside the room of each
+    it writes. A pack past it could never be staged whole.
 
     The choice is greedy and deterministic in region order (the plan is
     cached and instantiations must agree): a pack starts at the first
@@ -420,7 +425,10 @@ def pack_source_regions(sizes: Sequence[int],
                 if total + sizes[c] > max_size \
                         or any(k in p_reads or k in p_writes
                                for k in writes[c]) \
-                        or any(k in p_writes for k in reads[c]):
+                        or any(k in p_writes for k in reads[c]) \
+                        or (max_held is not None and len(p_reads | set(
+                            reads[c])) + len(p_writes | set(writes[c]))
+                            > max_held):
                     refused.add(c)
                 else:
                     r = c

@@ -1650,11 +1650,16 @@ class PTGTaskpool(Taskpool):
             [(names[ci], bucket,
               "tpu" if (use_dev and place_dev[ci]) else "cpu")
              for ci in range(len(classes))])
+        # what the device's residency budget can hold at once bounds a
+        # pack, so it joins the key as the limits do
+        self._ptexec_tile_bytes = tile = None if devlane is None else \
+            self._ptexec_tile()
+        pin_cap = devlane.device._budget // tile if tile else None
         place = (ctx.nb_ranks, lane_comm is not None, use_dev,
                  device_fingerprint(),
                  bool(mca.get("region_fusion", True)),
                  fus_min, fus_max,
-                 tuple(place_dev), tuple(sorted(fus_declined)))
+                 tuple(place_dev), tuple(sorted(fus_declined)), pin_cap)
         key = self._ptexec_cache_key(names, place)
         cache = self.program.__dict__.setdefault("_ptexec_cache", {})
         ent = cache.get(key) if key is not None else None
@@ -1669,7 +1674,8 @@ class PTGTaskpool(Taskpool):
                 # — a fused region must never hide a cross-rank edge
                 plan = self._ptexec_fuse_plan(
                     flat, classes, place_dev, use_dev,
-                    (fus_declined, fus_min, fus_max))
+                    (fus_declined, fus_min, fus_max),
+                    None if pin_cap is None else (pin_cap, devlane.device))
             ent = {"flat": flat, "fusion": plan}
             if key is not None:
                 cache[key] = ent
@@ -1762,10 +1768,22 @@ class PTGTaskpool(Taskpool):
         return lane
 
     # ---------------------------------------------- region fusion (ISSUE 12)
+    def _ptexec_tile(self) -> Optional[int]:
+        """The bytes of the largest tile of this pool's collections; None
+        where a collection does not say its tile."""
+        tile = 0
+        for dc in self.collections.values():
+            try:
+                tile = max(tile, int(dc.mb) * int(dc.nb)
+                           * np.dtype(dc.dtype).itemsize)
+            except (AttributeError, TypeError):
+                return None
+        return tile or None
+
     def _ptexec_fuse_plan(self, flat, classes: List[TaskClass],
                           dev_classes: List[bool],
                           use_dev: bool,
-                          limits=None) -> Optional[Dict[str, Any]]:
+                          limits=None, pin=None) -> Optional[Dict[str, Any]]:
         """The fusion pass over the flattened CSR: identify capturable
         subgraphs — same-device jittable bodies (the class's single
         jitted ``_ptg_body_fn``, or an empty forwarding body), static
@@ -1777,7 +1795,11 @@ class PTGTaskpool(Taskpool):
         release walk and the slot-retire protocol cross the seam
         correctly) plus per-region replay plans, or None when nothing
         is worth fusing. Pure structure — no per-instantiation objects
-        — so the whole plan rides the flatten cache."""
+        — so the whole plan rides the flatten cache. ``pin`` is (the
+        memory operands and outputs the device can hold at once, the
+        device): a pack reads and writes no more of them, and a device
+        region that needs more bytes than the device's budget holds is
+        refused here, by name."""
         if not mca.get("region_fusion", True):
             return None
         # measured fusion limits (ISSUE 18, dsl/fusion.py): the decline
@@ -1835,7 +1857,8 @@ class PTGTaskpool(Taskpool):
         in_off, in_slots = data["in_off"], data["in_slots"]
         slot_base, in_refs = data["slot_base"], data["in_refs"]
         mem_reads = data["mem_reads"]
-        regions, n_packed = self._ptexec_pack(regions, kind, flat, fus_max)
+        regions, n_packed = self._ptexec_pack(
+            regions, kind, flat, fus_max, None if pin is None else pin[0])
         reg_of = [-1] * n
         for ri, members in enumerate(regions):
             for m in members:
@@ -2031,6 +2054,10 @@ class PTGTaskpool(Taskpool):
                            "ext_mems": [v for k2, v in ext if k2 == "mem"],
                            "shape": si, "wb_keys": wb_keys,
                            "out_slots": outs})
+            if pin is not None and rplans[-1]["kind"] == "dev" and \
+                    len(rplans[-1]["ext_mems"]) + len(set(wb_keys)) > pin[0]:
+                self._ptexec_unstageable(flat, classes, rplans[-1], ri,
+                                         pin[1])
         dev_mask2 = None
         ndev_tasks = 0
         if use_dev:
@@ -2130,16 +2157,40 @@ class PTGTaskpool(Taskpool):
                 end = nxt[0] if len(nxt) == 1 else None
         return out
 
+    def _ptexec_unstageable(self, flat, classes, rp, ri, device) -> None:
+        """Refuse the pool if device region ``ri`` needs more bytes than
+        ``device``'s residency budget holds, the memory it reads pinned
+        beside the room of what it writes back: no admission could ever
+        stage it whole."""
+        mem_reads = flat["data"]["mem_reads"]
+        datas = self._ptexec_datas([mem_reads[mi] for mi in rp["ext_mems"]])
+        outs = self._ptexec_datas(list(dict.fromkeys(rp["wb_keys"])))
+        need = sum(device.lane_bytes(d) for d in datas + outs)
+        if need <= device._budget:
+            return
+        m = rp["members"][0]
+        ci = flat["data"]["cls_of"][m]
+        first = (f"{classes[ci]._ptg_spec.name}"
+                 f"{tuple(flat['params'][ci][m - flat['bases'][ci]])}")
+        output.fatal(
+            f"PTG taskpool {self.name}: device region {ri} ({first} and "
+            f"{len(rp['members']) - 1} more tasks) reads {len(datas)} "
+            f"memory operands and writes {len(outs)} back, {need} bytes, "
+            f"more than the residency budget of {device.name}, "
+            f"{device._budget} bytes: it could never be staged")
+
     @staticmethod
-    def _ptexec_pack(regions: List[List[int]], kind, flat,
-                     max_size: int) -> Tuple[List[List[int]], int]:
+    def _ptexec_pack(regions: List[List[int]], kind, flat, max_size: int,
+                     max_held: Optional[int] = None
+                     ) -> Tuple[List[List[int]], int]:
         """Pack sibling device regions that share memory operands into
         one region (ISSUE 32): gather what only this side knows of each
         region of the partition (its edges to the outside, the memory it
         reads and writes) as plain lists, let
         :func:`~parsec_tpu.dsl.fusion.pack_source_regions` choose, and
         chain the members of each pack. Returns the regions and how many
-        of the partition's went into a pack of two or more."""
+        of the partition's went into a pack of two or more. ``max_held``
+        bounds the locations a pack reads and writes."""
         data = flat["data"]
         off, succs = flat["off"], flat["succs"]
         slot_base, in_refs = data["slot_base"], data["in_refs"]
@@ -2171,7 +2222,7 @@ class PTGTaskpool(Taskpool):
                 writes[reg_of[tid]].append((dcn, idx))
         packs = pack_source_regions(
             [len(m) for m in regions], [kind[m[0]] for m in regions],
-            ext_in, ext_out, reads, writes, max_size)
+            ext_in, ext_out, reads, writes, max_size, max_held)
         n_packed = sum(len(p) for p in packs if len(p) > 1)
         if n_packed:
             regions = [[m for ri in p for m in regions[ri]] for p in packs]
@@ -2403,6 +2454,7 @@ class PTGTaskpool(Taskpool):
             names=names, slots=slots, mem_datas=mem_datas,
             writebacks=writebacks, dev_mask=dev_mask, ndev_tasks=ndev,
             early=early, fusion=fusion, bucket=bucket,
+            tile=self._ptexec_tile_bytes or 0,
             # the lane's observations, folded into the cost model at
             # detach (Context._cost_fold)
             cost_obs=lane.setdefault("cost_dev", {}) if _cm.enabled()

@@ -17,9 +17,10 @@ or annotate spans manually through :class:`TaskAnnotator` (a PINS module).
 nine named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``;
 ``dev.gather`` and ``dev.call`` are the two halves of ``dev.submit``;
 ``dev.writeback``, the dirty branch of an eviction, is both managers')
-and six on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
+and seven on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
 instantiation; ``device/lane_pool.py``: the ``ptdev`` manager's dispatch,
-with its push phase and each program's call inside it, poll and retire),
+with its push phase and each program's call inside it, poll and retire;
+``device/tpu.py``: inside the push phase, a miss's room made),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
 alone: the ready-wait, and ``ptdev.stage_in_ns`` (the misses of the
@@ -75,14 +76,16 @@ DEV_GATHER, DEV_CALL = "dev.gather", "dev.call"     # inside dev.submit
 PTG_LOWER = "ptg.lower"
 PTDEV_DISPATCH = "ptdev.dispatch"
 PTDEV_PUSH, PTDEV_CALL = "ptdev.push", "ptdev.call"  # inside ptdev.dispatch
+PTDEV_ROOM = "ptdev.room"                           # inside ptdev.push
 PTDEV_POLL, PTDEV_RETIRE = "ptdev.poll", "ptdev.retire"
 
 #: the fields of a pool's account, in the order they are told: nanoseconds
 #: of the lane's manager thread that add up to ``life_ns`` (``head_ns`` is
-#: the part of ``life_ns`` before the first program's call), then counts
-POOL_ACCOUNT_FIELDS = ("life_ns", "head_ns", "push_ns", "call_ns", "own_ns",
-                       "poll_ns", "retire_ns", "away_ns", "programs",
-                       "callbacks", "passes", "tasks")
+#: the part of ``life_ns`` before the first program's call, ``room_ns`` the
+#: part of ``push_ns`` that made room), then counts
+POOL_ACCOUNT_FIELDS = ("life_ns", "head_ns", "push_ns", "room_ns", "call_ns",
+                       "own_ns", "poll_ns", "retire_ns", "away_ns",
+                       "programs", "callbacks", "passes", "tasks")
 #: the accounts of the last pools that ended on a ``ptdev`` lane with the
 #: spans on, oldest first. Process-wide, so it outlives ``ctx.fini()`` as
 #: the folded histograms do; appended on a lane's manager thread, read by
@@ -93,8 +96,8 @@ POOL_ACCOUNTS: Deque[Dict[str, int]] = collections.deque(maxlen=64)
 def file_pool_account(bound: int, first_call: int, end: int, *,
                       dispatch_ns: int, push_ns: int, call_ns: int,
                       poll_ns: int, retire_ns: int, programs: int,
-                      callbacks: int, passes: int, tasks: int
-                      ) -> Dict[str, int]:
+                      callbacks: int, passes: int, tasks: int,
+                      room_ns: int = 0) -> Dict[str, int]:
     """File the account of a pool that was bound at the clock ``bound``,
     entered its first ``ptdev.call`` at ``first_call`` (0: it never did)
     and ended at ``end``, from the nanoseconds of its callbacks' spans
@@ -103,12 +106,13 @@ def file_pool_account(bound: int, first_call: int, end: int, *,
     phase and its calls; ``away_ns`` the manager thread outside this
     pool's callbacks: the engine's release walk, the C lane's wake-ups and
     waits, other pools. ``push + call + own + poll + retire + away`` is
-    ``life`` to the nanosecond."""
+    ``life`` to the nanosecond; ``room_ns``, the ``ptdev.room`` spans, is
+    a part of ``push_ns``."""
     life = end - bound
     account = {
         "life_ns": life,
         "head_ns": first_call - bound if first_call else life,
-        "push_ns": push_ns, "call_ns": call_ns,
+        "push_ns": push_ns, "room_ns": room_ns, "call_ns": call_ns,
         "own_ns": dispatch_ns - push_ns - call_ns,
         "poll_ns": poll_ns, "retire_ns": retire_ns,
         "away_ns": life - dispatch_ns - poll_ns - retire_ns,

@@ -1188,8 +1188,11 @@ def _mixed_batch(dctx, monkeypatch):
 def _batch_under_a_budget(dctx, monkeypatch):
     """Four tiles of room. A batch of four new tiles evicts the four LRU
     unpinned ones and none of its own, and the dirty one among them is on
-    the host, at its version, before the put is called. A batch of six
-    pins all six, skips what it cannot evict and leaves the rest to XLA."""
+    the host, at its version, before the put is called. A batch of three
+    programs that need six tiles is cut to the two whose four fit; the
+    third waits, counted once, until a poll that gave pins back admits
+    it: the resident bytes never pass the budget."""
+    from parsec_tpu.device.native import PTDEV_STATS
     devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
     dev.set_budget(4 * _TILE, unit=1024)
     old, new, more = _tiles(4), _tiles(4, 10.0), _tiles(6, 20.0)
@@ -1222,14 +1225,18 @@ def _batch_under_a_budget(dctx, monkeypatch):
                d.get_copy(dev.device_index).payload is not None for d in new)
     assert dev._resident_bytes == 4 * _TILE
     drain()
-    skips = dev.coh_stats()["pinned_skips"]
-    send([4, 5, 6])
-    assert seen[-1][:2] == (6, 8)       # the four before it, none of its own
-    assert dev.coh_stats()["pinned_skips"] > skips
-    assert dev._resident_bytes == 6 * _TILE     # over: XLA's to carry
-    assert all(_pins(dev, d) == (1, 1) and
-               d.get_copy(dev.device_index).payload is not None for d in more)
+    stats = PTDEV_STATS.snapshot()
+    assert send([4, 5, 6]) == 3         # all three counted in flight
+    assert seen[-1][:2] == (4, 8)       # the four before it, none of its own
+    assert PTDEV_STATS.delta(stats)["held_back"] == 1
+    assert dev._resident_bytes == 4 * _TILE == dev.lane_room() + 4 * _TILE
+    assert all(_pins(dev, d) == (1, 1) for d in more[:4])
+    assert all(d.get_copy(dev.device_index) is None for d in more[4:])
     drain()
+    assert seen[-1][:2] == (2, 10) and len(seen) == 3
+    assert PTDEV_STATS.delta(stats)["held_back"] == 1
+    assert dev.coh_stats()["hwm_bytes"] <= 4 * _TILE
+    assert dev._resident_bytes == 4 * _TILE and dev.lane_room() == 4 * _TILE
     assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
     assert [float(np.asarray(slots[s])[0, 0]) for s in range(6, 21, 3)] == \
         [21.0, 25.0, 41.0, 45.0, 49.0]

@@ -581,9 +581,10 @@ def test_the_accounts_are_bounded_at_64_and_outlive_the_context(lane_pools):
                 tasks=4)
         assert X.POOL_ACCOUNTS.maxlen == len(X.POOL_ACCOUNTS) == 64
         assert X.POOL_ACCOUNTS[-1] == {
-            "life_ns": 159, "head_ns": 10, "push_ns": 10, "call_ns": 15,
-            "own_ns": 5, "poll_ns": 5, "retire_ns": 2, "away_ns": 122,
-            "programs": 1, "callbacks": 1, "passes": 3, "tasks": 4}
+            "life_ns": 159, "head_ns": 10, "push_ns": 10, "room_ns": 0,
+            "call_ns": 15, "own_ns": 5, "poll_ns": 5, "retire_ns": 2,
+            "away_ns": 122, "programs": 1, "callbacks": 1, "passes": 3,
+            "tasks": 4}
         assert X.POOL_ACCOUNTS[0]["life_ns"] == 96     # the oldest six left
     finally:
         X.POOL_ACCOUNTS.clear()
