@@ -80,21 +80,26 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     thread with the GIL held:
 
     * ``dispatch(ids)`` — the push+exec phases of the reference's stream
-      pipeline (device_gpu.c:3438) on XLA's async runtime: FIRST, in a
-      pool UNDER PRESSURE (its memory and write-backs could pass the
-      room the residency budget has, ``TPUDevice.lane_room``, when it is
-      bound), the batch is ADMITTED by bytes: a program whose memory
-      operands beyond those pinned already, and whose outputs, do not fit
-      waits in the pool's BACKLOG, ahead of every program that surfaces
-      after it, until retirements give pins back; an admitted program
-      holds the room of its outputs in the table until it ends
-      (``lane_reserve``). Then every memory operand of the
-      admitted programs stages in (version-checked key by key through
-      the C coherency table, the misses moved by ONE ``device_put`` of
-      the list, which is asynchronous, so the transfer overlaps compute
-      already in flight), THEN each
-      program dispatches (async) and its future outputs land in the
-      slots at once. A node under ``early`` is RELEASED here: its
+      pipeline (device_gpu.c:3438) on XLA's async runtime, one ROUND:
+      FIRST, in a pool UNDER PRESSURE (its memory and write-backs could
+      pass the room the residency budget has, ``TPUDevice.lane_room``,
+      when it is bound), the round is ADMITTED by bytes, once: a program
+      whose memory operands beyond those pinned already, and whose
+      outputs, do not fit waits in the pool's BACKLOG, ahead of every
+      program that surfaces after it, until retirements give pins back.
+      Then the admitted programs are PUSHED AND CALLED ONE BY ONE, in
+      order: under pressure a program takes the room of its outputs in
+      the table, held until it ends (``lane_reserve``); the memory
+      operands that no earlier program of the round staged stage in
+      (version-checked key by key through the C coherency table, the
+      misses moved by ONE ``device_put`` of the list, which blocks the
+      manager thread for the link's time); THEN the program is called
+      (async) and its future outputs land in the slots at once. So the
+      chip starts on the round's first program while the manager makes
+      room for and stages the operands of the next, where it used to
+      wait for the whole round's (``PTDEV_STATS["called_in_push"]``
+      counts the programs called while a later one of their round was
+      still to be pushed). A node under ``early`` is RELEASED here: its
       write-backs land (future arrays: ``Data.write_host`` blocks on
       nothing; under pressure ``TPUDevice.lane_write_back``, the array
       the data's newest copy, an OWNED resident of the table, which
@@ -102,9 +107,13 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
       reports its id, so the engine's
       release walk surfaces its successors, device nodes of this lane
       all, while it still runs; XLA queues each behind the producers
-      of its operands, donated ones included. It returns the number of
-      ids it was given: the C lane counts a waiting program in flight,
-      so it keeps polling;
+      of its operands, donated ones included. Those write-backs land
+      before a later program of the same round takes its decisions, and
+      that is safe because the programs of a round were all ready when it
+      began: none is an ancestor of another, so none reads from memory
+      what another writes back (two such programs would race in the
+      graph itself). It returns the number of ids it was given: the C
+      lane counts a waiting program in flight, so it keeps polling;
     * ``poll()`` — the event queue: ``jax.Array.is_ready`` over each
       inflight program's outputs (cudaEventQuery, device_gpu.c:2593).
       Completed tasks write back to memory, give up their reads and
@@ -119,22 +128,25 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
       A pass that gave pins back admits the backlog as ``dispatch``
       would.
 
-    Residency is touched once per distinct memory operand of a BATCH:
-    the push phase's one ``lane_stage_in_batch`` takes the operand's one
+    Residency is touched once per distinct memory operand of a ROUND:
+    the first program of the round that reads it asks for it, in that
+    program's one ``lane_stage_in_batch``, which takes the operand's one
     pin (table + ``readers``; all given back if it raises, always-on
     counts ``PTDEV_STATS["staged_tiles"]`` moved by ``["stage_in_puts"]``
-    calls), ``held`` counts the programs in flight that read it,
+    calls); a later program of the round joins that entry with no table
+    call. ``held`` counts the programs in flight that read it,
     and the pin is given back when the last of them retires or settles
-    (or at the end of ``dispatch``, where no program of the batch reads
-    it). The pins of a batch never pass the budget: what the admission
-    let through fits beside what is pinned, the one exception a program
+    (or at the end of the round, where no program of it reads it). The
+    pins of a round never pass the budget: what the admission let
+    through fits beside what is pinned, the one exception a program
     admitted while the pool holds no pin (nothing of its own to wait
     for; a region no budget can hold is refused when the plan is built),
     and a stage-in that finds no room short of a pinned victim says so
-    (``NoRoom``) and sends the batch back to the backlog while the pool
-    holds pins. Returns ``(dispatch, poll, drop, held)``; ``held`` is empty
-    whenever nothing is in flight: a node that is no sink of the device
-    part of the graph retires only when seen complete, every released
+    (``NoRoom``): the programs called before it stay in flight, and it
+    and the rest of the round go back to the head of the backlog while
+    the pool holds pins. Returns ``(dispatch, poll, drop, held)``; ``held``
+    is empty whenever nothing is in flight: a node that is no sink of the
+    device part of the graph retires only when seen complete, every released
     one has such a node dispatched after it, and the pass that retires
     it settles what precedes it. ``drop()`` gives up what an aborted
     pool leaves in flight (``unbind_pool``).
@@ -214,7 +226,7 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     # mi -> [device copy, programs in flight that read it, pins held]:
     # owned by the manager thread (dispatch and poll both run there
     # with the GIL, as _obs relies on), so no lock and no table call
-    # per (program, operand). An operand staged again by a later batch
+    # per (program, operand). An operand staged again by a later round
     # while an earlier reader still flies joins the same entry; its
     # pins nest in the table as they always did.
     held: Dict[int, List[Any]] = {}
@@ -306,7 +318,7 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         # whatever it needs
         room = dev.lane_room()
         admitted: List[int] = []
-        staged: Dict[int, Any] = {}
+        staged: set = set()
         for i in ids:
             fresh = [mi for mi in dict.fromkeys(_reads(i))
                      if mi not in staged]
@@ -315,57 +327,50 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 _wait([i])
                 continue
             room -= need
-            for mi in fresh:
-                staged[mi] = None
+            staged.update(fresh)
             admitted.append(i)
-        return admitted, staged
+        return admitted
 
-    def push(ids):
-        # PUSH phase: admission by bytes, then every distinct memory
-        # operand of the admitted programs asked for at once, before any
-        # compute dispatch. The device decides key by key, pinning each
-        # operand INSIDE the table's reserve critical section (no peer
-        # thread's stage-in can evict it first, and staging tile k+1 of
-        # this very batch cannot evict tile k before the exec phase reads
-        # it: "dot got NoneType", found by the verify drive), and moves the
-        # misses in one device_put. If it raises it has given its pins
-        # back, and nothing is held here yet. Returns the admitted ids
-        staged: Dict[int, Any] = {}
-        if _obs is not None and not inflight:
-            # idle -> active: restart the amortization clock so idle
-            # gaps between batches never land in any task's cost
-            dev_clock[0] = _pc()
+    def _admission(ids):
+        # under pressure, once a round: the programs admitted by bytes, and
+        # the bytes each writes back (the room it takes when it is pushed)
+        outs: Dict[int, int] = {}
         for i in ids:
-            for mi in _reads(i):
-                staged[mi] = None
-        if pressed[0]:
-            outs: Dict[int, int] = {}
-            for i in ids:
-                out = sum(dev.lane_bytes(d) for _p, d in _writes(i))
-                if out:
-                    outs[i] = out
-            sizes = {mi: 0 if mi in held else
-                     dev.lane_pin_bytes(mem_datas[mi]) for mi in staged}
-            if sum(sizes.values()) + sum(outs.values()) > dev.lane_room():
-                ids, staged = _admit(ids, sizes, outs)
-            for i in ids:
-                if i in outs:
-                    reserved[i] = (dev.lane_reserve(outs[i]), outs[i])
-        if not staged:
-            return ids, staged
+            out = sum(dev.lane_bytes(d) for _p, d in _writes(i))
+            if out:
+                outs[i] = out
+        sizes = {mi: 0 if mi in held else dev.lane_pin_bytes(mem_datas[mi])
+                 for i in ids for mi in _reads(i)}
+        if sum(sizes.values()) + sum(outs.values()) > dev.lane_room():
+            ids = _admit(ids, sizes, outs)
+        return ids, outs
+
+    def push(i, out, fresh, staged):
+        # PUSH phase of program i: under pressure the room of its outputs,
+        # then its memory operands that no earlier program of the round
+        # staged, asked for at once. The device decides key by key,
+        # pinning each operand INSIDE the table's reserve critical section
+        # (no peer thread's stage-in can evict it first, and staging tile
+        # k+1 cannot evict tile k before the call reads it: "dot got
+        # NoneType", found by the verify drive), and moves the misses in
+        # one device_put. If it raises it has given its pins back. False:
+        # no room short of a pinned victim, and the pool holds pins to
+        # wait for (its reserve given back; the caller sends it back)
+        if out:
+            reserved[i] = (dev.lane_reserve(out), out)
+        if not fresh:
+            return True
         t0 = _pc()
         try:
             copies, moved, put_ns, room_ns = dev.lane_stage_in_batch(
-                [mem_datas[mi] for mi in staged])
+                [mem_datas[mi] for mi in fresh])
         except NoRoom:
-            for i in ids:
-                if i in reserved:
-                    dev.lane_release(*reserved.pop(i))
-            if not held:        # no pin of the pool's to wait for
+            if i in reserved:
+                dev.lane_release(*reserved.pop(i))
+            if not held and not reserved:   # no pin of the pool's to wait for
                 raise
             pressed[0] = True
-            _wait(ids, first=True)
-            return [], {}
+            return False
         if moved:
             PTDEV_STATS["staged_tiles"] += moved
             PTDEV_STATS["stage_in_puts"] += 1
@@ -384,105 +389,101 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
                 getattr(c.payload, "nbytes", 0) for c in copies)
             for nb, n in sizes.items():
                 _obs((_STG, shape_bucket(nb), "tpu"), n, share * n)
-        for mi, copy in zip(staged, copies):
+        for mi, copy in zip(fresh, copies):
             h = held.get(mi)
             if h is None:
                 h = held[mi] = [copy, 0, 0]
-            h[2] += 1           # one table pin an operand a batch
+            h[2] += 1           # one table pin an operand a round
             staged[mi] = h
-        return ids, staged
+        return True
 
-    def issue(ids, staged):
-        # EXEC phase: dispatch each ready device task asynchronously
-        for i in ids:
-            oi = i
-            if _dregs is not None:
-                r = _dregs.get(i)
-                if r is not None:
-                    # region-sized dispatch: ONE jitted program for
-                    # the whole fused region, async like any task;
-                    # the retire id stays the compact node id
-                    ev: List[Any] = []
-                    for kk, v in r["ext"]:
-                        if kk == "slot":
-                            ev.append(slots[v])
-                        else:
-                            h = staged[v]
-                            h[1] += 1       # one more reader in flight
-                            ev.append(h[0].payload)
-                    # the operands this region is the last reader of
-                    # lead, and the program keeps them: each is a slot
-                    # value the pool owns, so the slot retires here
-                    given = r["given"]
-                    nd = len(given)
-                    _graph.trace_mark(_evr, i, _fs)
-                    if sp is None:
-                        first, rest = r["jitted"](tuple(ev[:nd]),
-                                                  tuple(ev[nd:]))
+    def call(i, staged):
+        # EXEC phase of program i: dispatched asynchronously, its
+        # memory operands the round's entries in ``staged``
+        oi = i
+        if _dregs is not None:
+            r = _dregs.get(i)
+            if r is not None:
+                # region-sized dispatch: ONE jitted program for
+                # the whole fused region, async like any task;
+                # the retire id stays the compact node id
+                ev: List[Any] = []
+                for kk, v in r["ext"]:
+                    if kk == "slot":
+                        ev.append(slots[v])
                     else:
-                        tok = sp.begin(PTDEV_CALL)
-                        try:
-                            first, rest = r["jitted"](tuple(ev[:nd]),
-                                                      tuple(ev[nd:]))
-                        finally:
-                            _called(tok)
-                    _graph.trace_mark(_evr, i, _fe)
-                    vals = first + rest
-                    for s, p in r["outs"]:
-                        slots[s] = vals[p]
-                    if nd:
-                        for s in given:
-                            slots[s] = None
-                        if ev[0].is_deleted():
-                            PTDEV_STATS["donated"] += nd
-                    PTDEV_STATS["region_outputs"] += len(vals)
-                    events = tuple(v for v in vals
-                                   if hasattr(v, "is_ready"))
-                    _fly(i, events, r["wb_pairs"], vals,
-                         r["ext_mems"], r["ntasks"],
-                         None if (_obs is None or r.get("cold")) else
-                         (names[r["cls"]], bucket, "tpu_fused"))
-                    continue
-                oi = _forig[i]
-            k = cls_of[oi]
-            base = slot_base[oi]
-            nd = ndflows[k]
-            vals: List[Any] = []
-            reads: List[int] = []
-            for dj in range(nd):
-                r = in_refs[base + dj]
-                if r >= 0:
-                    vals.append(slots[r])
-                elif r == -1:
-                    vals.append(None)
-                else:
-                    h = staged[-2 - r]
-                    h[1] += 1               # one more reader in flight
-                    reads.append(-2 - r)
-                    vals.append(h[0].payload)
-            fn = fns[k]
-            events = ()
-            if fn is not None:
+                        h = staged[v]
+                        h[1] += 1       # one more reader in flight
+                        ev.append(h[0].payload)
+                # the operands this region is the last reader of
+                # lead, and the program keeps them: each is a slot
+                # value the pool owns, so the slot retires here
+                given = r["given"]
+                nd = len(given)
+                _graph.trace_mark(_evr, i, _fs)
                 if sp is None:
-                    outs = fn(*params[k][oi - bases[k]], *vals)
+                    first, rest = r["jitted"](tuple(ev[:nd]),
+                                              tuple(ev[nd:]))
                 else:
                     tok = sp.begin(PTDEV_CALL)
                     try:
-                        outs = fn(*params[k][oi - bases[k]], *vals)
+                        first, rest = r["jitted"](tuple(ev[:nd]),
+                                                  tuple(ev[nd:]))
                     finally:
                         _called(tok)
-                for oj, dj in enumerate(written[k]):
-                    vals[dj] = outs[oj]
-                events = tuple(v for v in outs
+                _graph.trace_mark(_evr, i, _fe)
+                vals = first + rest
+                for s, p in r["outs"]:
+                    slots[s] = vals[p]
+                if nd:
+                    for s in given:
+                        slots[s] = None
+                    if ev[0].is_deleted():
+                        PTDEV_STATS["donated"] += nd
+                PTDEV_STATS["region_outputs"] += len(vals)
+                events = tuple(v for v in vals
                                if hasattr(v, "is_ready"))
-            for dj in range(nd):
-                slots[base + dj] = vals[dj]
-            _fly(i, events, writebacks.get(oi), vals, reads, 1,
-                 None if _obs is None else (names[k], bucket, "tpu"))
-        for mi, h in staged.items():
-            if not h[1]:            # staged, and no program reads it
-                _release(mi, h)
-        return len(ids)
+                _fly(i, events, r["wb_pairs"], vals,
+                     r["ext_mems"], r["ntasks"],
+                     None if (_obs is None or r.get("cold")) else
+                     (names[r["cls"]], bucket, "tpu_fused"))
+                return
+            oi = _forig[i]
+        k = cls_of[oi]
+        base = slot_base[oi]
+        nd = ndflows[k]
+        vals: List[Any] = []
+        reads: List[int] = []
+        for dj in range(nd):
+            r = in_refs[base + dj]
+            if r >= 0:
+                vals.append(slots[r])
+            elif r == -1:
+                vals.append(None)
+            else:
+                h = staged[-2 - r]
+                h[1] += 1               # one more reader in flight
+                reads.append(-2 - r)
+                vals.append(h[0].payload)
+        fn = fns[k]
+        events = ()
+        if fn is not None:
+            if sp is None:
+                outs = fn(*params[k][oi - bases[k]], *vals)
+            else:
+                tok = sp.begin(PTDEV_CALL)
+                try:
+                    outs = fn(*params[k][oi - bases[k]], *vals)
+                finally:
+                    _called(tok)
+            for oj, dj in enumerate(written[k]):
+                vals[dj] = outs[oj]
+            events = tuple(v for v in outs
+                           if hasattr(v, "is_ready"))
+        for dj in range(nd):
+            slots[base + dj] = vals[dj]
+        _fly(i, events, writebacks.get(oi), vals, reads, 1,
+             None if _obs is None else (names[k], bucket, "tpu"))
 
     def _waiting_first(ids):
         # the programs that waited go ahead of those that surface now
@@ -492,8 +493,56 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
         del backlog[:]
         return ids
 
+    def _round(ids):
+        # one admission round, program by program: each is pushed (its
+        # room, its operands the round has not staged yet) and called at
+        # once, so the chip starts after the first program's push, not
+        # the round's. A released program's write-backs land before the
+        # next program's decisions: the programs of a round were all ready
+        # when it began, so none reads from memory what another writes
+        # back. With the spans on, one ptdev.push span a program that
+        # pushes, the first's holding the round's admission too. Returns
+        # the number of programs called
+        staged: Dict[int, Any] = {}     # mi -> its held entry, this round
+        called = 0
+        pushed = 0      # the programs called before the last push began
+        tok = sp.begin(PTDEV_PUSH) if sp is not None else None
+        try:
+            if _obs is not None and not inflight:
+                # idle -> active: restart the amortization clock so idle
+                # gaps between batches never land in any task's cost
+                dev_clock[0] = _pc()
+            outs: Dict[int, int] = {}
+            if pressed[0]:
+                ids, outs = _admission(ids)
+            for k, i in enumerate(ids):
+                fresh = [mi for mi in dict.fromkeys(_reads(i))
+                         if mi not in staged]
+                out = outs.get(i)
+                if fresh or out:
+                    pushed = k
+                    if tok is None and sp is not None:
+                        tok = sp.begin(PTDEV_PUSH)
+                    if not push(i, out, fresh, staged):
+                        # it and the rest of the round wait, at the head
+                        _wait(list(ids[k:]), first=True)
+                        break
+                if tok is not None:
+                    acct["push_ns"] += sp.end(tok, sp.pt_push)
+                    tok = None
+                call(i, staged)
+                called += 1
+        finally:
+            PTDEV_STATS["called_in_push"] += pushed
+            if tok is not None:
+                acct["push_ns"] += sp.end(tok, sp.pt_push)
+        for mi, h in staged.items():
+            if not h[1]:            # staged, and no program reads it
+                _release(mi, h)
+        return called
+
     def _run(ids, _callback):
-        issue(*push(ids))
+        _round(ids)
 
     run = [_run]        # one admission round: the traced one with spans on
 
@@ -604,24 +653,20 @@ def _closures(devlane, engine, bases, params, slot_base, in_refs, ndflows,
     def traced_run(ids, callback):
         # one ptdev.dispatch span a round (a dispatch callback, or the
         # backlog admitted in a poll pass), recorded once per device
-        # program it called, its push phase a span inside it; the table
-        # pins the round took and the programs it found in flight (the
-        # depth of the device's queue as the host left it), one record each
+        # program it called, its programs' pushes spans inside it; the
+        # table pins the round took and the programs it found in flight
+        # (the depth of the device's queue as the host left it), one
+        # record each
         sp.pt_inflight.record(len(inflight))
         tok, before = sp.begin(PTDEV_DISPATCH), pinned[0]
-        called: Sequence[int] = ()
+        called = 0
         try:
-            sub = sp.begin(PTDEV_PUSH)
-            try:
-                called, staged = push(ids)
-            finally:
-                acct["push_ns"] += sp.end(sub, sp.pt_push)
-            issue(called, staged)
+            called = _round(ids)
         finally:
             acct["dispatch_ns"] += sp.end(
-                tok, sp.pt_dispatch if called else None, n=len(called) or 1)
+                tok, sp.pt_dispatch if called else None, n=called or 1)
             sp.pt_pins.record(pinned[0] - before)
-            acct["programs"] += len(called)
+            acct["programs"] += called
             acct["callbacks"] += callback
 
     run[0] = traced_run

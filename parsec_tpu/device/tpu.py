@@ -723,15 +723,16 @@ class TPUDevice(DeviceModule):
 
     def lane_stage_in(self, data: Data, pin: bool = False) -> DataCopy:
         """One datum staged in through the lane's entry: version-checked
-        through the C table, asynchronous, returns the device copy —
+        through the C table, returns the device copy —
         pinned atomically with the reserve when ``pin``."""
         return self._stage_in_copy(data, 0, pin=pin)
 
     def lane_stage_in_batch(self, datas: Sequence[Data]
                             ) -> Tuple[List[DataCopy], int, int, int]:
         """Stage-in entry for the native device lane's dispatch callback
-        (the push phase of ptdev): the distinct memory operands of a whole
-        batch at once. The decision is taken key by key and in order
+        (a program's push in ptdev): the memory operands of one program
+        that no earlier program of its round staged, at once. The
+        decision is taken key by key and in order
         (:meth:`_stage_in_decide`), each operand pinned atomically with
         its reserve and its victims applied before a byte moves, so a
         batch never evicts a tile of its own; a miss with no room short of

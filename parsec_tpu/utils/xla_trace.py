@@ -19,12 +19,12 @@ nine named spans on the per-task path (``device/tpu.py``, ``dsl/dtd.py``;
 ``dev.writeback``, the dirty branch of an eviction, is both managers')
 and seven on the PTG path (``dsl/ptg/compiler.py``: the lowering of one
 instantiation; ``device/lane_pool.py``: the ``ptdev`` manager's dispatch,
-with its push phase and each program's call inside it, poll and retire;
+with each program's push and call inside it, poll and retire;
 ``device/tpu.py``: inside the push phase, a miss's room made),
 each a ``TraceAnnotation`` on the profiler's host plane and a duration in
 a ``utils/hist.py`` histogram, plus two intervals that are histograms
 alone: the ready-wait, and ``ptdev.stage_in_ns`` (the misses of the
-lane's push phase, each at its share of the batch's one ``device_put``,
+lane's pushes, each at its share of its program's one ``device_put``,
 whose annotation is the ``dev.stage_in`` the push nests), and four
 counts filed the same way: ``tpudev.group_tasks``, ``ptdev.pins``,
 ``ptdev.inflight`` and ``ptexec.region_tasks``.

@@ -761,7 +761,7 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
     puts = _count_puts(dev, monkeypatch)
 
     def on_dispatch(ids, held):
-        log.append(("batch", len(ids)))
+        log.append(("batch", (len(ids), len(puts.calls))))
         for mi, h in held.items():
             readers[mi] = max(readers.get(mi, 0), h[1])
     mca.set("region_fusion", bool(bound))
@@ -779,12 +779,13 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
     assert box["held"] == {}
     _assert_unpinned(dev, mats)
     # a batch's pins: the distinct operands it staged, each once
-    batches, staged = [], []
+    batches, staged, put = [], [], []
     for what, v in log:
         if what == "stage":
             staged.append(v)
         elif what == "batch":
-            batches.append((v, staged))
+            batches.append((v[0], staged))
+            put.append(v[1] - sum(put))     # the puts this callback made
             staged = []
     programs = {128: 1, 16: _NT, 0: _NT ** 3}[bound]
     assert not staged and sum(n for n, _ in batches) == programs
@@ -797,9 +798,12 @@ def test_lane_pins_once_per_operand_of_a_batch(dctx, monkeypatch, bound):
         assert len(keys) <= min(n * tiles, 3 * _NT * _NT)
     pins = sum(len(keys) for _, keys in batches)
     assert pins >= 3 * _NT * _NT
-    # the 48 host tiles move once each, a batch's misses in one put (ISSUE 38)
+    # the 48 host tiles move once each; a round pushes program by program,
+    # a program's misses in one put: at least one put a callback that moved
+    # bytes, at most one a program of it
     assert sum(puts.calls) == 3 * _NT * _NT
-    assert len(puts.calls) <= sum(1 for _, keys in batches if keys)
+    assert sum(1 for p in put if p) <= len(puts.calls) == sum(put)
+    assert all(p <= n for p, (n, _keys) in zip(put, batches))
     assert not [e for e in log if e[0] == "pin"], "a pin per program operand"
     assert sum(1 for e in log if e[0] == "unpin") == pins
     # readers in flight count PROGRAMS: the A tiles of a row are read by its
@@ -1078,22 +1082,35 @@ def _tiles(n, base=0.0):
             for i in range(n)]
 
 
-def _hand_pool(devlane, reads, mem_datas):
+def _hand_pool(devlane, reads, mem_datas, writes=None, calls=None):
     """The lane's closures over independent tasks, for the test to drive by
     hand on its own thread: task ``i`` adds the memory operands ``reads[i]
-    = (mi, mj)`` into its one written flow. A batch is what ``dispatch`` is
-    given. Returns ``(dispatch, drain, held, slots)``; ``drain()`` polls
-    until every dispatched task has come back."""
+    = (mi, mj)`` into its one written flow, which ``writes[i]`` (a
+    ``Data``), where given, receives. A batch is what ``dispatch`` is
+    given; ``calls``, where given, gets ``("call", a + b)`` (the sum's
+    first entry) as each task is called. Returns ``(dispatch, drain, held,
+    slots)``; ``drain()`` polls until every dispatched task has come
+    back."""
     import time as _t
     import jax
     from parsec_tpu.device import lane_pool
     n = len(reads)
     in_refs = [r for a, b in reads for r in (-1, -2 - a, -2 - b)]
     slots = [None] * (3 * n)
+    add = jax.jit(lambda o, a, b: (a + b,))
+    if calls is not None:
+        def body(o, a, b):
+            calls.append(("call", float(np.asarray(a)[0, 0]
+                                        + np.asarray(b)[0, 0])))
+            return add(o, a, b)
+    else:
+        body = add
+    writebacks = {i: [(0, d)] for i, d in enumerate(writes or ())
+                  if d is not None}
     dispatch, poll, _drop, held = lane_pool._closures(
         devlane, None, [0], [[()] * n], list(range(0, 3 * n, 3)), in_refs,
-        [3], [0] * n, [jax.jit(lambda o, a, b: (a + b,))], [(0,)],
-        ["hand.add"], slots, mem_datas, {}, None, 0, None, None, n)
+        [3], [0] * n, [body], [(0,)],
+        ["hand.add"], slots, mem_datas, writebacks, None, 0, None, None, n)
     sent = []
 
     def send(ids):
@@ -1118,9 +1135,11 @@ def _pins(dev, data):
 
 
 def _batch_of_misses(dctx, monkeypatch):
-    """k misses: one ``device_put`` of the k tiles, k copies at the newest
-    versions, each pinned once in the table and in ``readers``, the bytes
-    and the table's misses counted, the lane's two counters up."""
+    """k misses over a round of three programs: one ``device_put`` a
+    program, of its misses that no earlier program of the round staged
+    (2, 2 and 1 of the 5 tiles), k copies at the newest versions, each
+    pinned once in the table and in ``readers``, the bytes and the
+    table's misses counted, the lane's two counters up."""
     from parsec_tpu.device.native import PTDEV_STATS
     devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
     datas = _tiles(5)
@@ -1131,7 +1150,7 @@ def _batch_of_misses(dctx, monkeypatch):
     moved, misses, stats = dev.transfer_in_bytes, \
         dev.coh_stats()["coh_misses"], PTDEV_STATS.snapshot()
     assert send([0, 1, 2]) == 3
-    assert puts.calls == [5]
+    assert puts.calls == [2, 2, 1]
     assert all(a is d.get_copy(0).payload for a, d in zip(puts, datas))
     assert dev.transfer_in_bytes == moved + 5 * _TILE
     assert dev.coh_stats()["coh_misses"] == misses + 5
@@ -1141,7 +1160,7 @@ def _batch_of_misses(dctx, monkeypatch):
         assert held[mi][2] == 1 and _pins(dev, d) == (1, 1)
     assert [h[1] for h in held.values()] == [2, 1, 1, 1, 1]
     delta = PTDEV_STATS.delta(stats)
-    assert (delta["staged_tiles"], delta["stage_in_puts"]) == (5, 1)
+    assert (delta["staged_tiles"], delta["stage_in_puts"]) == (5, 3)
     drain()
     assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
     assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3, 6)] == \
@@ -1149,13 +1168,15 @@ def _batch_of_misses(dctx, monkeypatch):
     # nothing left to move: a second batch over the same operands puts none
     send([0, 1, 2])
     drain()
-    assert puts.calls == [5] and PTDEV_STATS.delta(stats)["stage_in_puts"] == 1
+    assert puts.calls == [2, 2, 1]
+    assert PTDEV_STATS.delta(stats)["stage_in_puts"] == 3
 
 
 def _mixed_batch(dctx, monkeypatch):
     """Hits, an adoption, misses, and an operand that two programs (and one
-    of them twice) name: only the misses are in the put, the adoption is
-    counted, a hit's copy is the object it was, each operand pinned once."""
+    of them twice) name: only the misses are in the puts, one a program
+    that has one, the adoption is counted, a hit's copy is the object it
+    was, each operand pinned once."""
     import jax
     devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
     hit0, hit1, here, miss0, miss1, miss2 = datas = _tiles(6)
@@ -1168,7 +1189,7 @@ def _mixed_batch(dctx, monkeypatch):
     puts = _count_puts(dev, monkeypatch)
     moved, adopted = dev.transfer_in_bytes, dev.adopted
     send([0, 1, 2, 3])
-    assert puts.calls == [3] and len(puts) == 3
+    assert puts.calls == [1, 1, 1] and len(puts) == 3
     assert all(any(a is d.get_copy(0).payload for a in puts)
                for d in (miss0, miss1, miss2))
     assert dev.adopted == adopted + 1
@@ -1186,9 +1207,10 @@ def _mixed_batch(dctx, monkeypatch):
 
 
 def _batch_under_a_budget(dctx, monkeypatch):
-    """Four tiles of room. A batch of four new tiles evicts the four LRU
-    unpinned ones and none of its own, and the dirty one among them is on
-    the host, at its version, before the put is called. A batch of three
+    """Four tiles of room. A round of two programs over four new tiles
+    evicts the four LRU unpinned ones, two before each program's put, and
+    none of its own, and the dirty one among them is on the host, at its
+    version, before the put that took its room is called. A round of three
     programs that need six tiles is cut to the two whose four fit; the
     third waits, counted once, until a poll that gave pins back admits
     it: the resident bytes never pass the budget."""
@@ -1218,7 +1240,8 @@ def _batch_under_a_budget(dctx, monkeypatch):
     monkeypatch.setattr(dev._jax, "device_put", device_put)
     out = dev.transfer_out_bytes
     send([2, 3])
-    assert seen == [(4, 4, 1, np.ndarray, 100.0, True)]
+    assert seen == [(2, 2, 0, np.ndarray, 0.0, False),
+                    (2, 4, 1, np.ndarray, 100.0, True)]
     assert dev.transfer_out_bytes == out + _TILE
     assert all(d.get_copy(dev.device_index).payload is None for d in old)
     assert all(_pins(dev, d) == (1, 1) and
@@ -1227,13 +1250,13 @@ def _batch_under_a_budget(dctx, monkeypatch):
     drain()
     stats = PTDEV_STATS.snapshot()
     assert send([4, 5, 6]) == 3         # all three counted in flight
-    assert seen[-1][:2] == (4, 8)       # the four before it, none of its own
+    assert seen[-2:] == [(2, 6) + seen[-2][2:], (2, 8) + seen[-1][2:]]
     assert PTDEV_STATS.delta(stats)["held_back"] == 1
     assert dev._resident_bytes == 4 * _TILE == dev.lane_room() + 4 * _TILE
     assert all(_pins(dev, d) == (1, 1) for d in more[:4])
     assert all(d.get_copy(dev.device_index) is None for d in more[4:])
     drain()
-    assert seen[-1][:2] == (2, 10) and len(seen) == 3
+    assert seen[-1][:2] == (2, 10) and len(seen) == 5
     assert PTDEV_STATS.delta(stats)["held_back"] == 1
     assert dev.coh_stats()["hwm_bytes"] <= 4 * _TILE
     assert dev._resident_bytes == 4 * _TILE and dev.lane_room() == 4 * _TILE
@@ -1265,7 +1288,7 @@ def _a_put_that_raises(dctx, monkeypatch):
     assert miss0.get_copy(dev.device_index) is None
     puts = _count_puts(dev, monkeypatch)
     send([0, 1])
-    assert puts.calls == [2] and all(_pins(dev, d) == (1, 1) for d in datas)
+    assert puts.calls == [1, 1] and all(_pins(dev, d) == (1, 1) for d in datas)
     drain()
     assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
     assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3)] == [2.0, 10.0]
@@ -1297,7 +1320,7 @@ def _batch_with_the_spans_on(dctx, monkeypatch):
 
     def grew(name, field):
         return s1[name][field] - s0.get(name, {field: 0})[field]
-    assert puts.calls == [5]
+    assert puts.calls == [2, 2, 1]
     assert grew("ptdev.stage_in_ns", "count") == 5
     assert grew("tpudev.stage_in_ns", "count") == 5
     # n equal shares of one put: the two histograms hold the same time
@@ -1331,6 +1354,207 @@ def test_the_push_phase_stages_a_batch_at_once(monkeypatch, case, spans):
         ctx.fini()
         mca.params.unset("hist_enabled")
         mca.params.unset("device_tpu_over_cpu")
+
+
+# ---------------------------------------------------------------------------
+# A dispatch round pushes and calls program by program
+# ---------------------------------------------------------------------------
+
+def _log_puts(dev, monkeypatch, log):
+    """``("put", n)`` into ``log`` at each ``device_put`` of a list of n."""
+    real = dev._jax.device_put
+
+    def device_put(x, *args, **kw):
+        log.append(("put", len(x) if isinstance(x, list) else 1))
+        return real(x, *args, **kw)
+    monkeypatch.setattr(dev._jax, "device_put", device_put)
+
+
+def _round_in_program_order(dctx, monkeypatch):
+    """A round of three programs, each with two misses: three puts in
+    program order, each program called before the next one's put, and two
+    of the three called while a later one was still to be pushed; a round
+    of one program over resident tiles puts nothing and counts none."""
+    from parsec_tpu.device.native import PTDEV_STATS
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    datas, log = _tiles(6), []
+    send, drain, held, slots = _hand_pool(
+        devlane, [(0, 1), (2, 3), (4, 5), (0, 5)], datas, calls=log)
+    _log_puts(dev, monkeypatch, log)
+    stats = PTDEV_STATS.snapshot()
+    send([0, 1, 2])
+    assert log == [("put", 2), ("call", 1.0), ("put", 2), ("call", 5.0),
+                   ("put", 2), ("call", 9.0)]
+    delta = PTDEV_STATS.delta(stats)
+    assert (delta["called_in_push"], delta["programs"]) == (2, 3)
+    assert (delta["staged_tiles"], delta["stage_in_puts"]) == (6, 3)
+    drain()
+    stats, del_log = PTDEV_STATS.snapshot(), log[:]
+    send([3])
+    drain()
+    assert log[len(del_log):] == [("call", 5.0)]
+    delta = PTDEV_STATS.delta(stats)
+    assert (delta["called_in_push"], delta["programs"]) == (0, 1)
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(slots[s])[0, 0]) for s in (0, 3, 6, 9)] == \
+        [1.0, 5.0, 9.0, 5.0]
+
+
+def _shared_operand_decided_once(dctx, monkeypatch):
+    """Two programs of a round that share an operand: the first asks the
+    table for it and pins it, the second joins that entry with no table
+    call, and the pin is given back once both have retired."""
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    datas, table = _tiles(4), []
+    send, drain, held, _slots = _hand_pool(
+        devlane, [(0, 1), (1, 2), (2, 3)], datas)
+    puts = _count_puts(dev, monkeypatch)
+    monkeypatch.setattr(dev, "_ncoh", _TableSpy(dev._ncoh, table))
+    send([0, 1, 2])
+    staged = [k for what, k in table if what == "stage"]
+    assert sorted(staged) == sorted(set(staged)) and len(staged) == 4
+    assert puts.calls == [2, 1, 1]
+    assert [held[mi][1:] for mi in range(4)] == [[1, 1], [2, 1], [2, 1],
+                                                 [1, 1]]
+    assert all(_pins(dev, d) == (1, 1) for d in datas)
+    drain()
+    assert held == {} and all(_pins(dev, d) == (0, 0) for d in datas)
+    assert sum(1 for what, _k in table if what == "unpin") == 4
+
+
+def _pressed_pool(dctx, budget_tiles, reads, datas, writes, log=None):
+    """A hand pool over ``budget_tiles`` that binds under pressure: two
+    tiles of another's are pinned while it is bound, and given back."""
+    devlane, dev = _need_lane(dctx), _tpu_dev(dctx)
+    dev.set_budget(budget_tiles * _TILE, unit=1024)
+    pins = [dev.lane_stage_in(d, pin=True) for d in _tiles(2, 70.0)]
+    pool = _hand_pool(devlane, reads, datas, writes=writes, calls=log)
+    for pin in pins:
+        dev.unpin_copy(pin)
+    return pool
+
+
+def _reserve_before_own_stage_in(dctx, monkeypatch):
+    """Under pressure each program takes the room of its outputs right
+    before its own stage-in, not the round's up front, and its write-back
+    is the written datum's newest copy on the device; every pin and every
+    reserve comes back."""
+    dev, log = _tpu_dev(dctx), []
+    datas, outs = _tiles(6), _tiles(3, 100.0)
+    send, drain, held, _slots = _pressed_pool(
+        dctx, 10, [(0, 1), (2, 3), (4, 5)], datas, outs, log)
+    reserve, stage = dev.lane_reserve, dev.lane_stage_in_batch
+
+    def spy_reserve(nbytes):
+        log.append(("reserve", nbytes))
+        return reserve(nbytes)
+
+    def spy_stage(ds):
+        log.append(("stage", len(ds)))
+        return stage(ds)
+    monkeypatch.setattr(dev, "lane_reserve", spy_reserve)
+    monkeypatch.setattr(dev, "lane_stage_in_batch", spy_stage)
+    send([0, 1, 2])
+    assert log == [("reserve", _TILE), ("stage", 2), ("call", 1.0),
+                   ("reserve", _TILE), ("stage", 2), ("call", 5.0),
+                   ("reserve", _TILE), ("stage", 2), ("call", 9.0)]
+    assert dev._pinned_bytes == 9 * _TILE
+    drain()
+    assert held == {} and dev._pinned_bytes == 0
+    assert all(_pins(dev, d) == (0, 0) for d in datas)
+    assert [float(np.asarray(d.newest_copy().payload)[0, 0])
+            for d in outs] == [1.0, 5.0, 9.0]
+    assert all(d.newest_copy() is d.get_copy(dev.device_index)
+               for d in outs)
+    assert dev.coh_stats()["hwm_bytes"] <= 10 * _TILE
+
+
+def _no_room_at_the_second_program(dctx, monkeypatch):
+    """Another's pins take the room between the first program's push and
+    the second's: the first stays in flight, the second gives its reserve
+    back and it and the third wait at the head of the backlog, ahead of a
+    program that surfaces later, each counted once; once the room comes
+    back they run in that order, and every pin and reserve is given back."""
+    from parsec_tpu.device.native import PTDEV_STATS
+    dev, log = _tpu_dev(dctx), []
+    datas, outs = _tiles(6), _tiles(4, 100.0)
+    send, drain, held, _slots = _pressed_pool(
+        dctx, 10, [(0, 1), (2, 3), (4, 5), (0, 2)], datas, outs, log)
+    others, stage, pins = _tiles(6, 50.0), dev.lane_stage_in_batch, []
+
+    def spy_stage(ds):
+        if not pins and any(d is datas[2] for d in ds):    # a peer's pins
+            pins.extend(dev.lane_stage_in(d, pin=True) for d in others)
+        return stage(ds)
+    monkeypatch.setattr(dev, "lane_stage_in_batch", spy_stage)
+    stats = PTDEV_STATS.snapshot()
+    assert send([0, 1, 2]) == 3
+    assert log == [("call", 1.0)]
+    assert sorted(held) == [0, 1] and all(h[1:] == [1, 1]
+                                          for h in held.values())
+    assert _pins(dev, datas[2]) == (0, 0) and _pins(dev, datas[3]) == (0, 0)
+    # program 0's two operands and its reserve, and the peer's six
+    assert dev._pinned_bytes == 9 * _TILE
+    assert send([3]) == 1               # no room yet: all three wait
+    assert log == [("call", 1.0)]
+    for pin in pins:
+        dev.unpin_copy(pin)
+    drain()
+    assert log == [("call", 1.0), ("call", 5.0), ("call", 9.0),
+                   ("call", 2.0)]
+    delta = PTDEV_STATS.delta(stats)
+    assert (delta["held_back"], delta["programs"]) == (3, 4)
+    assert held == {} and dev._pinned_bytes == 0
+    assert all(_pins(dev, d) == (0, 0) for d in datas + others)
+    assert [float(np.asarray(d.newest_copy().payload)[0, 0])
+            for d in outs] == [1.0, 5.0, 9.0, 2.0]
+
+
+@pytest.mark.parametrize("case", [
+    _round_in_program_order, _shared_operand_decided_once,
+    _reserve_before_own_stage_in, _no_room_at_the_second_program],
+    ids=["order", "shared", "reserve", "no-room"])
+def test_a_round_pushes_and_calls_program_by_program(monkeypatch, case):
+    mca.set("device_tpu_over_cpu", True)
+    ctx = Context(nb_cores=1)
+    try:
+        case(ctx, monkeypatch)
+    finally:
+        monkeypatch.undo()
+        ctx.fini()
+        mca.params.unset("device_tpu_over_cpu")
+
+
+@pytest.mark.parametrize("stats, want", [
+    ({"programs": 47, "called_in_push": 19}, 100.0 * 19 / 47),
+    ({"programs": 47, "called_in_push": 0}, 0.0),
+    ({"programs": 0, "called_in_push": 0}, None),   # no program ran
+    ({"programs": 47}, None),                       # no such counter
+])
+def test_called_in_push_share_reader(monkeypatch, stats, want):
+    """``chipbench/layers/called_in_push_share.py``: 100 x
+    ``called_in_push`` over ``programs``, process-lifetime totals, nothing
+    where no program ran or the program keeps no such count; its entry
+    lists the out-of-core PTG cell."""
+    import json
+    import os
+    from parsec_tpu.device.native import PTDEV_STATS
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.syspath_prepend(root)
+    from chipbench.layers import called_in_push_share
+    for key in ("programs", "called_in_push"):
+        if key in stats:
+            monkeypatch.setitem(PTDEV_STATS, key, stats[key])
+        else:
+            monkeypatch.delitem(PTDEV_STATS, key)
+    assert called_in_push_share.read(None) == want
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"]
+                  if m["name"] == "called_in_push_share"]
+    assert entry == {"name": "called_in_push_share", "unit": "%",
+                     "better": "higher", "source": "program_counter",
+                     "layer": "device issue", "moves": "tflops",
+                     "workloads": ["ptg_gemm_ooc.ts2048"]}
 
 
 # ---------------------------------------------------------------------------

@@ -828,12 +828,77 @@ def test_the_benchmarks_reader_gives_the_early_share_or_nothing(monkeypatch):
 
 
 @pytest.mark.parametrize("path", ["regions", "per-task"])
+def test_no_program_of_a_round_reads_what_another_writes_back(
+        dctx, monkeypatch, path):
+    """A round pushes and calls program by program, so the write-backs of
+    a program released at dispatch land before a later program of the
+    same round takes its decisions. That changes nothing the later one
+    reads: the programs of a round were all ready when it began, so none
+    reads from memory a datum that another of them writes back. Checked on
+    every ``dispatch`` callback of the factorization (a program a task:
+    released programs that write back share rounds with others); the
+    factor is the reference's."""
+    from parsec_tpu.device import lane_pool
+    nt = {"regions": 8, "per-task": 5}[path]
+    knob, value = {"regions": ("region_fusion_max", 16),
+                   "per-task": ("region_fusion", False)}[path]
+    make, pools, rounds = lane_pool._closures, [], []
+
+    def spied(*args):
+        dispatch, poll, drop, held = make(*args)
+        pools.append(args)
+        n = len(pools) - 1
+
+        def spy_dispatch(ids):
+            rounds.append((n, list(ids)))
+            return dispatch(ids)
+        return spy_dispatch, poll, drop, held
+    monkeypatch.setattr(lane_pool, "_closures", spied)
+
+    def reads_writes(args, i):
+        (slot_base, in_refs, ndflows, cls_of, mem_datas, writebacks,
+         fusion) = (args[4], args[5], args[6], args[7], args[12], args[13],
+                    args[14])
+        if fusion is not None:
+            r = fusion["dev_regions"].get(i)
+            if r is not None:
+                return ({id(mem_datas[m]) for m in r["ext_mems"]},
+                        {id(d) for _p, d in r["wb_pairs"]})
+            i = fusion["orig_of"][i]
+        base = slot_base[i]
+        return ({id(mem_datas[-2 - r])
+                 for r in in_refs[base:base + ndflows[cls_of[i]]] if r < -1},
+                {id(d) for _p, d in writebacks.get(i, ())})
+    a, A = _matrix(nt, seed=41)
+    prog = compile_ptg(ops.POTRF_JDF, f"potrf-rounds-{path}")
+    mca.set(knob, value)
+    try:
+        got = _factor(dctx, A, prog)
+    finally:
+        mca.params.unset(knob)
+    assert counters.read("ptdev.cb_errors") == 0
+    shared = 0
+    for n, ids in rounds:
+        args = pools[n]
+        rw = [reads_writes(args, i) for i in ids]
+        for k, (_r, writes) in enumerate(rw):
+            for j, (reads, _w) in enumerate(rw):
+                assert j == k or not reads & writes, (ids[k], ids[j])
+        early = args[17] or ()
+        shared += any(early[i] and rw[k][1] for k, i in enumerate(ids[:-1]))
+    if path == "per-task":
+        assert shared > 0, "no round holds a released writer before another"
+    _assert_pins_given_back(dctx, [A])
+    _assert_factor(got, a)
+
+
+@pytest.mark.parametrize("path", ["regions", "per-task"])
 def test_the_lane_counts_the_tiles_it_moves_and_the_puts_that_move_them(
         dctx, monkeypatch, path):
-    """ISSUE 38: the push phase of a ``dispatch`` callback moves its
-    batch's misses in one ``device_put``. Over a factorization the lane
-    moves the lower triangle's tiles, each once, in no more puts than the
-    callbacks that had a miss, and the factor is the reference's."""
+    """ISSUE 38: a program's push moves its misses in one ``device_put``.
+    Over a factorization the lane moves the lower triangle's tiles, each
+    once, in at least one put a callback that moved bytes and at most one a
+    program, and the factor is the reference's."""
     from parsec_tpu.device import lane_pool
     nt = {"regions": 8, "per-task": 5}[path]
     knob, value = {"regions": ("region_fusion_max", 16),
@@ -865,6 +930,12 @@ def test_the_lane_counts_the_tiles_it_moves_and_the_puts_that_move_them(
     tiles = nt * (nt + 1) // 2
     assert dd["staged_tiles"] == tiles
     assert sum(moved) == tiles * TS * TS * 4
-    assert 1 <= dd["stage_in_puts"] == sum(1 for b in moved if b) < tiles
+    # a round pushes program by program: puts >= the callbacks that moved
+    # bytes, and <= the programs that had a miss (each tile is one
+    # program's miss, and a program reads several in the regions path)
+    assert 1 <= sum(1 for b in moved if b) <= dd["stage_in_puts"]
+    assert dd["stage_in_puts"] <= min(dd["programs"], tiles)
+    if path == "regions":
+        assert dd["stage_in_puts"] < tiles
     _assert_pins_given_back(dctx, [A])
     _assert_factor(got, a)

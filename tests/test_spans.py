@@ -488,13 +488,15 @@ def lane_pools():
 
 @pytest.mark.parametrize("path", list(PATHS))
 def test_ptdev_sub_spans_record_once_per_their_unit(lane_pools, path):
-    """``ptdev.push``: one record a ``dispatch`` callback, holding at least
-    what the callback's stage-in misses recorded; ``ptdev.call``: one a
-    device program, like ``ptdev.dispatch``."""
+    """``ptdev.push``: one record a program that pushes, the first of a
+    ``dispatch`` callback always (it holds the round's admission too), so
+    one to one a program, holding at least what the callback's stage-in
+    misses recorded; ``ptdev.call``: one a device program, like
+    ``ptdev.dispatch``."""
     got = lane_pools[path]
     assert got["callbacks"] and got["stats"]["programs"] >= 4
     for n_ids, d in got["callbacks"]:
-        assert d["push_ns"][0] == 1
+        assert 1 <= d["push_ns"][0] <= n_ids
         assert d["call_ns"][0] == d["dispatch_ns"][0] == n_ids
         assert d["push_ns"][1] >= d["stage_in_ns"][1]
     assert sum(n for n, _d in got["callbacks"]) == got["stats"]["programs"]

@@ -176,7 +176,8 @@ def test_runtime_spans_stand_on_the_host_plane_properly_nested(tmp_path, path):
                  X.PTDEV_POLL, X.PTDEV_RETIRE, X.DEV_STAGE_IN}
         seen, parents = _host_plane_spans(str(tmp_path), names)
         assert set(seen) == names
-        assert seen[X.PTDEV_PUSH] == seen[X.PTDEV_DISPATCH]  # one a callback
+        # one a program that pushes: one region, one callback
+        assert seen[X.PTDEV_PUSH] == seen[X.PTDEV_DISPATCH]
         assert seen[X.PTDEV_CALL] == seen[X.PTDEV_RETIRE] == 1  # one region
         # A, B, C: 12 tiles, every one a miss, in one put of the one callback
         assert seen[X.DEV_STAGE_IN] == seen[X.PTDEV_PUSH] == 1
