@@ -59,6 +59,10 @@ class DeviceModule:
         self.batched_tasks = 0
         # stage-ins that took a resident array as they found it (no copy)
         self.adopted = 0
+        # flows written without being read that took room on the device
+        # and moved no byte, and those bytes
+        self.write_allocs = 0
+        self.write_alloc_bytes = 0
         self._lock = threading.Lock()
 
     # -- lifecycle ------------------------------------------------------------
@@ -224,6 +228,8 @@ class DeviceRegistry:
             d.name: {
                 "executed_tasks": d.executed_tasks,
                 "transfer_in_bytes": d.transfer_in_bytes,
+                "write_alloc_bytes": d.write_alloc_bytes,
+                "write_allocs": d.write_allocs,
                 "transfer_out_bytes": d.transfer_out_bytes,
                 "adopted": d.adopted,
                 "batched_dispatches": d.batched_dispatches,

@@ -39,12 +39,12 @@ from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.task import (DEV_TPU, FLOW_ACCESS_CTL, FLOW_ACCESS_WRITE,
-                         HOOK_ASYNC, HOOK_DONE, Task)
+from ..core.task import (DEV_TPU, FLOW_ACCESS_CTL, FLOW_ACCESS_READ,
+                         FLOW_ACCESS_WRITE, HOOK_ASYNC, HOOK_DONE, Task)
 from ..data.data import COHERENCY_INVALID, COHERENCY_OWNED, COHERENCY_SHARED, Data, DataCopy
 from ..utils import mca, output
-from ..utils.xla_trace import (DEV_CALL, DEV_GATHER, DEV_POLL, DEV_RETIRE,
-                               DEV_STAGE_IN, DEV_SUBMIT,
+from ..utils.xla_trace import (DEV_ALLOC, DEV_CALL, DEV_GATHER, DEV_POLL,
+                               DEV_RETIRE, DEV_STAGE_IN, DEV_SUBMIT,
                                DEV_WRITEBACK, PTDEV_ROOM)
 from .device import DeviceModule
 
@@ -505,15 +505,20 @@ class TPUDevice(DeviceModule):
         """Version-checked stage-in of one datum (ref:
         parsec_device_data_stage_in device_gpu.c:1800): the decision
         (:meth:`_stage_in_decide`), then the transfer if it said so
-        (:meth:`_transfer`, a list of one). Returns the device-resident
-        copy, pinned when ``pin`` — release with :meth:`unpin_copy`."""
+        (:meth:`_transfer`, a list of one). A flow without READ ``access``
+        is given room and no byte (:meth:`_allocate`). Returns the
+        device-resident copy, pinned when ``pin`` — release with
+        :meth:`unpin_copy`."""
+        if not access & FLOW_ACCESS_READ:
+            return self._stage_in_write_only(data, pin)
         copy, src = self._stage_in_decide(data, pin)
         if src is None:
             return copy
         return self._transfer([(data, copy, src)], pin)[0][0]
 
     def _stage_in_decide(self, data: Data, pin: bool,
-                         room: Optional[List[int]] = None
+                         room: Optional[List[int]] = None,
+                         write_only: bool = False
                          ) -> Tuple[Optional[DataCopy], Optional[DataCopy]]:
         """One operand's residency decision, and everything about it that
         moves no byte. With the native table up the decision is C's — is a
@@ -539,13 +544,13 @@ class TPUDevice(DeviceModule):
         hit (LRU touched), or newest bytes that already live on this
         device, adopted. Else ``(copy, newest)``: the device copy to
         refill (or None) and the copy that holds the bytes to transfer,
-        for :meth:`_install` to finish."""
+        for :meth:`_install` to finish. A ``write_only`` miss is
+        ``(copy, None)`` too: room and no byte (:meth:`_allocate`)."""
         copy = data.get_copy(self.device_index)
         newest = data.newest_copy()
         if newest is None:
             raise RuntimeError(f"no valid copy to stage in for {data!r}")
-        hit = copy is not None and copy.version == newest.version and \
-            copy.coherency_state != COHERENCY_INVALID
+        hit = _current(copy, newest)
         if self._ncoh is not None:
             nbytes, tok = _nbytes(newest.payload), None
             if room is not None and not hit:
@@ -576,6 +581,8 @@ class TPUDevice(DeviceModule):
                 with self._heap_lock:
                     self._pin_locked(copy)  # the table half was pinned above
             return copy, None
+        if write_only:
+            return self._allocate(data, copy, newest, pin), None
         arr = newest.payload
         if isinstance(arr, self._jax.Array) and arr.committed and \
                 arr.devices() == {self.jax_device}:
@@ -593,6 +600,49 @@ class TPUDevice(DeviceModule):
             return self._install(data, copy, arr, newest.version, pin,
                                  moved=False), None
         return copy, newest
+
+    def _stage_in_write_only(self, data: Data, pin: bool) -> DataCopy:
+        """A flow the task writes without reading (ref: the stage-in of a
+        flow without READ access, device_gpu.c:1800, moves nothing): a
+        copy of the newest version resident here is used as it stands;
+        else the flow takes room as a miss does and no byte moves
+        (:meth:`_allocate`), inside the ``dev.alloc`` span."""
+        sp, tok = self._spans, None
+        if sp is not None and not _current(data.get_copy(self.device_index),
+                                           data.newest_copy()):
+            tok = sp.begin(DEV_ALLOC)
+        try:
+            return self._stage_in_decide(data, pin, write_only=True)[0]
+        finally:
+            if tok is not None:
+                sp.end(tok, sp.alloc)
+
+    def _allocate(self, data: Data, copy: Optional[DataCopy],
+                  newest: DataCopy, pin: bool) -> DataCopy:
+        """The write-only miss: the room of the newest copy's bytes, which
+        the table reserved (victims applied, the pin taken) or the Python
+        LRU makes here, held by a device copy that has no payload yet
+        (INVALID at the newest version, a stale array dropped). Nothing
+        is transferred and the body is handed ``None`` for the flow; the
+        epilog installs its output as the newest version
+        (``write_alloc_bytes`` / ``write_allocs`` count these)."""
+        nbytes = _nbytes(newest.payload)
+        if self._ncoh is None:
+            self._reserve(nbytes)
+        with self._heap_lock:
+            if copy is None:
+                copy = data.create_copy(self.device_index, None,
+                                        COHERENCY_INVALID)
+            else:
+                copy.payload = None
+                copy.coherency_state = COHERENCY_INVALID
+            copy.version = newest.version
+            self._lru_touch_locked(self.res_key(data), copy, nbytes)
+            if pin:
+                self._pin_locked(copy)  # the table half: pinned in stage_in
+            self.write_alloc_bytes += nbytes
+            self.write_allocs += 1
+        return copy
 
     def _transfer(self, misses: List[Tuple[Data, Optional[DataCopy],
                                            DataCopy]], pin: bool
@@ -725,7 +775,7 @@ class TPUDevice(DeviceModule):
         """One datum staged in through the lane's entry: version-checked
         through the C table, returns the device copy —
         pinned atomically with the reserve when ``pin``."""
-        return self._stage_in_copy(data, 0, pin=pin)
+        return self._stage_in_copy(data, FLOW_ACCESS_READ, pin=pin)
 
     def lane_stage_in_batch(self, datas: Sequence[Data]
                             ) -> Tuple[List[DataCopy], int, int, int]:
@@ -866,7 +916,9 @@ class TPUDevice(DeviceModule):
                     self.pin_copy(dev_copy)
                 slot.data_in = dev_copy
                 gt.pinned.append(dev_copy)
-                inputs.append(dev_copy.payload)
+                # a write-only flow's old bytes are no input of the body
+                inputs.append(dev_copy.payload if flow.access & FLOW_ACCESS_READ
+                              else None)
             else:
                 payload = getattr(copy_in, "payload", copy_in)
                 inputs.append(self._jax.device_put(payload, self.jax_device))
@@ -1022,9 +1074,10 @@ class TPUDevice(DeviceModule):
         with self._heap_lock:
             self._lru_touch_locked(key, copy)
 
-    def _lru_touch_locked(self, key: Any, copy: DataCopy) -> None:
+    def _lru_touch_locked(self, key: Any, copy: DataCopy,
+                          nbytes: Optional[int] = None) -> None:
         self._lru.pop(key, None)
-        new_size = _nbytes(copy.payload)
+        new_size = _nbytes(copy.payload) if nbytes is None else nbytes
         old_size = self._lru_sizes.get(key, 0)
         self._resident_bytes += new_size - old_size
         if copy.readers:
@@ -1143,6 +1196,13 @@ class TPUDevice(DeviceModule):
         self._pinned_bytes = 0
         self._fetching.clear()
         self._pending.clear()
+
+
+def _current(copy: Optional[DataCopy], newest: Optional[DataCopy]) -> bool:
+    """Does ``copy`` hold the newest version, valid?"""
+    return copy is not None and newest is not None and \
+        copy.version == newest.version and \
+        copy.coherency_state != COHERENCY_INVALID
 
 
 def _is_oom(e: Exception) -> bool:

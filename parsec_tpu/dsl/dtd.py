@@ -246,6 +246,12 @@ def _grouped(fn: Callable, k: int):
 _ladders: Dict[Tuple, Any] = {}
 
 
+def _signature(vals: Sequence[Any]) -> Tuple:
+    """(shape, dtype) of each operand of a device program; ``None`` (the
+    device lane's value for a flow written without being read) is its own."""
+    return tuple(None if v is None else (v.shape, v.dtype) for v in vals)
+
+
 _host_dev_cache = [False, None]   # [resolved, device]
 
 
@@ -1638,10 +1644,10 @@ class DTDTaskpool(Taskpool):
             flat += [np.asarray(v) if isinstance(v, (int, float)) else v
                      for v in self._gather_args(t, inp)]
         a = len(flat) // len(tasks)
-        sig = tuple((v.shape, v.dtype) for v in flat[:a])
-        for i in range(a, len(flat)):
-            if (flat[i].shape, flat[i].dtype) != sig[i % a]:
-                raise ValueError(f"ragged group of {tasks[0].task_class.name}")
+        sigs = _signature(flat)
+        if any(sigs[i] != sigs[i % a] for i in range(a, len(flat))):
+            raise ValueError(f"ragged group of {tasks[0].task_class.name}")
+        sig = sigs[:a]
         if _ladders.get((fn, sig)) is not True:
             if not self._may_group((fn, sig)):
                 tasks[0].task_class.groups = False
@@ -1671,8 +1677,7 @@ class DTDTaskpool(Taskpool):
             vals = [np.asarray(v) if isinstance(v, (int, float)) else v
                     for v in vals]
             if tc.groups is None:   # the class's first program in this pool
-                tc.groups = self._may_group(
-                    (tc.fn, tuple((v.shape, v.dtype) for v in vals)))
+                tc.groups = self._may_group((tc.fn, _signature(vals)))
         outs = self._apply_outputs(task, fn(*vals))
         # order outputs by WRITE flows (contract shared with device epilog)
         return tuple(outs)

@@ -1,93 +1,149 @@
-"""Tiled QR factorization (dgeqrf) DAG builder.
+"""Tiled QR factorization (dgeqrf) DAG builder, in compact-WY form.
 
-The DPLASMA-style dgeqrf of BASELINE config 5: the classic communication-
-avoiding tile QR (GEQRT / UNMQR / TSQRT / TSMQR kernel quartet), expressed
-with explicit per-step Q factors held in scratch tiles instead of compact
-WY storage — the natural TPU formulation, since each kernel is then one or
-two MXU matmuls plus a small in-tile QR (jnp.linalg.qr, TPU-lowered):
+The DPLASMA dgeqrf of BASELINE config 5: the tile QR of Buttari, Langou,
+Kurzak and Dongarra ("A class of parallel tiled linear algebra algorithms
+for multicore architectures", 2009) with its four kernels, on the flat TS
+tree, storing its factors as ``dplasma_dgeqrf(A, T)`` does:
 
     for k:
-      GEQRT:  A[k,k] -> Q1 (ts×ts), R into A[k,k]
-      UNMQR:  A[k,n] = Q1^T A[k,n]                       (n > k)
+      GEQRT(k):     A[k,k] -> R (upper) and V (strictly lower, unit
+                    diagonal implied); T[k,k] <- its triangular factor
+      UNMQR(k,n):   A[k,n] <- Q(k)^T A[k,n]                       (n > k)
       for m > k:
-        TSQRT:  [A[k,k]; A[m,k]] -> Q2 (2ts×ts), new R into A[k,k],
-                A[m,k] = 0 (implicit)
-        TSMQR:  [A[k,n]; A[m,n]] = Q2^T [A[k,n]; A[m,n]]  (n > k)
+        TSQRT(k,m):   [R[k,k]; A[m,k]] -> new R into A[k,k] (its lower part,
+                      GEQRT's V, kept); V2 into A[m,k]; T[m,k] <- its T
+        TSMQR(k,m,n): [A[k,n]; A[m,n]] <- Q(k,m)^T [A[k,n]; A[m,n]]  (n > k)
 
-The result's R occupies the upper triangle of A; Q is implicit in the
-scratch tiles (enough for least-squares solves and the A^T A = R^T R
-correctness contract)."""
+Every reflector block is ``Q = I - V T V^T`` with ``T`` upper triangular,
+``T^{-1} = striu(V^T V) + diag(1/tau)`` (a reflector with ``tau = 0`` is the
+identity, and its row and column of ``T`` are 0). ``T`` is written by GEQRT
+and TSQRT and never read first: a WRITE flow, which the device lane gives
+room on the device without moving a byte (docs/device_lane.md).
+
+Departures from DPLASMA, each for the MXU:
+
+* ``ib = TS``: one level of blocking, ``T`` one upper-triangular TS x TS tile
+  per panel. DPLASMA's inner blocking (ib ~ 32) tunes for a CPU's caches; on
+  the MXU it would be a loop of 32-wide products. UNMQR and TSMQR then do
+  three dense TS x TS x TS products, 6 TS^3 FLOP, where the inner-blocked
+  algorithm does 4 TS^3.
+* TSQRT factors the stacked ``[triu(R); A[m,k]]`` with the dense Householder
+  panel, jax's ``geqrf`` (the ``Qr`` custom call on the TPU), about twice
+  the structured kernel's panel FLOP. The top of its V is exactly the
+  identity (the stack's top block is upper triangular, so no reflector has a
+  component below its own row there), and only V2 is stored, in A[m,k].
+
+The panel runs under ``default_matmul_precision("highest")``; the products
+take the ``tile_dot_precision`` of every other tile body."""
 
 from __future__ import annotations
-
-from typing import Dict, Tuple
-
-import numpy as np
 
 from ..data.matrix import TiledMatrix
 from ..dsl.dtd import AFFINITY, DTDTaskpool, READ, RW, WRITE
 
 
-def tile_geqrt(akk, q_out):
-    """QR of the diagonal tile: returns (R, Q)."""
+def _dot(a, b):
     import jax.numpy as jnp
-    q, r = jnp.linalg.qr(akk, mode="complete")
-    return r, q
+    from .pallas_kernels import dot_precision
+    return jnp.dot(a, b, precision=dot_precision(),
+                   preferred_element_type=jnp.float32).astype(a.dtype)
 
 
-def tile_unmqr(q, akn):
-    """A[k,n] = Q^T A[k,n]."""
+def _panel(a):
+    """Householder QR of ``a`` (m x n, m >= n): LAPACK's packed output (R on
+    and above the diagonal, the reflectors below it) and ``tau``."""
+    import jax
+    # jax exports only the explicit-Q ``qr``; its Householder half is the
+    # primitive ``geqrf`` (the ``Qr`` custom call on the TPU)
+    from jax._src.lax.linalg import geqrf
+    with jax.default_matmul_precision("highest"):
+        return geqrf(a)
+
+
+def _t_factor(gram, tau):
+    """The upper-triangular T of ``Q = I - V T V^T`` from ``V^T V`` and the
+    reflectors' ``tau``: the inverse of ``striu(V^T V) + diag(1/tau)``, with
+    the row and column of a ``tau = 0`` reflector (the identity) set to 0."""
+    import jax
     import jax.numpy as jnp
-    return jnp.dot(q.T, akn, preferred_element_type=jnp.float32).astype(akn.dtype)
+    nz = tau != 0
+    keep = nz[:, None] & nz[None, :]
+    inv_tau = jnp.where(nz, 1.0 / jnp.where(nz, tau, 1.0), 1.0)
+    m = jnp.where(keep, jnp.triu(gram, 1), 0.0) + jnp.diag(inv_tau)
+    eye = jnp.eye(gram.shape[0], dtype=gram.dtype)
+    with jax.default_matmul_precision("highest"):
+        t = jax.scipy.linalg.solve_triangular(m, eye, lower=False)
+    return jnp.where(keep, t, 0.0).astype(gram.dtype)
 
 
-def tile_tsqrt(rkk, amk, q_out):
-    """QR of the stacked [R(k,k); A(m,k)]: returns (new R, zeroed A[m,k], Q2)."""
+def _unit_lower(a):
     import jax.numpy as jnp
-    ts = rkk.shape[0]
-    stacked = jnp.concatenate([jnp.triu(rkk), amk], axis=0)
-    q, r = jnp.linalg.qr(stacked, mode="complete")   # (2ts, 2ts), (2ts, ts)
-    return r[:ts, :], jnp.zeros_like(amk), q
+    return jnp.tril(a, -1) + jnp.eye(a.shape[0], dtype=a.dtype)
 
 
-def tile_tsmqr(q2, akn, amn):
-    """[A[k,n]; A[m,n]] = Q2^T [A[k,n]; A[m,n]]."""
+def tile_geqrt(akk, t):
+    """QR of the diagonal tile. ``t`` is write-only (``None`` on the device
+    lane). Returns (R above and V below the diagonal, T)."""
+    a, tau = _panel(akk)
+    v = _unit_lower(a)
+    return a, _t_factor(_dot(v.T, v), tau)
+
+
+def tile_unmqr(akk, t, akn):
+    """A[k,n] <- Q^T A[k,n], Q = I - V T V^T, V the unit lower part of A[k,k]."""
+    v = _unit_lower(akk)
+    return akn - _dot(v, _dot(t.T, _dot(v.T, akn)))
+
+
+def tile_tsqrt(akk, amk, t):
+    """QR of the stack [triu(A[k,k]); A[m,k]]. Returns (new R above
+    A[k,k]'s kept lower part, V2, T); ``t`` is write-only."""
     import jax.numpy as jnp
-    ts = akn.shape[0]
-    stacked = jnp.concatenate([akn, amn], axis=0)
-    out = jnp.dot(q2.T, stacked, preferred_element_type=jnp.float32).astype(akn.dtype)
-    return out[:ts, :], out[ts:, :]
+    ts = akk.shape[0]
+    a, tau = _panel(jnp.concatenate([jnp.triu(akk), amk], axis=0))
+    v2 = a[ts:]
+    # V = [I; V2], so striu(V^T V) = striu(V2^T V2)
+    return (jnp.triu(a[:ts]) + jnp.tril(akk, -1), v2,
+            _t_factor(_dot(v2.T, v2), tau))
 
 
-def insert_geqrf_tasks(tp: DTDTaskpool, A: TiledMatrix) -> int:
-    """Tile QR DAG; Q factors go to per-(k[,m]) scratch tiles. Returns task
-    count."""
-    T = A.mt
-    assert A.mt == A.nt
-    ts = A.mb
+def tile_tsmqr(akn, amn, v2, t):
+    """[A[k,n]; A[m,n]] <- Q^T [A[k,n]; A[m,n]], Q = I - [I; V2] T [I; V2]^T."""
+    w = _dot(t.T, akn + _dot(v2.T, amn))
+    return akn - w, amn - _dot(v2, w)
+
+
+def insert_geqrf_tasks(tp: DTDTaskpool, A: TiledMatrix,
+                       T: TiledMatrix) -> int:
+    """The flat-tree tile QR of the square tiled ``A``; the triangular
+    factors go to ``T``'s tiles (k, k) and (m, k), m > k, which GEQRT and
+    TSQRT write without reading. Priorities put the panel first and the
+    earlier step ahead of the later. Returns the task count,
+    NT + 2 NT(NT-1)/2 + sum_{j<NT} j^2."""
+    nt = A.mt
+    if A.mt != A.nt or (T.mt, T.nt, T.mb, T.nb) != (A.mt, A.nt, A.mb, A.nb):
+        raise ValueError("the tile QR takes a square A of square tiles and "
+                         "a T of A's tiling")
     n0 = tp.inserted
-    for k in range(T):
-        prio = (T - k) * 10000
-        qk = tp.tile_new((ts, ts), np.float32)
-        tp.insert_task(tile_geqrt,
-                       (tp.tile_of(A, k, k), RW | AFFINITY),
-                       (qk, WRITE),
+    for k in range(nt):
+        prio = (nt - k) * 10000
+        akk = tp.tile_of(A, k, k)
+        tp.insert_task(tile_geqrt, (akk, RW | AFFINITY),
+                       (tp.tile_of(T, k, k), WRITE),
                        priority=prio + 3000, name="GEQRT")
-        for n in range(k + 1, T):
-            tp.insert_task(tile_unmqr, (qk, READ),
+        for n in range(k + 1, nt):
+            tp.insert_task(tile_unmqr, (akk, READ), (tp.tile_of(T, k, k), READ),
                            (tp.tile_of(A, k, n), RW | AFFINITY),
                            priority=prio + 2000, name="UNMQR")
-        for m in range(k + 1, T):
-            q2 = tp.tile_new((2 * ts, 2 * ts), np.float32)
-            tp.insert_task(tile_tsqrt,
-                           (tp.tile_of(A, k, k), RW | AFFINITY),
-                           (tp.tile_of(A, m, k), RW),
-                           (q2, WRITE),
+        for m in range(k + 1, nt):
+            tmk = tp.tile_of(T, m, k)
+            tp.insert_task(tile_tsqrt, (akk, RW | AFFINITY),
+                           (tp.tile_of(A, m, k), RW), (tmk, WRITE),
                            priority=prio + 1500, name="TSQRT")
-            for n in range(k + 1, T):
-                tp.insert_task(tile_tsmqr, (q2, READ),
-                               (tp.tile_of(A, k, n), RW),
+            for n in range(k + 1, nt):
+                tp.insert_task(tile_tsmqr, (tp.tile_of(A, k, n), RW),
                                (tp.tile_of(A, m, n), RW | AFFINITY),
+                               (tp.tile_of(A, m, k), READ), (tmk, READ),
                                priority=prio, name="TSMQR")
     return tp.inserted - n0
 

@@ -61,8 +61,11 @@ HIST_NAMES: Dict[str, Tuple[str, ...]] = {
     # record a tile written back to the host
     # ``gather_ns``, ``call_ns`` (ISSUE 37): the two halves of ``submit_ns``,
     # the inputs made resident and pinned, then the program's call
+    # ``alloc_ns``: a flow written without being read given room on the
+    # device and no byte, one record an allocation
     "tpudev": ("submit_ns", "stage_in_ns", "poll_ns", "retire_ns",
-               "group_tasks", "writeback_ns", "gather_ns", "call_ns"),
+               "group_tasks", "writeback_ns", "gather_ns", "call_ns",
+               "alloc_ns"),
     "dtd": ("link_ns", "stall_ns"),
     # the PTG path's spans (ISSUE 29): one instantiation lowered onto its
     # lanes, and the ptdev manager's dispatch / stage-in / poll / retire

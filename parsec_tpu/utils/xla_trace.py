@@ -72,6 +72,7 @@ DTD_LINK, DTD_STALL = "dtd.link", "dtd.stall"
 DEV_SUBMIT, DEV_STAGE_IN = "dev.submit", "dev.stage_in"
 DEV_POLL, DEV_RETIRE = "dev.poll", "dev.retire"
 DEV_WRITEBACK = "dev.writeback"
+DEV_ALLOC = "dev.alloc"         # inside dev.gather: a write-only flow's room
 DEV_GATHER, DEV_CALL = "dev.gather", "dev.call"     # inside dev.submit
 PTG_LOWER = "ptg.lower"
 PTDEV_DISPATCH = "ptdev.dispatch"
@@ -150,6 +151,7 @@ class Spans:
         self.writeback = tpudev.cell("writeback_ns")
         self.gather = tpudev.cell("gather_ns")
         self.call = tpudev.cell("call_ns")
+        self.alloc = tpudev.cell("alloc_ns")
         self.link = dtd.cell("link_ns")
         self.stall = dtd.cell("stall_ns")
         self._ready = ready.cell("ready_wait_ns")

@@ -383,7 +383,11 @@ def test_capture_lu_qr_match_scheduler(ctx, which):
         M = TwoDimBlockCyclic(f"{which}{capture}", n, n, ts, ts, P=1, Q=1)
         M.fill(lambda m, k: src[m*ts:(m+1)*ts, k*ts:(k+1)*ts])
         tp = DTDTaskpool(ctx, f"{which}-{capture}", capture=capture)
-        ins(tp, M)
+        if which == "geqrf":
+            ins(tp, M, TwoDimBlockCyclic(f"T{capture}", n, n, ts, ts,
+                                         P=1, Q=1))
+        else:
+            ins(tp, M)
         tp.wait(timeout=60)
         tp.close()
         ctx.wait(timeout=30)

@@ -337,8 +337,9 @@ def test_alternating_rank_write_chain():
 
 def test_distributed_geqrf_row_cyclic():
     """Tile QR across 2 ranks with ROW-cyclic tiles: TSQRT/TSMQR write
-    tiles owned by other ranks (flush writes them home) and Q factors ship
-    across the fabric — BASELINE config 5's dgeqrf shape."""
+    tiles owned by other ranks (flush writes them home) and the V and T
+    factors ship across the fabric (T on the same row-cyclic grid) —
+    BASELINE config 5's dgeqrf shape."""
     from parsec_tpu.ops.geqrf import insert_geqrf_tasks
     n, ts = 64, 16
     rng = np.random.default_rng(92)
@@ -349,8 +350,10 @@ def test_distributed_geqrf_row_cyclic():
         A = TwoDimBlockCyclic("QRD", n, n, ts, ts, P=2, Q=1,
                               nodes=2, myrank=rank)
         A.fill(lambda m, k: a[m*ts:(m+1)*ts, k*ts:(k+1)*ts])
+        T = TwoDimBlockCyclic("QRT", n, n, ts, ts, P=2, Q=1,
+                              nodes=2, myrank=rank)
         tp = DTDTaskpool(ctx, "dgeqrf")
-        insert_geqrf_tasks(tp, A)
+        insert_geqrf_tasks(tp, A, T)
         tp.data_flush_all(A)
         tp.wait(timeout=60); tp.close(); ctx.wait(timeout=60); ctx.fini()
         return {(m, k): np.asarray(A.data_of(m, k).newest_copy().payload)

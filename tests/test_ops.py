@@ -93,24 +93,30 @@ def test_getrf_builder(ctx):
 
 
 def test_geqrf_builder(ctx):
-    """Tiled QR: R^T R must equal A^T A (Q orthogonal, implicit)."""
+    """Tiled QR: R^T R must equal A^T A (Q orthogonal), and the reflectors
+    stored below the diagonal (V2 in the sub-diagonal tiles, T beside)
+    rebuild A = QR."""
     from parsec_tpu.ops.geqrf import insert_geqrf_tasks
+    from test_geqrf import apply_q
     n, ts = 64, 16
+    nt = n // ts
     rng = np.random.default_rng(9)
     a = rng.standard_normal((n, n)).astype(np.float32)
     A = _tiled_from(a, ts, "QR")
+    T = TiledMatrix("QRT", n, n, ts, ts)
     tp = DTDTaskpool(ctx, "geqrf")
-    insert_geqrf_tasks(tp, A)
+    insert_geqrf_tasks(tp, A, T)
     tp.wait()
     tp.close()
     ctx.wait()
     R = np.triu(A.to_dense())
     np.testing.assert_allclose(R.T @ R, a.T @ a, rtol=5e-2, atol=5e-2)
-    # below-diagonal tiles must be (numerically) annihilated
-    for m in range(1, n // ts):
-        for k in range(m):
-            tile = np.asarray(A.data_of(m, k).newest_copy().payload)
-            assert np.abs(tile).max() < 1e-3
+    # the sub-diagonal tiles hold V2 by design: with T they give back A
+    tile = lambda M, m, k: np.asarray(M.data_of(m, k).newest_copy().payload)
+    qr = np.vstack(apply_q(lambda k, m: tile(A, m, k),
+                           lambda m, k: tile(T, m, k),
+                           [R[i * ts:(i + 1) * ts] for i in range(nt)], nt))
+    assert np.linalg.norm(qr - a) / np.linalg.norm(a) < 1e-5
 
 
 def test_dtd_gemm_bf16_tiles(ctx):
