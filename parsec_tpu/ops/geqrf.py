@@ -22,11 +22,18 @@ room on the device without moving a byte (docs/device_lane.md).
 
 Departures from DPLASMA, each for the MXU:
 
-* ``ib = TS``: one level of blocking, ``T`` one upper-triangular TS x TS tile
-  per panel. DPLASMA's inner blocking (ib ~ 32) tunes for a CPU's caches; on
-  the MXU it would be a loop of 32-wide products. UNMQR and TSMQR then do
-  three dense TS x TS x TS products, 6 TS^3 FLOP, where the inner-blocked
-  algorithm does 4 TS^3.
+* ``T`` is one upper-triangular TS x TS tile per panel, as if ``ib = TS``.
+  UNMQR and TSMQR read only its ib x ib diagonal blocks: the panel's
+  ``Q = H_1 H_2 ... H_b``, ``H_j = I - V_j T_jj V_j^T`` with ``T_jj`` T's
+  j-th diagonal block (T is built as ``[[T11, -T11 V1^T V2 T22], [0, T22]]``,
+  so its off-diagonal blocks only encode the product), and they apply it one
+  ib-wide block of reflectors at a time: TSMQR 4 TS^3 + 2 ib TS^2 FLOP,
+  UNMQR 2 TS^3 + 4 ib TS^2, where the whole T in three dense products is
+  6 TS^3. The same Q is applied, up to rounding. T is still stored whole:
+  it is the factor the caller is handed, from which anyone may apply Q. ib
+  is 256 from a tile of 1024 up (:func:`_inner_block`), the width at which
+  TSMQR runs fastest on the MXU; DPLASMA's ib ~ 32 tunes for a CPU's
+  caches. A smaller tile is one block: the dense form.
 * TSQRT factors the stacked ``[triu(R); A[m,k]]`` with the dense Householder
   panel, jax's ``geqrf`` (the ``Qr`` custom call on the TPU), about twice
   the structured kernel's panel FLOP. The top of its V is exactly the
@@ -40,6 +47,22 @@ from __future__ import annotations
 
 from ..data.matrix import TiledMatrix
 from ..dsl.dtd import AFFINITY, DTDTaskpool, READ, RW, WRITE
+
+
+#: the width of the reflector blocks UNMQR and TSMQR apply one at a time,
+#: and the smallest tile they are applied to in blocks. On a TPU v5e at f32
+#: HIGHEST a TSMQR of 2048 takes 1.24 ms at ib = 256 (1.28 at 128, 1.30 at
+#: 512, 1.69 dense); blocks gain at 1024 and nothing at 512 (PERF.md)
+INNER_BLOCK = 256
+BLOCKED_FROM = 1024
+
+
+def _inner_block(ts):
+    """ib for a TS x TS tile: ``INNER_BLOCK`` where the tile is at least
+    ``BLOCKED_FROM`` and a multiple of it, else TS (one block: three dense
+    products, 6 TS^3)."""
+    return INNER_BLOCK if ts >= BLOCKED_FROM and ts % INNER_BLOCK == 0 \
+        else ts
 
 
 def _dot(a, b):
@@ -90,9 +113,21 @@ def tile_geqrt(akk, t):
 
 
 def tile_unmqr(akk, t, akn):
-    """A[k,n] <- Q^T A[k,n], Q = I - V T V^T, V the unit lower part of A[k,k]."""
+    """A[k,n] <- Q^T A[k,n], Q = I - V T V^T, V the unit lower part of A[k,k],
+    applied one ib-wide block of reflectors at a time. Block j's columns of
+    V are zero above row j ib, so it reads and writes only the rows below:
+    2 TS^3 + 4 ib TS^2 FLOP."""
+    import jax.numpy as jnp
     v = _unit_lower(akk)
-    return akn - _dot(v, _dot(t.T, _dot(v.T, akn)))
+    ib = _inner_block(akk.shape[0])
+    done, rest = [], akn
+    for j in range(0, akk.shape[0], ib):
+        vj, s = v[j:, j:j + ib], slice(j, j + ib)
+        w = _dot(t[s, s].T, _dot(vj.T, rest))
+        rest = rest - _dot(vj, w)
+        done.append(rest[:ib])
+        rest = rest[ib:]
+    return jnp.concatenate(done)
 
 
 def tile_tsqrt(akk, amk, t):
@@ -108,9 +143,19 @@ def tile_tsqrt(akk, amk, t):
 
 
 def tile_tsmqr(akn, amn, v2, t):
-    """[A[k,n]; A[m,n]] <- Q^T [A[k,n]; A[m,n]], Q = I - [I; V2] T [I; V2]^T."""
-    w = _dot(t.T, akn + _dot(v2.T, amn))
-    return akn - w, amn - _dot(v2, w)
+    """[A[k,n]; A[m,n]] <- Q^T [A[k,n]; A[m,n]], Q = I - [I; V2] T [I; V2]^T,
+    applied one ib-wide block of reflectors at a time: block j touches
+    A[k,n]'s rows j ib : (j+1) ib and all of A[m,n], 4 TS^3 + 2 ib TS^2
+    FLOP."""
+    import jax.numpy as jnp
+    ib = _inner_block(akn.shape[0])
+    top = []
+    for j in range(0, akn.shape[0], ib):
+        v2j, s = v2[:, j:j + ib], slice(j, j + ib)
+        w = _dot(t[s, s].T, akn[s] + _dot(v2j.T, amn))
+        top.append(akn[s] - w)
+        amn = amn - _dot(v2j, w)
+    return jnp.concatenate(top), amn
 
 
 def insert_geqrf_tasks(tp: DTDTaskpool, A: TiledMatrix,

@@ -83,6 +83,35 @@ def _q_times(A, T, x, nt, ts):
 @pytest.mark.parametrize("ts", [8, 16])
 @pytest.mark.parametrize("nt", [1, 2, 4, 6])
 def test_qr_matches_the_reference(dctx, nt, ts):
+    _check_qr(dctx, nt, ts)
+
+
+@pytest.fixture()
+def blocked(monkeypatch):
+    """The update bodies applied in blocks of TS / 4 reflectors (at TS = 8
+    or 16 the program's rule gives one block); the rule notes each tile it
+    was asked for, so a test sees that the bodies were traced under it. The
+    jit caches are cleared on both sides: a trace made under the program's
+    rule is not reused here, nor this one after."""
+    asked = []
+
+    def quarter(ts):
+        asked.append(ts)
+        return ts // 4
+
+    jax.clear_caches()
+    monkeypatch.setattr(G, "_inner_block", quarter)
+    yield asked
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("nt", [2, 4])
+def test_blocked_qr_matches_the_reference(dctx, blocked, nt):
+    _check_qr(dctx, nt, 16)
+    assert 16 in blocked
+
+
+def _check_qr(dctx, nt, ts):
     n = nt * ts
     a = np.random.default_rng((nt, ts)).standard_normal((n, n)).astype(
         np.float32)
@@ -114,6 +143,63 @@ def test_qr_matches_the_reference(dctx, nt, ts):
     stats = dctx.devices.statistics()[dev.name]
     assert stats["write_allocs"] == tiles
     assert stats["write_alloc_bytes"] == dev.write_alloc_bytes
+
+
+def _reflectors(ts, zero_column):
+    """V (unit lower) and T of GEQRT and TSQRT on seeded Gaussian tiles:
+    ``(v, t)`` for UNMQR's and ``(v2, t)`` for TSMQR's shape. With
+    ``zero_column`` one column of each panel is 0, so one reflector of each
+    has tau = 0 and a zero row and column in T."""
+    rng = np.random.default_rng((ts, zero_column))
+    akk, amk = (rng.standard_normal((ts, ts)).astype(np.float32)
+                for _ in range(2))
+    if zero_column:
+        akk[:, ts // 3] = 0.0
+        amk[:, 0] = 0.0     # the stack's column 0 is R's, zero below row 0
+    r, t_kk = jax.jit(G.tile_geqrt)(akk, None)
+    _, v2, t_mk = jax.jit(G.tile_tsqrt)(r, amk, None)
+    r, t_kk, v2, t_mk = map(np.asarray, (r, t_kk, v2, t_mk))
+    if zero_column:
+        assert not t_kk[:, ts // 3].any() and not t_kk[ts // 3].any()
+        assert not t_mk[:, 0].any() and not t_mk[0].any()
+    return (r, t_kk), (v2, t_mk)
+
+
+@pytest.mark.parametrize("zero_column", [False, True],
+                         ids=["full", "tau0"])
+@pytest.mark.parametrize("ib", [8, 16, 32, 64])
+@pytest.mark.parametrize("body", ["unmqr", "tsmqr"])
+def test_blocked_update_equals_the_dense_one(monkeypatch, body, ib,
+                                             zero_column):
+    """Applied in ib-wide blocks, each with its own diagonal block of T, the
+    update is Q^T X = X - V T^T V^T X of the whole T: float64 on the host
+    from the same V and T."""
+    ts = 64
+    (r, t_kk), (v2, t_mk) = _reflectors(ts, zero_column)
+    rng = np.random.default_rng(ib)
+    x, y = (rng.standard_normal((ts, ts)).astype(np.float32)
+            for _ in range(2))
+    monkeypatch.setattr(G, "_inner_block", lambda n: ib)
+    f64 = lambda a: np.asarray(a, np.float64)
+    # a function of its own, so jax traces it under this rule
+    if body == "unmqr":
+        v = np.tril(f64(r), -1) + np.eye(ts)
+        got = [jax.jit(lambda *a: G.tile_unmqr(*a))(r, t_kk, x)]
+        want = [f64(x) - v @ (f64(t_kk).T @ (v.T @ f64(x)))]
+    else:
+        w = f64(t_mk).T @ (f64(x) + f64(v2).T @ f64(y))
+        got = jax.jit(lambda *a: G.tile_tsmqr(*a))(x, y, v2, t_mk)
+        want = [f64(x) - w, f64(y) - f64(v2) @ w]
+    for g, want in zip(got, want):
+        assert np.linalg.norm(f64(g) - want) / np.linalg.norm(want) < 1e-5
+
+
+@pytest.mark.parametrize("ts, ib", [(2048, 256), (4096, 256), (1024, 256),
+                                    (512, 512), (16, 16), (8, 8)])
+def test_inner_block_rule(ts, ib):
+    """256-wide blocks from a tile of 1024 up, the dense form (one block)
+    below: the v5e's readings at f32 HIGHEST."""
+    assert G._inner_block(ts) == ib
 
 
 def test_tsqrt_outputs_land_in_flow_order(dctx):
@@ -164,7 +250,12 @@ def test_a_group_equals_single_programs(body, k):
 def test_updates_are_issued_in_groups(dctx, monkeypatch):
     """Over a host device the manager takes a class as paced by the host:
     UNMQR and TSMQR (two outputs a task) go as multi-task programs, and the
-    factorization is still right."""
+    factorization is still right. The first step's UNMQRs are released
+    together by its GEQRT, and each is judged at the next one's issue,
+    before a program on the host device is done: such a burst ends the
+    class's streak. On a loaded host the ten UNMQRs of the later steps at
+    NT = 6 did not always build it again (UNMQR then never went grouped);
+    NT = 16 gives the streak enough steps to form."""
     seen = []
     submit = DTDTaskpool._tpu_batch_submit
 
@@ -173,7 +264,7 @@ def test_updates_are_issued_in_groups(dctx, monkeypatch):
         return submit(self, device, tasks, inputs_list)
 
     monkeypatch.setattr(DTDTaskpool, "_tpu_batch_submit", spy)
-    nt, ts = 6, 8
+    nt, ts = 16, 8
     a = np.random.default_rng(11).standard_normal((nt * ts, nt * ts)).astype(
         np.float32)
     A, T, _ = _factor(dctx, a, ts)
